@@ -779,23 +779,27 @@ def _lstm_layer(x, w, r, b=None, h0=None, c0=None, forgetBias=0.0,
     if b is not None:
         xw = xw + b
 
-    # Pallas recurrence kernel on TPU when shapes/dtype allow: h, c and R
-    # stay VMEM-resident across all timesteps (1.8x the scan lowering at
-    # b1024 under slope timing, r4 A/B: 13.3 vs 24.4 ms/step on the
-    # char-RNN config; kernels/lstm.py documents the design and bounds)
-    import os as _os
-
+    # Pallas recurrence kernel when shapes/dtype allow: h, c and R stay
+    # VMEM-resident across all timesteps (kernels/lstm.py documents the
+    # design and bounds). kernels.recurrence_route makes and COUNTS the
+    # decision; under a GSPMD-sharded step the kernel runs per batch
+    # shard (kernels.per_batch_shard)
+    from deeplearning4j_tpu import kernels
     from deeplearning4j_tpu.kernels.lstm import lstm_seq, lstm_seq_available
 
-    if (jax.default_backend() == "tpu"
-            and lstm_seq_available(x.shape[0], hsz, x.dtype)
-            and r.dtype == jnp.float32
-            and _os.environ.get("DL4J_DISABLE_PALLAS_LSTM") != "1"):
+    route = kernels.recurrence_route(
+        "LSTM", r.dtype == jnp.float32
+        and lstm_seq_available(kernels.shard_rows(n), hsz, x.dtype))
+    if route != "scan":
         xw_k = xw.astype(jnp.float32)
         if forgetBias:
             xw_k = xw_k.at[:, :, hsz:2 * hsz].add(forgetBias)
-        hs_k, hT, cT = lstm_seq(xw_k, r, h0.astype(jnp.float32),
-                                c0.astype(jnp.float32))
+        interpret = route == "interpret"
+        seq = kernels.per_batch_shard(
+            lambda xw_, r_, h_, c_: lstm_seq(xw_, r_, h_, c_, interpret),
+            n, batch_dims=(1, None, 0, 0), out_batch_dims=(1, 0, 0))
+        hs_k, hT, cT = seq(xw_k, r, h0.astype(jnp.float32),
+                           c0.astype(jnp.float32))
         out = jnp.moveaxis(hs_k, 0, 2)
         if not returnFullSequence:
             return hT, hT, cT
@@ -845,7 +849,7 @@ def _gru_layer(x, w, r, b=None, h0=None, unroll=4, resetAfter=True,
     """Input projection hoisted out of the scan (same lowering as
     lstmLayer); the reset-gated candidate keeps only h@r sequential.
     On TPU the Pallas recurrence kernel (kernels/gru.py) takes over when
-    shapes allow.
+    shapes allow (kernels.recurrence_route counts the decision).
 
     Gate layout [reset | update | candidate]. resetAfter=True (cuDNN /
     Keras v2 convention): candidate = tanh(c_w + r * (h@Rc + rb_c)),
@@ -875,19 +879,22 @@ def _gru_layer(x, w, r, b=None, h0=None, unroll=4, resetAfter=True,
         hT, hs = lax.scan(step_before, h0, xw, unroll=min(unroll, t))
         return jnp.moveaxis(hs, 0, 2), hT
 
-    import os as _os
-
+    from deeplearning4j_tpu import kernels
     from deeplearning4j_tpu.kernels.gru import gru_seq, gru_seq_available
 
-    if (jax.default_backend() == "tpu"
-            and activation == "tanh"  # the Pallas kernel fixes tanh
-            and gru_seq_available(n, hsz, x.dtype)
-            and r.dtype == jnp.float32
-            and _os.environ.get("DL4J_DISABLE_PALLAS_GRU") != "1"):
+    route = kernels.recurrence_route(
+        "GRU", activation == "tanh"  # the Pallas kernel fixes tanh
+        and r.dtype == jnp.float32
+        and gru_seq_available(kernels.shard_rows(n), hsz, x.dtype))
+    if route != "scan":
         rb_k = (jnp.zeros((3 * hsz,), jnp.float32) if rb is None
                 else rb.astype(jnp.float32))
-        hs_k, hT = gru_seq(xw.astype(jnp.float32), r, rb_k,
-                           h0.astype(jnp.float32))
+        interpret = route == "interpret"
+        seq = kernels.per_batch_shard(
+            lambda xw_, r_, rb_, h_: gru_seq(xw_, r_, rb_, h_, interpret),
+            n, batch_dims=(1, None, None, 0), out_batch_dims=(1, 0))
+        hs_k, hT = seq(xw.astype(jnp.float32), r, rb_k,
+                       h0.astype(jnp.float32))
         return jnp.moveaxis(hs_k, 0, 2), hT
 
     def step(h, xw_t):

@@ -215,6 +215,25 @@ def _attach_shm(name):
         return shared_memory.SharedMemory(name=name)
 
 
+def _check_shm_room(size):
+    """Raise the OSError the host would give for a ``size``-byte segment
+    before creating it: past RLIMIT_FSIZE the stdlib's own clean-up of
+    the failed segment confuses its resource tracker, and past what
+    /dev/shm has free the segment is created but a worker's first write
+    to an unbacked page is a SIGBUS."""
+    import errno
+    import resource
+
+    limit = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+    if limit != resource.RLIM_INFINITY and size > limit:
+        raise OSError(errno.EFBIG, f"RLIMIT_FSIZE is {limit} bytes")
+    if os.path.isdir("/dev/shm"):
+        st = os.statvfs("/dev/shm")
+        if size > st.f_bavail * st.f_frsize:
+            raise OSError(errno.ENOSPC, f"/dev/shm has "
+                          f"{st.f_bavail * st.f_frsize} bytes free")
+
+
 class ShmRing:
     """Fixed-slot shared-memory ring for decoded batches.
 
@@ -248,6 +267,7 @@ class ShmRing:
         self.slot_bytes = int(slot_bytes)
         self.data_off = ((self.slots + 63) // 64) * 64
         size = self.data_off + self.slots * self.slot_bytes
+        _check_shm_room(size)
         self.shm = shared_memory.SharedMemory(create=True, size=size)
         self.flags = np.frombuffer(self.shm.buf, np.uint8, self.slots, 0)
         self.flags[:] = 0
@@ -644,7 +664,10 @@ class ParallelImageDataSetIterator(DataSetIterator):
     - ``shuffle``: reshuffle the batch->file assignment each epoch from
       ``(seed, epoch)`` (deterministic under resume via ``set_epoch``);
     - ``transport``: ``"auto"`` (shm where available, else queue, else
-      serial) | ``"shm"`` | ``"queue"`` | ``"serial"``;
+      serial) | ``"shm"`` | ``"queue"`` | ``"serial"``. Under ``"auto"``
+      a host that refuses the ring's shared-memory segment (a file-size
+      limit, a small /dev/shm) gets the queue with a warning; an explicit
+      ``"shm"`` raises there. ``.transport`` says which one is in use;
     - ``pool``: an :class:`EtlWorkerPool` handle to share workers with
       other iterators (default: a private pool, persistent across
       epochs, shut down by ``close()``);
@@ -716,6 +739,7 @@ class ParallelImageDataSetIterator(DataSetIterator):
             raise ValueError("no images found")
 
         self._transport = self._resolve_transport(transport)
+        self._auto_transport = transport == "auto"
         self._pool = None
         self._own_pool = False
         if self._transport != "serial":
@@ -753,6 +777,11 @@ class ParallelImageDataSetIterator(DataSetIterator):
         batches (mesh.host_sharded_batch) instead of assuming every
         process feeds the identical batch."""
         return self._host_sharded
+
+    @property
+    def transport(self):
+        """The batch transport in use: "shm", "queue" or "serial"."""
+        return self._transport
 
     def getLabels(self):
         return list(self._labels)
@@ -816,6 +845,29 @@ class ParallelImageDataSetIterator(DataSetIterator):
                                  self._slot_bytes())
         return self._ring
 
+    def _ring_descriptor(self):
+        """The work order's ring, or None for the queue. The segment is
+        created on the first epoch; a host that refuses it (RLIMIT_FSIZE
+        or /dev/shm smaller than the ring, see _check_shm_room) has no
+        shm transport, which "auto" answers with the queue and an
+        explicit "shm" with the error."""
+        if self._transport != "shm":
+            return None
+        try:
+            return self._ensure_ring().descriptor
+        except OSError as e:
+            if not self._auto_transport:
+                raise
+            import warnings
+
+            size = max(self._qsize, self._pool.size) * self._slot_bytes()
+            warnings.warn(
+                f"ParallelImageDataSetIterator: cannot create the "
+                f"{size}-byte shared-memory ring ({e}); transport=\"auto\" "
+                f"falls back to the queue", RuntimeWarning, stacklevel=2)
+            self._transport = "queue"
+            return None
+
     def _instruments(self):
         from deeplearning4j_tpu import telemetry
 
@@ -852,8 +904,7 @@ class ParallelImageDataSetIterator(DataSetIterator):
                                    self._n_batches - start)),
             "start": start,
             "stall": self._stall,
-            "ring": (self._ensure_ring().descriptor
-                     if self._transport == "shm" else None),
+            "ring": self._ring_descriptor(),
             # (trace_id, span_id) of the sampled training trace, or
             # None: workers decode under this identity and ship
             # etl.decode span records back beside their batches

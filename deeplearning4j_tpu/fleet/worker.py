@@ -353,6 +353,9 @@ def main(argv=None) -> int:
         os.environ["XLA_FLAGS"] = (
             f"{flags} --xla_force_host_platform_device_count="
             f"{int(n_dev)}").strip()
+    from deeplearning4j_tpu.runtime import RuntimeConfig
+
+    RuntimeConfig.enable_compile_cache()
     stop = threading.Event()
     for sig in (signal.SIGTERM, signal.SIGINT):
         signal.signal(sig, lambda *_: stop.set())

@@ -1,7 +1,119 @@
 """In-repo Pallas TPU kernels — the custom-call tier SURVEY.md §7
 reserves for ops where generic XLA lowering demonstrably misses
 (reference analog: libnd4j's platform-helper kernels, e.g. the cuDNN
-LSTM path). Each kernel ships with an XLA fallback and parity tests."""
+LSTM path). Each kernel ships with an XLA fallback and parity tests.
+
+This module also owns the ROUTING decision between a kernel and its
+``lax.scan`` fallback (:func:`recurrence_route`), so that the choice is
+made in one place and is never silent: every traced ``lstmLayer`` /
+``gruLayer`` call is counted in ``dl4j_recurrence_route_total{op,route}``.
+"""
+
+import contextlib
+import os
+import threading
 
 from deeplearning4j_tpu.kernels.lstm import (  # noqa: F401
     lstm_seq, lstm_seq_available)
+
+ROUTE_HELP = ("Traced lstmLayer/gruLayer calls by the implementation "
+              "the recurrence was routed to (pallas = compiled Mosaic "
+              "kernel, interpret = the same kernel under the Pallas "
+              "interpreter, scan = the lax.scan lowering). Counted at "
+              "trace time: once per compiled executable, not per step")
+
+_tls = threading.local()
+
+
+def recurrence_route(op: str, available: bool) -> str:
+    """Which implementation one traced recurrence takes: ``"pallas"``
+    (the compiled kernel: TPU backend, shape/dtype/VMEM gate passed),
+    ``"interpret"`` (the same kernel through the Pallas interpreter,
+    only when ``DL4J_PALLAS_INTERPRET=1`` — chip_smoke.py's ``--dry-cpu``
+    and nothing else), or ``"scan"``.
+
+    ``op`` is ``"LSTM"`` or ``"GRU"``; ``DL4J_DISABLE_PALLAS_<op>=1``
+    forces the scan (the gated tier's A/B parity tests). The decision is
+    counted in ``dl4j_recurrence_route_total`` so a bench-width config
+    that quietly fell back to the scan shows up on /metrics and fails
+    chip_smoke.py."""
+    import jax
+
+    from deeplearning4j_tpu.telemetry import registry as _registry
+
+    if not available or \
+            os.environ.get(f"DL4J_DISABLE_PALLAS_{op}") == "1":
+        route = "scan"
+    elif jax.default_backend() == "tpu":
+        route = "pallas"
+    elif os.environ.get("DL4J_PALLAS_INTERPRET") == "1":
+        route = "interpret"
+    else:
+        route = "scan"
+    if _registry.enabled():
+        fam = _registry.get_registry().counter(
+            "dl4j_recurrence_route_total", ROUTE_HELP, ("op", "route"))
+        fam.local = True   # depends on the host's backend: scrape-only
+        fam.labels(op=op, route=route).inc()
+    return route
+
+
+@contextlib.contextmanager
+def batch_sharded(mesh, axis):
+    """Trace-time scope set by a GSPMD-sharded train step whose batch is
+    split over ``axis`` of ``mesh``: a recurrence kernel traced inside
+    it runs per batch shard under ``shard_map`` (:func:`per_batch_shard`).
+    Without the scope a Mosaic kernel inside a multi-device jit does not
+    lower at all ("Mosaic kernels cannot be automatically partitioned"),
+    and the kernels' VMEM gates would judge the global batch."""
+    prev = getattr(_tls, "shard", None)
+    _tls.shard = (mesh, axis)
+    try:
+        yield
+    finally:
+        _tls.shard = prev
+
+
+def _shard_scope(n):
+    """(mesh, axis, size) when a batch of ``n`` rows is being traced
+    inside a :func:`batch_sharded` scope that really splits it."""
+    shard = getattr(_tls, "shard", None)
+    if shard is None:
+        return None
+    mesh, axis = shard
+    size = mesh.shape.get(axis, 1)
+    if size == 1 or n % size:
+        return None
+    return mesh, axis, size
+
+
+def shard_rows(n) -> int:
+    """Batch rows ONE device sees for a global batch of ``n`` — what the
+    kernels' shape/VMEM gates must judge."""
+    scope = _shard_scope(n)
+    return n if scope is None else n // scope[2]
+
+
+def per_batch_shard(fn, n, batch_dims, out_batch_dims):
+    """``fn`` wrapped so that, inside a :func:`batch_sharded` scope, it
+    runs once per batch shard. ``batch_dims`` / ``out_batch_dims`` give,
+    for each positional input / output, the index of its batch axis, or
+    None for a replicated one (recurrent weights — their cotangents are
+    summed over the axis by shard_map's transpose). Outside a scope
+    ``fn`` is returned unchanged."""
+    scope = _shard_scope(n)
+    if scope is None:
+        return fn
+    mesh, axis, _ = scope
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    def spec(dim):
+        return P() if dim is None else P(*([None] * dim + [axis]))
+
+    # check_vma=False: pallas_call outputs carry no varying-axes info
+    return jax.shard_map(
+        fn, mesh=mesh,
+        in_specs=tuple(spec(d) for d in batch_dims),
+        out_specs=tuple(spec(d) for d in out_batch_dims),
+        check_vma=False)

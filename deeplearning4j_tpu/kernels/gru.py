@@ -17,7 +17,8 @@ Residuals saved for backward: ru [T,N,2H], cand [T,N,H], rz_c [T,N,H].
 Backward returns (dxw, dR, drb, dh0).
 
 Constraints mirror the LSTM kernel: f32, H % 128 == 0, N % 8 == 0,
-VMEM-bounded; callers fall back to the lax.scan lowering otherwise.
+VMEM-bounded; callers route to the lax.scan lowering otherwise
+(`kernels.recurrence_route` counts the decision).
 """
 
 from __future__ import annotations
@@ -27,19 +28,14 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    _PALLAS_OK = True
-except Exception:  # pragma: no cover
-    _PALLAS_OK = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deeplearning4j_tpu.kernels.lstm import _VMEM_BUDGET, _dotT_lhs, _dotT_rhs
 
 
 def gru_seq_available(n, h, dtype) -> bool:
-    if not (_PALLAS_OK and jnp.dtype(dtype) == jnp.float32
+    if not (jnp.dtype(dtype) == jnp.float32
             and h % 128 == 0 and n % 8 == 0):
         return False
     weights = 3 * (h * 3 * h * 4)

@@ -94,18 +94,15 @@ class MultiHost:
             return MultiHost.topology()
         coord = args[0]
         if num_processes is not None and num_processes > 1:
-            try:
-                # the CPU backend needs an explicit cross-process
-                # collectives implementation (TPU/GPU wire theirs up in
-                # PJRT); without it every multi-process CPU computation
-                # fails with "Multiprocess computations aren't
-                # implemented on the CPU backend". Must be set BEFORE
-                # backend init, so no jax.devices()/default_backend()
-                # probing here — harmless for non-CPU backends.
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:
-                pass  # jax version without the option
+            # the CPU backend needs an explicit cross-process
+            # collectives implementation (TPU/GPU wire theirs up in
+            # PJRT); without it every multi-process CPU computation
+            # fails with "Multiprocess computations aren't
+            # implemented on the CPU backend". Must be set BEFORE
+            # backend init, so no jax.devices()/default_backend()
+            # probing here — harmless for non-CPU backends.
+            jax.config.update(
+                "jax_cpu_collectives_implementation", "gloo")
             jax.distributed.initialize(coordinator_address=coord,
                                        num_processes=num_processes,
                                        process_id=process_id)

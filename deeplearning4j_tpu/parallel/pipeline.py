@@ -26,7 +26,7 @@ from functools import partial
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import (
@@ -72,7 +72,7 @@ def pipeline_apply(stage_fn, stage_params, x_mb, mesh):
 
     @partial(shard_map, mesh=mesh,
              in_specs=(param_specs, x_spec), out_specs=x_spec,
-             check_rep=False)
+             check_vma=False)
     def run(params_local, x_local):
         # params_local leaves: [1, ...] (this device's stage)
         p_here = jax.tree_util.tree_map(lambda a: a[0], params_local)

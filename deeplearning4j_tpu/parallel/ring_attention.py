@@ -17,20 +17,15 @@ import math
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.6 jax ships it under experimental
-    from jax.experimental.shard_map import shard_map
 
 from deeplearning4j_tpu.parallel.mesh import SEQ_AXIS
 
 
-def _ring_attention_local(q, k, v, axis_name, causal, scale, n):
-    """Runs per-device under shard_map. q,k,v: [B,H,Tl,D] local blocks.
-    `n` is the static ring size (mesh axis size; lax.axis_size is not
-    available on every supported jax)."""
+def _ring_attention_local(q, k, v, axis_name, causal, scale):
+    """Runs per-device under shard_map. q,k,v: [B,H,Tl,D] local blocks."""
+    n = lax.axis_size(axis_name)
     my_rank = lax.axis_index(axis_name)
     b, h, tl, d = q.shape
     q_pos = my_rank * tl + jnp.arange(tl)          # global query positions
@@ -78,14 +73,9 @@ def ring_attention(q, k, v, mesh: Mesh, causal: bool = False,
     scale = 1.0 / math.sqrt(q.shape[-1]) if scaled else 1.0
     spec = P(None, None, axis, None)
     local = functools.partial(_ring_attention_local, axis_name=axis,
-                              causal=causal, scale=scale,
-                              n=mesh.shape[axis])
-    try:
-        fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_vma=False)
-    except TypeError:  # pre-0.6 jax spells the kwarg check_rep
-        fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
+                              causal=causal, scale=scale)
+    fn = shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                   out_specs=spec, check_vma=False)
     return fn(q, k, v)
 
 

@@ -99,6 +99,7 @@ class ShardedTrainer:
         updaters = [net._layer_updater(i) for i in range(len(net.layers))]
         p_sh, s_sh, o_sh, b_sh, repl = self._shardings()
 
+        from deeplearning4j_tpu import kernels
         from deeplearning4j_tpu.nn.multilayer import _normalize_grads
         from deeplearning4j_tpu.telemetry import health as _health
 
@@ -118,8 +119,12 @@ class ShardedTrainer:
                     return scaler.scale_loss(loss, prec), (loss, ns)
                 return loss, (loss, ns)
 
-            (_, (loss, new_states)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(params)
+            # a Mosaic kernel cannot be partitioned by GSPMD (jax raises
+            # at lowering): inside this scope the recurrence kernels
+            # run per batch shard under shard_map
+            with kernels.batch_sharded(self.mesh, DATA_AXIS):
+                (_, (loss, new_states)), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
             if scaling:
                 grads = scaler.unscale(grads, prec)
                 finite = scaler.all_finite(grads)
@@ -574,8 +579,11 @@ class ParallelInference:
             p_sh = jax.tree_util.tree_map(lambda _: repl, net._params)
             s_sh = jax.tree_util.tree_map(lambda _: repl, net._states)
 
+            from deeplearning4j_tpu import kernels
+
             def fn(params, states, xb):
-                y, _ = net._forward(params, states, xb, False, None)
+                with kernels.batch_sharded(mesh, DATA_AXIS):
+                    y, _ = net._forward(params, states, xb, False, None)
                 return y
 
             self._fn = jax.jit(fn, in_shardings=(p_sh, s_sh, b_sh),

@@ -3,11 +3,9 @@
 Reference capability: tier-2 config — `Nd4jEnvironment` / ND4J system
 properties and the scattered XLA/platform flags (SURVEY.md §5 "Config /
 flag system": "tier 2 becomes XLA/PJRT flags behind one typed config
-class"). Round 1 set these inline per entry point (conftest.py,
-__graft_entry__.py), which is exactly the scatter that broke the driver's
-multichip check (VERDICT.md weak item 1) — this module is the one place
-that owns platform selection, virtual device counts, matmul precision and
-debug toggles.
+class"). This module is the one place that owns platform selection,
+virtual device counts, matmul precision, debug toggles and the location
+of JAX's persistent compilation cache.
 
 Usage (must run BEFORE the first jax backend touch for platform changes):
 
@@ -84,6 +82,32 @@ class RuntimeConfig:
         if self.disable_jit:
             jax.config.update("jax_disable_jit", True)
         return self
+
+    @staticmethod
+    def enable_compile_cache() -> str:
+        """Place JAX's persistent compilation cache; returns its path.
+
+        Called by the entry points (chip_smoke.py, bench.py main,
+        fleet/worker.py main) before their first compile — never at
+        package import and never from tests/conftest.py: tier-1 tests
+        count backend compiles, and a warm cache would change the counts.
+
+        ``JAX_COMPILATION_CACHE_DIR`` wins: when it is set this function
+        sets nothing (JAX reads the variable itself), so whoever runs the
+        program decides where the cache lives. Unset, the cache goes to
+        ``<checkout>/.jax_cache``, derived from this file's location —
+        a fixed path, because the directory is part of what a later run
+        must find again."""
+        env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if env:
+            return env
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        import jax
+
+        jax.config.update("jax_compilation_cache_dir", path)
+        return path
 
     @staticmethod
     def cpu_mesh(n_devices: int = 8,

@@ -335,9 +335,7 @@ class CompileLedger:
             rec["hlo_fingerprint"] = hlo_audit.fingerprint(
                 lowered.as_text())
             analysis = lowered.cost_analysis()
-            if isinstance(analysis, (list, tuple)):
-                analysis = analysis[0] if analysis else None
-            if isinstance(analysis, dict):
+            if analysis is not None:
                 rec["flops"] = float(analysis.get("flops", 0.0))
         except Exception:
             pass

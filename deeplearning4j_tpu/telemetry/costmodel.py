@@ -29,10 +29,12 @@ Published metrics (canonical list in docs/OBSERVABILITY.md):
 - ``dl4j_mfu{executable}`` — live model-FLOP utilization:
   ``flops / (step_seconds * peak_flops)``, refreshed every recorded
   step once the loop's FLOP count is known. Peak FLOP/s comes from
-  :func:`peak_flops` (TPU detection, ``DL4J_PEAK_FLOPS`` override,
-  :func:`set_peak_flops`); without a known peak the MFU gauge is
-  simply not published (a made-up CPU peak would be noise, not
-  observability).
+  :func:`peak_flops` (the :data:`TPU_PEAK_FLOPS` table keyed by
+  ``device_kind``, ``DL4J_PEAK_FLOPS`` override,
+  :func:`set_peak_flops`). On a non-TPU device there is no peak and the
+  MFU gauge is simply not published (a made-up CPU peak would be noise,
+  not observability); a TPU whose kind is not in the table is an error,
+  never a silently missing gauge.
 
 Overhead guard: training-loop attribution is *throttled by step time*
 (``min_step_seconds``, default 20 ms): a fleet of sub-millisecond unit
@@ -62,9 +64,11 @@ MFU_HELP = ("Live model-FLOP utilization: cost-model FLOPs per step / "
             "(step seconds * peak FLOP/s); published once the loop's "
             "executable is attributed and a hardware peak is known")
 
-# TPU v5e bf16 peak (bench.py's V5E_PEAK_BF16); other TPU generations
-# fall back to the env override
-_TPU_PEAKS = {"v5e": 197e12, "v5litepod": 197e12}
+# Peak dense bf16 FLOP/s of ONE chip, keyed by the exact ``device_kind``
+# string jax reports for it (chip_smoke.py prints it). Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16; a v5e chip reports
+# itself as "TPU v5 lite".
+TPU_PEAK_FLOPS = {"TPU v5 lite": 197e12}
 
 _state = {"min_step_seconds": 0.02, "peak": None, "peak_resolved": False}
 _lock = threading.Lock()
@@ -92,7 +96,8 @@ def set_peak_flops(peak):
 
 def peak_flops():
     """Peak FLOP/s for MFU: explicit override > ``DL4J_PEAK_FLOPS`` >
-    TPU device-kind detection > None (MFU unpublished)."""
+    :data:`TPU_PEAK_FLOPS` by ``device_kind``. None on a non-TPU device
+    (MFU unpublished); a TPU kind missing from the table raises."""
     with _lock:
         if _state["peak_resolved"]:
             return _state["peak"]
@@ -105,18 +110,18 @@ def peak_flops():
             log.warning("DL4J_PEAK_FLOPS=%r is not a number; ignored",
                         env)
     if peak is None:
-        try:
-            import jax
+        import jax
 
-            dev = jax.devices()[0]
-            if dev.platform == "tpu":
-                kind = getattr(dev, "device_kind", "").lower()
-                for tag, p in _TPU_PEAKS.items():
-                    if tag in kind:
-                        peak = p
-                        break
-        except Exception:
-            peak = None
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            if dev.device_kind not in TPU_PEAK_FLOPS:
+                raise LookupError(
+                    f"no peak FLOP/s known for TPU device_kind "
+                    f"{dev.device_kind!r} (table: "
+                    f"{sorted(TPU_PEAK_FLOPS)}); add it to "
+                    f"telemetry.costmodel.TPU_PEAK_FLOPS with its source "
+                    f"or set DL4J_PEAK_FLOPS")
+            peak = TPU_PEAK_FLOPS[dev.device_kind]
     with _lock:
         _state["peak"] = peak
         _state["peak_resolved"] = True
@@ -126,14 +131,6 @@ def peak_flops():
 # ---------------------------------------------------------------------------
 # analysis plumbing
 # ---------------------------------------------------------------------------
-
-def _first(analysis):
-    """cost_analysis() returns a dict (or a 1-list of dicts on older
-    jax); normalize."""
-    if isinstance(analysis, (list, tuple)):
-        analysis = analysis[0] if analysis else None
-    return analysis if isinstance(analysis, dict) else None
-
 
 def _publish_flops(executable, flops, registry=None):
     if not _registry.enabled():
@@ -192,7 +189,7 @@ def step_cost(executable, jitted, args, cache=None):
         return flops
     flops = None
     try:
-        analysis = _first(jitted.lower(*args).cost_analysis())
+        analysis = jitted.lower(*args).cost_analysis()
         if analysis is not None:
             flops = float(analysis.get("flops", 0.0))
     except Exception as e:
@@ -263,7 +260,7 @@ def executable_cost(executable, compiled, registry=None):
     reg = registry if registry is not None else _registry.get_registry()
     flops = None
     try:
-        analysis = _first(compiled.cost_analysis())
+        analysis = compiled.cost_analysis()
         if analysis is not None:
             flops = float(analysis.get("flops", 0.0))
             _publish_flops(executable, flops, registry=reg)

@@ -19,7 +19,10 @@ facts an operator acts on:
   ``.remat`` suffix jax.checkpoint leaves behind — rematerialization
   trades FLOPs for memory and should be a *decision*, not a surprise;
 - **largest buffers** by result-type byte size — the first question
-  when ``memory_analysis()`` temp bytes look wrong.
+  when ``memory_analysis()`` temp bytes look wrong;
+- **custom calls by target** — a Pallas/Mosaic kernel shows up as
+  ``tpu_custom_call``; chip_smoke.py reads this to prove the compiled
+  ``fit()`` step holds the recurrence kernels and not the ``lax.scan``.
 
 The parser is a line-oriented state machine over HLO text — no XLA
 bindings, so it audits a dumped module in a test as happily as a live
@@ -47,6 +50,9 @@ _SHAPE_RE = re.compile(r"\b([a-z][a-z0-9]*)\[([0-9,]*)\]")
 # computation headers: "%fused_computation.1 (p: f32[..]) -> .. {" /
 # "ENTRY %main.5 (...) -> .. {"
 _COMP_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w\.\-]+)\s*\(.*\)\s*->.*\{")
+_TARGET_RE = re.compile(r'custom_call_target="([^"]+)"')
+# the target every Pallas/Mosaic TPU kernel compiles to
+MOSAIC_TARGET = "tpu_custom_call"
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
@@ -87,6 +93,8 @@ def audit_text(hlo: str) -> dict:
     opt_barriers = 0
     remat_ops = 0
     custom_calls = 0
+    custom_call_targets: dict = {}
+    mosaic_results: list = []
     ops = 0
     computations = 0
     fused_computations = 0
@@ -128,6 +136,18 @@ def audit_text(hlo: str) -> dict:
             opt_barriers += 1
         if opcode == "custom-call":
             custom_calls += 1
+            found = _TARGET_RE.search(rhs)
+            target = found.group(1) if found is not None else None
+            if target is not None:
+                custom_call_targets[target] = \
+                    custom_call_targets.get(target, 0) + 1
+            if target == MOSAIC_TARGET:
+                # the kernel's first result, e.g. "f32[100,1024,256]":
+                # under a mesh this is the PER-DEVICE shape, which says
+                # whether the kernel saw its batch shard or the whole
+                first = _SHAPE_RE.search(rhs[:m.start()])
+                if first is not None:
+                    mosaic_results.append(first.group(0))
         if ".remat" in name:
             remat_ops += 1
         nbytes, label = _result_bytes(rhs)
@@ -149,6 +169,8 @@ def audit_text(hlo: str) -> dict:
                         "total": sum(collectives.values())},
         "remat": {"opt_barriers": opt_barriers, "remat_ops": remat_ops},
         "custom_calls": custom_calls,
+        "custom_call_targets": custom_call_targets,
+        "mosaic_results": mosaic_results,
         "opcode_histogram": top_ops,
         "largest_buffers": [
             {"bytes": b, "type": t, "op": n}
@@ -172,9 +194,7 @@ def audit_compiled(compiled) -> dict:
     out["module_bytes"] = len(text)
     try:
         analysis = compiled.cost_analysis()
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else None
-        if isinstance(analysis, dict):
+        if analysis is not None:
             out["flops"] = float(analysis.get("flops", 0.0))
             out["bytes_accessed"] = float(
                 analysis.get("bytes accessed", 0.0))

@@ -37,12 +37,7 @@ class GradientCheckUtil:
         device_scope = (jax.default_device(cpu) if cpu is not None
                         else contextlib.nullcontext())
 
-        try:
-            x64_scope = jax.enable_x64
-        except AttributeError:  # removed from the jax root namespace
-            from jax.experimental import enable_x64 as x64_scope
-
-        with device_scope, x64_scope():
+        with device_scope, jax.enable_x64():
             # ascontiguousarray is load-bearing: XLA buffers can expose
             # non-C-contiguous layouts through np.asarray, making
             # reshape(-1) below return a COPY and perturbations silently

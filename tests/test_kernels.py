@@ -135,3 +135,77 @@ class TestGruPallasParity:
             np.testing.assert_allclose(
                 np.asarray(a), np.asarray(b), rtol=2e-4, atol=1e-5,
                 err_msg=name)
+
+
+class TestRecurrenceRouting:
+    """The kernel-or-scan decision is made in one place, counted, and
+    under a batch-sharded step the kernel runs per shard (a bare Mosaic
+    call inside a multi-device GSPMD jit is a lowering error on TPU)."""
+
+    def test_route_is_counted_and_gated(self, monkeypatch):
+        from deeplearning4j_tpu import kernels, telemetry
+
+        reg = telemetry.get_registry()
+
+        def count(op, route):
+            fam = reg.counter("dl4j_recurrence_route_total",
+                              kernels.ROUTE_HELP, ("op", "route"))
+            return fam.labels(op=op, route=route).value
+
+        before = count("LSTM", "scan")
+        assert kernels.recurrence_route("LSTM", True) == "scan"  # cpu
+        assert count("LSTM", "scan") == before + 1
+        monkeypatch.setenv("DL4J_PALLAS_INTERPRET", "1")
+        assert kernels.recurrence_route("LSTM", True) == "interpret"
+        assert kernels.recurrence_route("LSTM", False) == "scan"
+        monkeypatch.setenv("DL4J_DISABLE_PALLAS_LSTM", "1")
+        assert kernels.recurrence_route("LSTM", True) == "scan"
+        assert kernels.recurrence_route("GRU", True) == "interpret"
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert kernels.recurrence_route("GRU", True) == "pallas"
+
+    def test_sharded_fit_matches_single_device(self, monkeypatch):
+        """ShardedTrainer over 4 devices with the kernel routed per
+        batch shard == the same fit on one device (scan route): the
+        shard_map transpose must sum dR over the data axis."""
+        from deeplearning4j_tpu import kernels
+        from deeplearning4j_tpu.nn import (InputType, LSTM,
+                                           MultiLayerNetwork,
+                                           NeuralNetConfiguration,
+                                           RnnOutputLayer)
+        from deeplearning4j_tpu.optimize.updaters import Sgd
+        from deeplearning4j_tpu.parallel.mesh import MeshConfig
+        from deeplearning4j_tpu.parallel.trainer import ShardedTrainer
+
+        def build():
+            conf = (NeuralNetConfiguration.Builder().seed(3)
+                    .updater(Sgd(0.1)).list()
+                    .layer(LSTM.Builder().nOut(128).activation("tanh")
+                           .build())
+                    .layer(RnnOutputLayer.Builder().nOut(5)
+                           .activation("softmax").build())
+                    .setInputType(InputType.recurrent(5, 6)).build())
+            return MultiLayerNetwork(conf).init()
+
+        rng = np.random.default_rng(0)
+        ids = rng.integers(0, 5, (32, 7))
+        x = np.eye(5, dtype=np.float32)[ids[:, :-1]].transpose(0, 2, 1)
+        y = np.eye(5, dtype=np.float32)[ids[:, 1:]].transpose(0, 2, 1)
+
+        ref = build()
+        ref.fit([(x, y)] * 2)
+        monkeypatch.setenv("DL4J_PALLAS_INTERPRET", "1")
+        net = build()
+        mesh = MeshConfig(data=4, devices=jax.devices()[:4]).build()
+        seen = []
+        real = kernels.per_batch_shard
+        monkeypatch.setattr(
+            kernels, "per_batch_shard",
+            lambda fn, n, *a, **k: (seen.append(kernels.shard_rows(n)),
+                                    real(fn, n, *a, **k))[1])
+        ShardedTrainer(net, mesh).fit([(x, y)] * 2)
+        assert seen and set(seen) == {8}, seen   # 32 rows / 4 devices
+        for a, b in zip(jax.tree_util.tree_leaves(ref._params),
+                        jax.tree_util.tree_leaves(net._params)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-4, atol=2e-5)

@@ -206,6 +206,40 @@ class TestTransportBitIdentity:
             np.testing.assert_array_equal(a[0], b[0])
             np.testing.assert_array_equal(a[1], b[1])
 
+    def test_refused_ring_auto_takes_queue_shm_raises(self, tmp_path,
+                                                      monkeypatch):
+        """A host that refuses the ring's segment (EFBIG under a
+        file-size limit): "auto" warns and ships the same batches
+        through the queue, an explicit "shm" raises."""
+        import errno
+
+        from deeplearning4j_tpu.datasets import parallel_etl
+
+        _write_image_tree(tmp_path, n_per_class=8)
+        serial = self._batches(tmp_path, transport="serial")
+
+        def refuse(slots, slot_bytes):
+            raise OSError(errno.EFBIG, "File too large")
+
+        monkeypatch.setattr(parallel_etl, "ShmRing", refuse)
+        it = ParallelImageDataSetIterator(
+            FileSplit(str(tmp_path)), 8, 8, 3, batchSize=4, numWorkers=2)
+        assert it.transport == "shm"
+        with pytest.warns(RuntimeWarning, match="falls back to the queue"):
+            auto = _collect(it)
+        assert it.transport == "queue"
+        for a, b in zip(serial, auto):
+            np.testing.assert_array_equal(a[0], b[0])
+            np.testing.assert_array_equal(a[1], b[1])
+        it = ParallelImageDataSetIterator(
+            FileSplit(str(tmp_path)), 8, 8, 3, batchSize=4, numWorkers=2,
+            transport="shm")
+        try:
+            with pytest.raises(OSError, match="File too large"):
+                it.next()
+        finally:
+            it.close()
+
     def test_uint8_output_casts_to_float_path(self, tmp_path):
         """floatOutput=False ships the decode's uint8 straight through;
         casting it reproduces the float32 output exactly (what lets the

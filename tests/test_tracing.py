@@ -717,6 +717,49 @@ class TestCostModel:
             costmodel.configure(min_step_seconds=0.02)
 
 
+class TestPeakTable:
+    """The MFU denominator is keyed by the device_kind string a chip
+    reports (ISSUE 21): a v5e says "TPU v5 lite"; an unknown TPU is an
+    error, the CPU has no peak."""
+
+    @staticmethod
+    def _stub(monkeypatch, platform, kind):
+        import types
+
+        import jax
+
+        dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+        monkeypatch.setattr(jax, "devices", lambda *a, **k: [dev])
+        monkeypatch.delenv("DL4J_PEAK_FLOPS", raising=False)
+        costmodel.set_peak_flops(None)
+
+    def test_v5e_kind_resolves_to_its_published_peak(self, monkeypatch):
+        self._stub(monkeypatch, "tpu", "TPU v5 lite")
+        try:
+            assert costmodel.peak_flops() == 197e12
+        finally:
+            costmodel.set_peak_flops(None)
+
+    def test_unknown_tpu_kind_raises(self, monkeypatch):
+        self._stub(monkeypatch, "tpu", "TPU v9 imaginary")
+        with pytest.raises(LookupError, match="TPU v9 imaginary"):
+            costmodel.peak_flops()
+        # the override is the way out, not a silent None
+        monkeypatch.setenv("DL4J_PEAK_FLOPS", "1e15")
+        try:
+            assert costmodel.peak_flops() == 1e15
+        finally:
+            costmodel.set_peak_flops(None)
+
+    def test_cpu_has_no_peak_and_mfu_stays_unpublished(self, monkeypatch):
+        self._stub(monkeypatch, "cpu", "cpu")
+        try:
+            assert costmodel.peak_flops() is None
+            assert costmodel.publish_mfu("fit", 1e9, 0.01) is None
+        finally:
+            costmodel.set_peak_flops(None)
+
+
 # ---------------------------------------------------------------------------
 # /debug/traces route
 # ---------------------------------------------------------------------------
