@@ -17,6 +17,7 @@ the LM head ties the embedding matrix."""
 
 from __future__ import annotations
 
+import contextlib
 import math
 from dataclasses import dataclass
 
@@ -511,21 +512,30 @@ class BertTrainer:
         """tokens [B,T] int32; labels [B,T] with -100 at unmasked
         positions. The masked-position gather happens host-side so the
         device step only scores the ~15% of positions that matter."""
+        from deeplearning4j_tpu import telemetry
+
         if self._step_fn is None:
             self._step_fn = self._build()
-        positions, mlm_labels, weights = mlm_gather(
-            labels, max_preds=self._max_preds(np.asarray(tokens).shape[1]))
-        # rbg PRNG: XLA's RngBitGenerator is far cheaper than threefry for
-        # the ~380M dropout bits a BERT-base step draws (~17 ms/step on
-        # v5e); dropout only needs statistical, not reproducible-forever,
-        # randomness
-        rng = jax.random.key(self._step + 1, impl="rbg")
-        # step counter as a traced scalar — a static arg would recompile
-        # the executable every step
-        loss, self.params, self.opt = self._step_fn(
-            self.params, self.opt, jnp.asarray(tokens, jnp.int32),
-            positions, mlm_labels, weights, rng,
-            jnp.asarray(self._step, jnp.int32))
+        # the step's two host phases as spans on the profiler's clock
+        # (pure annotations; nothing is made when telemetry is off)
+        span = (telemetry.span if telemetry.enabled()
+                else contextlib.nullcontext)
+        with span("dl4j.train.gather"):
+            positions, mlm_labels, weights = mlm_gather(
+                labels,
+                max_preds=self._max_preds(np.asarray(tokens).shape[1]))
+        with span("dl4j.train.dispatch"):
+            # rbg PRNG: XLA's RngBitGenerator is far cheaper than
+            # threefry for the ~380M dropout bits a BERT-base step draws
+            # (~17 ms/step on v5e); dropout only needs statistical, not
+            # reproducible-forever, randomness
+            rng = jax.random.key(self._step + 1, impl="rbg")
+            # step counter as a traced scalar — a static arg would
+            # recompile the executable every step
+            loss, self.params, self.opt = self._step_fn(
+                self.params, self.opt, jnp.asarray(tokens, jnp.int32),
+                positions, mlm_labels, weights, rng,
+                jnp.asarray(self._step, jnp.int32))
         self._step += 1
         return loss
 

@@ -46,6 +46,7 @@ makes continuous batching safe to enable by default.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import queue as _queue
 import threading
@@ -63,6 +64,15 @@ class DecodeError(RuntimeError):
 
 class DecodeShutdown(RuntimeError):
     """Engine closed with this request still pending."""
+
+
+def _phase(inst, phase):
+    """The Timer over one phase of a boundary (histogram and
+    `dl4j.decode.<phase>` span), or nothing when telemetry is off. The
+    five phases are leaves that tile an iteration of the engine's loop;
+    no span stands around the whole of it, so that a reader of a device
+    trace can give every idle gap to the phase that covers it."""
+    return contextlib.nullcontext() if inst is None else inst.phase(phase)
 
 
 # ---------------------------------------------------------------------------
@@ -1081,7 +1091,7 @@ class DecodeEngine:
             return plan, dplan
         return plan, None
 
-    def _admit(self):
+    def _admit(self, inst):
         """Move pending requests into free slots at this token
         boundary. The submit queue drains into an engine-private FIFO
         first, so a request that can't get its KV pages yet
@@ -1136,7 +1146,6 @@ class DecodeEngine:
                     # always remains — match() never covers the last)
                     req.ptr = adopted * self._kv.page
                 if self._pcache is not None:
-                    inst = self._instruments_fn()
                     if adopted:
                         self._pcache.hits += 1
                         if inst is not None:
@@ -1148,11 +1157,13 @@ class DecodeEngine:
             self._state = self.model.reset_slot(self._state, slot)
             self._active[slot] = req
             admitted += 1
+            # submit -> slot join: the decode analog of queue-wait
+            t_join = time.perf_counter()
+            if inst is not None:
+                inst.decode_queue_wait.observe(t_join - req.t_submit)
             if req.trace is not None:
-                # submit -> slot join: the decode analog of queue-wait
                 tracing.emit("decode.queue", req.trace, req.t_submit,
-                             time.perf_counter(), slot=slot,
-                             req_id=req.req_id)
+                             t_join, slot=slot, req_id=req.req_id)
             flight.record("decode_join", model=self.name,
                           req_id=req.req_id, slot=slot,
                           prompt=len(req.prompt), max_new=req.max_new,
@@ -1233,6 +1244,18 @@ class DecodeEngine:
         return (len(req.generated) >= req.max_new
                 or (req.eos_id is not None and tok == req.eos_id))
 
+    def _boundary_done(self, inst, executable, prompt=0, answer=0):
+        """What the engine counts once a boundary, at the end of its
+        emit phase: the boundary, the positions it fed, and the gauges
+        with the pool's fill summed beside its gauge (a mean without
+        polling)."""
+        inst.boundary(executable, prompt, answer)
+        inst.slots.set(len(self._active))
+        if self._kv is not None:
+            fill = self._kv.used_pages / max(1, self._kv.n_pages)
+            inst.kv_occupancy.set(fill)
+            inst.kv_fill_sum.inc(fill)
+
     def _prefill_boundary(self, inst) -> bool:
         """Boundary phase 1 (ISSUE 12 tentpole a): retire up to
         ``chunk`` prompt tokens per prefilling slot through the block
@@ -1246,25 +1269,29 @@ class DecodeEngine:
             return True
         S = self.model.max_slots
         C = self._block.chunk
-        blocks = np.zeros((S, C), np.int32)
-        pos0 = np.zeros((S,), np.int32)
-        counts = np.zeros((S,), np.int32)
-        for slot, req in todo.items():
-            n = min(C, len(req.prompt) - 1 - req.ptr)
-            blocks[slot, :n] = req.prompt[req.ptr:req.ptr + n]
-            pos0[slot] = req.ptr
-            counts[slot] = n
-        # a REAL copy, not ascontiguousarray (which aliases an
-        # already-contiguous table): admission mutates the table
-        # between boundaries, and jax may zero-copy numpy inputs
-        table = self._table.copy()
+        with _phase(inst, "build"):
+            blocks = np.zeros((S, C), np.int32)
+            pos0 = np.zeros((S,), np.int32)
+            counts = np.zeros((S,), np.int32)
+            for slot, req in todo.items():
+                n = min(C, len(req.prompt) - 1 - req.ptr)
+                blocks[slot, :n] = req.prompt[req.ptr:req.ptr + n]
+                pos0[slot] = req.ptr
+                counts[slot] = n
+            # a REAL copy, not ascontiguousarray (which aliases an
+            # already-contiguous table): admission mutates the table
+            # between boundaries, and jax may zero-copy numpy inputs
+            table = self._table.copy()
         t_b0 = time.perf_counter()
         try:
-            _, self._state = self._block.run(
-                self._state, blocks, pos0, counts, table,
-                site=f"decode:{self.name}:prefill")
-            if self._spec is not None:
-                self._spec.prefill(blocks, pos0, counts)
+            with _phase(inst, "dispatch"):
+                outs, self._state = self._block.launch(
+                    self._state, blocks, pos0, counts, table,
+                    site=f"decode:{self.name}:prefill")
+            with _phase(inst, "readback"):
+                np.asarray(outs)    # prefill wants none of it: the wait
+                if self._spec is not None:
+                    self._spec.prefill(blocks, pos0, counts)
         except Exception as e:
             # OOM forensics (ISSUE 14): a device allocation failure at
             # this boundary fails the requests with the typed error
@@ -1274,17 +1301,21 @@ class DecodeEngine:
                 self._finish(req, error=err)
             return False
         t_b1 = time.perf_counter()
-        self._last_boundary = time.monotonic()
-        for slot, req in todo.items():
-            if self._active.get(slot) is not req:
-                continue
-            req.ptr += int(counts[slot])
-            if req.trace is not None and \
-                    req.spans_emitted < self.boundary_span_cap:
-                req.spans_emitted += 1
-                tracing.emit("decode.prefill_chunk", req.trace, t_b0,
-                             t_b1, slot=slot,
-                             tokens=int(counts[slot]), pos=req.ptr)
+        with _phase(inst, "emit"):
+            self._last_boundary = time.monotonic()
+            for slot, req in todo.items():
+                if self._active.get(slot) is not req:
+                    continue
+                req.ptr += int(counts[slot])
+                if req.trace is not None and \
+                        req.spans_emitted < self.boundary_span_cap:
+                    req.spans_emitted += 1
+                    tracing.emit("decode.prefill_chunk", req.trace,
+                                 t_b0, t_b1, slot=slot,
+                                 tokens=int(counts[slot]), pos=req.ptr)
+            if inst is not None:
+                self._boundary_done(inst, "prefill",
+                                    prompt=int(counts.sum()))
         return True
 
     def _step_boundary(self, inst):
@@ -1292,30 +1323,37 @@ class DecodeEngine:
         PR-8 path, semantics unchanged: every active slot advances one
         token (prefilling slots feed their next prompt token)."""
         S = self.model.max_slots
-        tokens = np.zeros((S,), np.int32)
-        pos = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        # snapshot: close() may clear _active concurrently
-        for slot, req in list(self._active.items()):
-            if req.ptr < len(req.prompt):
-                tokens[slot] = req.prompt[req.ptr]
-            else:
-                tokens[slot] = req.generated[-1]
-            pos[slot] = req.ptr
-            active[slot] = True
-        # a REAL copy, not ascontiguousarray (which aliases an
-        # already-contiguous table): admission mutates the table
-        # between boundaries, and jax may zero-copy numpy inputs
-        table = self._table.copy()
+        with _phase(inst, "build"):
+            tokens = np.zeros((S,), np.int32)
+            pos = np.zeros((S,), np.int32)
+            active = np.zeros((S,), bool)
+            n_prompt = n_answer = 0
+            # snapshot: close() may clear _active concurrently
+            for slot, req in list(self._active.items()):
+                if req.ptr < len(req.prompt):
+                    tokens[slot] = req.prompt[req.ptr]
+                    n_prompt += 1
+                else:
+                    tokens[slot] = req.generated[-1]
+                    n_answer += 1
+                pos[slot] = req.ptr
+                active[slot] = True
+            # a REAL copy, not ascontiguousarray (which aliases an
+            # already-contiguous table): admission mutates the table
+            # between boundaries, and jax may zero-copy numpy inputs
+            table = self._table.copy()
         t_b0 = time.perf_counter()
         try:
-            nxt, self._state = self._model_step(self._state, tokens,
-                                                pos, table)
-            nxt = np.asarray(nxt)
-            if self._spec is not None:
-                # fallback boundaries keep the draft pool in sync so
-                # a later speculation probe proposes from real context
-                self._spec.track(tokens, pos, active)
+            with _phase(inst, "dispatch"):
+                nxt, self._state = self._model_step(self._state, tokens,
+                                                    pos, table)
+                if self._spec is not None:
+                    # fallback boundaries keep the draft pool in sync so
+                    # a later speculation probe proposes from real
+                    # context
+                    self._spec.track(tokens, pos, active)
+            with _phase(inst, "readback"):
+                nxt = np.asarray(nxt)
         except Exception as e:
             err = _boundary_error(e, f"decode:{self.name}:step",
                                   "decode step failed")
@@ -1323,42 +1361,44 @@ class DecodeEngine:
                 self._finish(req, error=err)
             return
         t_b1 = time.perf_counter()
-        self._last_boundary = time.monotonic()
-        n_decoded = 0
-        for slot, req in list(self._active.items()):
-            prefilling = req.ptr + 1 < len(req.prompt)
-            if req.trace is not None:
-                # one child span per token boundary this sequence
-                # took part in (ISSUE 10): prefill and decode
-                # interleave through the same executable, and the
-                # span name says which phase this boundary was.
-                # Capped per request: a near-max_new generation
-                # would otherwise evict every concurrent trace
-                # (including its own early spans) from the bounded
-                # ring — boundaries past the cap aggregate into
-                # one decode.tokens span at finish.
-                if req.spans_emitted < self.boundary_span_cap:
-                    req.spans_emitted += 1
-                    tracing.emit(
-                        "decode.prefill" if prefilling
-                        else "decode.token",
-                        req.trace, t_b0, t_b1, slot=slot,
-                        pos=req.ptr)
-                elif req.t_suppressed is None:
-                    req.t_suppressed = t_b0
-            req.ptr += 1
-            self._publish(req, slot)
-            if req.ptr < len(req.prompt):
-                continue            # still prefilling
-            tok = int(nxt[slot])
-            done = self._emit_token(req, tok, inst)
-            n_decoded += 1
-            if self._spec is not None and inst is not None:
-                inst.accepted("fallback", 1)
-            if done:
-                self._finish(req)
-        if inst is not None:
-            inst.tokens.inc(n_decoded)
+        with _phase(inst, "emit"):
+            self._last_boundary = time.monotonic()
+            n_decoded = 0
+            for slot, req in list(self._active.items()):
+                prefilling = req.ptr + 1 < len(req.prompt)
+                if req.trace is not None:
+                    # one child span per token boundary this sequence
+                    # took part in (ISSUE 10): prefill and decode
+                    # interleave through the same executable, and the
+                    # span name says which phase this boundary was.
+                    # Capped per request: a near-max_new generation
+                    # would otherwise evict every concurrent trace
+                    # (including its own early spans) from the bounded
+                    # ring — boundaries past the cap aggregate into
+                    # one decode.tokens span at finish.
+                    if req.spans_emitted < self.boundary_span_cap:
+                        req.spans_emitted += 1
+                        tracing.emit(
+                            "decode.prefill" if prefilling
+                            else "decode.token",
+                            req.trace, t_b0, t_b1, slot=slot,
+                            pos=req.ptr)
+                    elif req.t_suppressed is None:
+                        req.t_suppressed = t_b0
+                req.ptr += 1
+                self._publish(req, slot)
+                if req.ptr < len(req.prompt):
+                    continue            # still prefilling
+                tok = int(nxt[slot])
+                done = self._emit_token(req, tok, inst)
+                n_decoded += 1
+                if self._spec is not None and inst is not None:
+                    inst.accepted("fallback", 1)
+                if done:
+                    self._finish(req)
+            if inst is not None:
+                inst.tokens.inc(n_decoded)
+                self._boundary_done(inst, "step", n_prompt, n_answer)
 
     def _speculative_boundary(self, inst):
         """Boundary phase 2, speculative (ISSUE 12 tentpole c): the
@@ -1374,33 +1414,37 @@ class DecodeEngine:
             self._step_boundary(inst)
             return
         V = self._spec.k + 1
-        feed = np.zeros((S,), np.int32)
-        pos = np.zeros((S,), np.int32)
-        active = np.zeros((S,), bool)
-        for slot, req in ready.items():
-            feed[slot] = (req.prompt[req.ptr]
-                          if req.ptr < len(req.prompt)
-                          else req.generated[-1])
-            pos[slot] = req.ptr
-            active[slot] = True
-        # a REAL copy, not ascontiguousarray (which aliases an
-        # already-contiguous table): admission mutates the table
-        # between boundaries, and jax may zero-copy numpy inputs
-        table = self._table.copy()
+        with _phase(inst, "build"):
+            feed = np.zeros((S,), np.int32)
+            pos = np.zeros((S,), np.int32)
+            active = np.zeros((S,), bool)
+            for slot, req in ready.items():
+                feed[slot] = (req.prompt[req.ptr]
+                              if req.ptr < len(req.prompt)
+                              else req.generated[-1])
+                pos[slot] = req.ptr
+                active[slot] = True
+            # a REAL copy, not ascontiguousarray (which aliases an
+            # already-contiguous table): admission mutates the table
+            # between boundaries, and jax may zero-copy numpy inputs
+            table = self._table.copy()
         t_b0 = time.perf_counter()
         try:
-            drafts = self._spec.propose(feed, pos, active)
-            blocks = np.zeros((S, V), np.int32)
-            counts = np.zeros((S,), np.int32)
-            for slot, req in ready.items():
-                c = min(V, req.max_new - len(req.generated))
-                blocks[slot, 0] = feed[slot]
-                if c > 1:
-                    blocks[slot, 1:c] = drafts[slot, :c - 1]
-                counts[slot] = c
-            outs, self._state = self._block.run(
-                self._state, blocks, pos, counts, table,
-                site=f"decode:{self.name}:verify")
+            with _phase(inst, "dispatch"):
+                drafts = self._spec.propose(feed, pos, active)
+                blocks = np.zeros((S, V), np.int32)
+                counts = np.zeros((S,), np.int32)
+                for slot, req in ready.items():
+                    c = min(V, req.max_new - len(req.generated))
+                    blocks[slot, 0] = feed[slot]
+                    if c > 1:
+                        blocks[slot, 1:c] = drafts[slot, :c - 1]
+                    counts[slot] = c
+                outs, self._state = self._block.launch(
+                    self._state, blocks, pos, counts, table,
+                    site=f"decode:{self.name}:verify")
+            with _phase(inst, "readback"):
+                outs = np.asarray(outs)
         except Exception as e:
             err = _boundary_error(e, f"decode:{self.name}:verify",
                                   "speculative decode failed")
@@ -1408,62 +1452,77 @@ class DecodeEngine:
                 self._finish(req, error=err)
             return
         t_b1 = time.perf_counter()
-        self._last_boundary = time.monotonic()
-        n_decoded = 0
-        for slot, req in ready.items():
-            if self._active.get(slot) is not req:
-                continue
-            c = int(counts[slot])
-            if c < 1:
-                continue
-            # o_0 is the target's answer to the real last token (always
-            # valid); each later o_j is valid iff the draft proposal fed
-            # at j matched o_{j-1} — the greedy acceptance rule
-            m = 1
-            while m < c and \
-                    int(blocks[slot, m]) == int(outs[slot, m - 1]):
-                m += 1
-            self._spec.observe(m, c)
-            if inst is not None:
-                inst.accepted("accepted", m)
-                if c > m:
-                    inst.accepted("rejected", c - m)
-            if req.trace is not None and \
-                    req.spans_emitted < self.boundary_span_cap:
-                req.spans_emitted += 1
-                tracing.emit("decode.speculate", req.trace, t_b0, t_b1,
-                             slot=slot, drafted=c - 1, accepted=m,
-                             pos=req.ptr)
-            # rejected positions were written past the accepted point
-            # in both pools — above the causal mask until the true
-            # tokens overwrite those same positions (no rollback)
-            req.ptr += m
-            self._publish(req, slot)
-            done = False
-            for j in range(m):
-                done = self._emit_token(req, int(outs[slot, j]), inst)
-                n_decoded += 1
+        with _phase(inst, "emit"):
+            self._last_boundary = time.monotonic()
+            n_decoded = n_prompt = n_answer = 0
+            for slot, req in ready.items():
+                if self._active.get(slot) is not req:
+                    continue
+                c = int(counts[slot])
+                if c < 1:
+                    continue
+                # o_0 is the target's answer to the real last token
+                # (always valid); each later o_j is valid iff the draft
+                # proposal fed at j matched o_{j-1} — the greedy
+                # acceptance rule
+                m = 1
+                while m < c and \
+                        int(blocks[slot, m]) == int(outs[slot, m - 1]):
+                    m += 1
+                self._spec.observe(m, c)
+                if inst is not None:
+                    inst.accepted("accepted", m)
+                    if c > m:
+                        inst.accepted("rejected", c - m)
+                if req.trace is not None and \
+                        req.spans_emitted < self.boundary_span_cap:
+                    req.spans_emitted += 1
+                    tracing.emit("decode.speculate", req.trace, t_b0,
+                                 t_b1, slot=slot, drafted=c - 1,
+                                 accepted=m, pos=req.ptr)
+                # of the m positions accepted, the first was the prompt's
+                # last token where the slot came straight from prefill
+                first_is_prompt = req.ptr < len(req.prompt)
+                n_prompt += first_is_prompt
+                n_answer += m - first_is_prompt
+                # rejected positions were written past the accepted
+                # point in both pools — above the causal mask until the
+                # true tokens overwrite those same positions (no
+                # rollback)
+                req.ptr += m
+                self._publish(req, slot)
+                done = False
+                for j in range(m):
+                    done = self._emit_token(req, int(outs[slot, j]),
+                                            inst)
+                    n_decoded += 1
+                    if done:
+                        break
                 if done:
-                    break
-            if done:
-                self._finish(req)
-        self._spec.boundary_done()
-        if inst is not None:
-            inst.tokens.inc(n_decoded)
+                    self._finish(req)
+            self._spec.boundary_done()
+            if inst is not None:
+                inst.tokens.inc(n_decoded)
+                self._boundary_done(inst, "verify", n_prompt, n_answer)
 
     def _loop(self):
         while not self._closed:
-            self._admit()
+            inst = self._instruments_fn()
+            # an idle poll leaves no admit observation behind: the phase
+            # is timed only where there is a request to admit or advance
+            busy = self._active or self._waiting or \
+                not self._pending.empty()
+            with _phase(inst if busy else None, "admit"):
+                self._admit(inst)
+                for req in list(self._active.values()):
+                    if not req.generated:
+                        req.ttft_boundaries += 1
             if not self._active:
                 self._last_boundary = None   # idle: nothing to wedge
                 self._wake.wait(0.05)
                 self._wake.clear()
                 continue
             self._last_boundary = time.monotonic()
-            for req in list(self._active.values()):
-                if not req.generated:
-                    req.ttft_boundaries += 1
-            inst = self._instruments_fn()
             if self._block is not None and \
                     not self._prefill_boundary(inst):
                 continue
@@ -1474,8 +1533,3 @@ class DecodeEngine:
                 self._speculative_boundary(inst)
             else:
                 self._step_boundary(inst)
-            if inst is not None:
-                inst.slots.set(len(self._active))
-                if self._kv is not None:
-                    inst.kv_occupancy.set(
-                        self._kv.used_pages / max(1, self._kv.n_pages))

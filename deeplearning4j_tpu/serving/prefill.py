@@ -87,12 +87,10 @@ class ChunkedPrefill:
         state, outs = lax.fori_loop(0, V, body, (state, outs0))
         return outs, state
 
-    def run(self, state, blocks, pos0, counts, table, site=None):
-        """Consume ``counts[s]`` tokens of ``blocks[s]`` per slot
-        starting at ``pos0[s]``. Returns ``(outs, state)`` where
-        ``outs[s, j]`` is the model's next-token argmax after consuming
-        block token j (-1 past a slot's count) — ignored by prefill,
-        consumed by speculative verify."""
+    def launch(self, state, blocks, pos0, counts, table, site=None):
+        """Dispatch the block and return without waiting: ``outs`` is
+        still on the device (the engine times the wait for it as its
+        own phase)."""
         args = (self.model.params_for_step(), state,
                 np.ascontiguousarray(blocks, dtype=np.int32),
                 np.ascontiguousarray(pos0, dtype=np.int32),
@@ -100,6 +98,16 @@ class ChunkedPrefill:
         outs, state = self._jit(*args)
         if site is not None:
             compile_ledger.note_step(site, self._jit, args, donation=())
+        return outs, state
+
+    def run(self, state, blocks, pos0, counts, table, site=None):
+        """Consume ``counts[s]`` tokens of ``blocks[s]`` per slot
+        starting at ``pos0[s]``. Returns ``(outs, state)`` where
+        ``outs[s, j]`` is the model's next-token argmax after consuming
+        block token j (-1 past a slot's count) — ignored by prefill,
+        consumed by speculative verify."""
+        outs, state = self.launch(state, blocks, pos0, counts, table,
+                                  site=site)
         return np.asarray(outs), state
 
     def warmup(self, state, table, widths=None, site=None):

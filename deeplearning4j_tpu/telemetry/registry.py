@@ -320,11 +320,6 @@ class MetricsRegistry:
         return self._get_or_create(Histogram, name, help, labelnames,
                                    buckets=buckets)
 
-    def timer(self, name, help="", labelnames=(),
-              buckets=SECONDS_BUCKETS) -> Timer:
-        """Timer over a same-named histogram (seconds)."""
-        return self.histogram(name, help, labelnames, buckets).time()
-
     def collect(self):
         """Metric families, name-sorted (exporter entry point). Copied
         under the lock so a concurrent first-time registration cannot
@@ -542,6 +537,23 @@ DECODE_ACCEPTED_HELP = ("Speculative-decode tokens by outcome: "
                         "is in acceptance fallback)")
 SERVING_KV_OCCUPANCY_HELP = ("Fraction of the paged decode KV pool "
                              "currently reserved (0..1)")
+DECODE_PHASES = ("admit", "build", "dispatch", "readback", "emit")
+DECODE_BOUNDARY_HELP = ("Seconds the decode engine's thread spent in "
+                        "each phase of a boundary (admit|build|dispatch|"
+                        "readback|emit: the five tile one iteration of "
+                        "its loop; the same spans stand in a profiler "
+                        "trace as dl4j.decode.<phase>)")
+DECODE_BOUNDARIES_HELP = ("Decode engine boundaries by the executable "
+                          "that ran (step|prefill|verify)")
+DECODE_POSITIONS_HELP = ("Sequence positions the decode engine advanced, "
+                         "by executable and by kind: prompt (the slot "
+                         "was fed a prompt token) or answer (a generated "
+                         "one)")
+DECODE_KV_FILL_HELP = ("Sum over boundaries of the paged KV pool's "
+                       "reserved fraction; over "
+                       "dl4j_decode_boundaries_total it is the mean fill")
+DECODE_QUEUE_WAIT_HELP = ("Seconds from decode submit to the boundary "
+                          "at which the request took a slot")
 
 
 class ServingInstruments:
@@ -553,7 +565,8 @@ class ServingInstruments:
                  "occupancy", "dispatch", "depth", "steals",
                  "_replica_load", "_shed", "tokens", "slots",
                  "prefix_hits", "prefix_misses", "ttft", "_accepted",
-                 "kv_occupancy")
+                 "kv_occupancy", "_phases", "_boundaries", "_positions",
+                 "kv_fill_sum", "decode_queue_wait")
 
     def __init__(self, registry, model):
         self.model = model
@@ -605,6 +618,23 @@ class ServingInstruments:
         self.kv_occupancy = registry.gauge(
             "dl4j_serving_kv_page_occupancy",
             SERVING_KV_OCCUPANCY_HELP, ("model",)).labels(model=model)
+        phases = registry.histogram(
+            "dl4j_decode_boundary_seconds", DECODE_BOUNDARY_HELP,
+            ("model", "phase"))
+        self._phases = {p: (phases.labels(model=model, phase=p),
+                            "dl4j.decode." + p) for p in DECODE_PHASES}
+        self._boundaries = registry.counter(
+            "dl4j_decode_boundaries_total", DECODE_BOUNDARIES_HELP,
+            ("model", "executable"))
+        self._positions = registry.counter(
+            "dl4j_decode_positions_total", DECODE_POSITIONS_HELP,
+            ("model", "executable", "kind"))
+        self.kv_fill_sum = registry.counter(
+            "dl4j_decode_kv_fill_sum", DECODE_KV_FILL_HELP,
+            ("model",)).labels(model=model)
+        self.decode_queue_wait = registry.histogram(
+            "dl4j_decode_queue_wait_seconds", DECODE_QUEUE_WAIT_HELP,
+            ("model",)).labels(model=model)
 
     def request(self, outcome):
         self._requests.labels(model=self.model, outcome=outcome).inc()
@@ -618,6 +648,24 @@ class ServingInstruments:
 
     def accepted(self, outcome, n=1):
         self._accepted.labels(model=self.model, outcome=outcome).inc(n)
+
+    def phase(self, phase):
+        """A Timer over one phase of a decode boundary: the histogram
+        and, on the profiler's clock, the span `dl4j.decode.<phase>`."""
+        histogram, annotation = self._phases[phase]
+        return histogram.time(annotation)
+
+    def boundary(self, executable, prompt=0, answer=0):
+        """One decode boundary through `executable` that fed `prompt`
+        prompt positions and `answer` generated ones, all slots
+        together."""
+        self._boundaries.labels(model=self.model,
+                                executable=executable).inc()
+        for kind, n in (("prompt", prompt), ("answer", answer)):
+            if n:
+                self._positions.labels(
+                    model=self.model, executable=executable,
+                    kind=kind).inc(n)
 
 
 def serving_instruments(model):

@@ -560,17 +560,44 @@ class _CountingStubTracer:
         raise AssertionError(f"tracer touched while disabled: {name}")
 
 
+class _CountingStubRegistry:
+    calls = 0
+
+    def __getattr__(self, name):
+        type(self).calls += 1
+        raise AssertionError(f"registry touched while disabled: {name}")
+
+
 class TestDisabledContract:
-    def test_zero_tracer_calls_and_bit_identical(self, traced):
+    def test_zero_tracer_calls_and_bit_identical(self, traced,
+                                                 monkeypatch):
+        import jax
+
+        from deeplearning4j_tpu.models.bert import (
+            BertConfig, BertTrainer, synthetic_mlm_batch)
+        from deeplearning4j_tpu.parallel.mesh import MeshConfig
+        from deeplearning4j_tpu.serving.decode import (
+            TransformerDecodeModel)
+        from deeplearning4j_tpu.telemetry import registry as registry_mod
+
         X, y = _batches(1)[0]
         tracing.configure(sample_rate=1.0)
         n1 = _mlp()
         n1.fit([(X, y), (X, y)], 2)
         p1 = np.asarray(n1.params())
 
-        _CountingStubTracer.calls = 0
+        cfg = BertConfig(vocab_size=200, hidden=32, num_layers=1,
+                         num_heads=2, ffn=64, max_len=16)
+        tok, lab = synthetic_mlm_batch(cfg, 2, 16, seed=0)
+        timers = []
+        monkeypatch.setattr(
+            registry_mod.Timer, "__enter__",
+            lambda self: timers.append(self.name) or self)
+
+        _CountingStubTracer.calls = _CountingStubRegistry.calls = 0
         telemetry.disable()
         prev = tracing.set_tracer(_CountingStubTracer())
+        prev_reg = telemetry.set_registry(_CountingStubRegistry())
         try:
             n2 = _mlp()
             n2.fit([(X, y), (X, y)], 2)
@@ -578,11 +605,24 @@ class TestDisabledContract:
             session.register("m", n2, example_shape=(16,),
                              ladder=BucketLadder((1, 4)), warmup=True)
             session.predict("m", X)
+            # a decode boundary and a BertTrainer step (ISSUE 26): no
+            # phase Timer, no count, no span
+            session.register_decoder("d", TransformerDecodeModel.init(
+                vocab=32, hidden=16, n_layers=1, n_heads=2, max_len=64,
+                max_slots=2, page=8, max_pages_per_slot=4))
+            assert len(session.decoder("d").decode([1, 2, 3], 4,
+                                                   timeout=60)) == 4
             session.close()
+            trainer = BertTrainer(cfg, MeshConfig(
+                data=1, devices=jax.devices()[:1]).build(), lr=1e-4)
+            float(trainer.train_step(tok, lab))
         finally:
             tracing.set_tracer(prev)
+            telemetry.set_registry(prev_reg)
             telemetry.enable()
         assert _CountingStubTracer.calls == 0
+        assert _CountingStubRegistry.calls == 0
+        assert timers == []
         np.testing.assert_array_equal(p1, np.asarray(n2.params()))
 
     def test_sampled_off_emits_nothing(self, traced):
