@@ -222,14 +222,16 @@ def _pool_bytes_estimate(model):
 # decode models
 # ---------------------------------------------------------------------------
 
-def _maybe_store(jitted, site, model, lane):
+def _maybe_store(jitted, site, model, lane, donation=()):
     """Route a decode-model jit through the PR-13 persistent executable
     store (ISSUE 20 satellite: the remaining cold-start gap). Warm
     engine construction then deserializes every step/prefill/verify
     executable instead of compiling — ledger-asserted zero XLA
     compiles. The sharded lane stays scoped out (ISSUE 19: serialized
     SPMD executables bake in a device assignment), and the wrapper is
-    the identity when the store is off."""
+    the identity when the store is off. ``donation`` is the jit's own
+    ``donate_argnums``: the store keys on it and owns a donated argument
+    before a deserialized executable's first call."""
     from deeplearning4j_tpu import compilestore
 
     if getattr(model, "mesh", None) is not None:
@@ -238,7 +240,7 @@ def _maybe_store(jitted, site, model, lane):
         return jitted
     return compilestore.StoredJit(
         jitted, site, program=f"{model._store_program()}:{lane}",
-        donation=())
+        donation=donation)
 
 
 class RnnDecodeModel:
@@ -254,6 +256,9 @@ class RnnDecodeModel:
 
     uses_pages = False
     page = None
+    # nothing donated: the state lists the net's own arrays (running
+    # statistics of non-recurrent layers) beside a few KB of carries
+    state_donation = ()
 
     def __init__(self, net, max_slots=8, vocab=None):
         import jax
@@ -368,6 +373,11 @@ class TransformerDecodeModel:
     single-device)."""
 
     uses_pages = True
+    # the KV pool is donated to every executable over it (``state`` is
+    # argument 1 of ``_fn``, ``masked_fn`` and the block executable) and
+    # written in place: a step CONSUMES the state it is given, the
+    # caller goes on with the one returned
+    state_donation = (1,)
 
     def __init__(self, params, n_heads, max_slots=8, page=16,
                  max_pages_per_slot=8, n_pages=None, eps=1e-12):
@@ -390,10 +400,12 @@ class TransformerDecodeModel:
                         else max_slots * max_pages_per_slot)
         self.eps = eps
         self.n_layers = len(params["layers"])
-        self._jit_step = _maybe_store(jax.jit(self._fn),
-                                      "decode:step", self, "step")
-        self._jit_masked = _maybe_store(jax.jit(self.masked_fn),
-                                        "decode:step", self, "masked")
+        self._jit_step = _maybe_store(
+            jax.jit(self._fn, donate_argnums=self.state_donation),
+            "decode:step", self, "step", donation=self.state_donation)
+        self._jit_masked = _maybe_store(
+            jax.jit(self.masked_fn, donate_argnums=self.state_donation),
+            "decode:step", self, "masked", donation=self.state_donation)
 
     def _store_program(self):
         """Store program digest: the transformer step is determined by
@@ -428,20 +440,28 @@ class TransformerDecodeModel:
         params = init_params(cfg, jax.random.key(seed))
         return cls(params, n_heads=n_heads, **kw)
 
-    # pools: [L, n_pages + 1, page, H, D]; page 0 is scratch
+    def _pool_shape(self):
+        """[L, n_pages + 1, page, H*D]; page 0 is scratch. A position's
+        row is the H*D floats the step writes, heads side by side: with
+        (H, D) as the two minor dimensions the device would pad them to
+        its (8, 128) tile, so it stores such a pool page-minor instead
+        and every executable converts what it touches there and back
+        (PERF.md, PR 27). (page, H*D) fills the tile as written."""
+        return (self.n_layers, self.n_pages + 1, self.page, self.hidden)
+
     def init_state(self):
         import jax.numpy as jnp
 
-        shape = (self.n_layers, self.n_pages + 1, self.page,
-                 self.n_heads, self.head_dim)
-        return {"k": jnp.zeros(shape, jnp.float32),
-                "v": jnp.zeros(shape, jnp.float32)}
+        return {"k": jnp.zeros(self._pool_shape(), jnp.float32),
+                "v": jnp.zeros(self._pool_shape(), jnp.float32)}
 
-    def _paged_attention(self, q, kpool, vpool, table, pos):
-        """q [S,H,D] against this slot's pages. Blockwise online
-        softmax over the page axis — ring_attention's accumulation with
-        pages instead of ring ranks; masked pages contribute exactly
-        zero, so a slot's output never depends on its neighbors."""
+    def _paged_attention(self, q, kpool, vpool, li, table, pos):
+        """q [S,H,D] against this slot's pages of layer ``li``, gathered
+        out of the whole pools (no layer of a pool is ever a value of
+        its own). Blockwise online softmax over the page axis —
+        ring_attention's accumulation with pages instead of ring ranks;
+        masked pages contribute exactly zero, so a slot's output never
+        depends on its neighbors."""
         import jax.numpy as jnp
         from jax import lax
 
@@ -451,8 +471,8 @@ class TransformerDecodeModel:
 
         def body(i, carry):
             m, l, o = carry
-            kb = kpool[table[:, i]]                  # [S, page, H, D]
-            vb = vpool[table[:, i]]
+            kb = kpool[li, table[:, i]].reshape(s_, page, h_, d_)
+            vb = vpool[li, table[:, i]].reshape(s_, page, h_, d_)
             s = jnp.einsum("shd,sphd->shp", q, kb) * scale
             k_pos = i * page + jnp.arange(page)      # this block's slots
             mask = k_pos[None, :] <= pos[:, None]    # causal + length
@@ -510,18 +530,16 @@ class TransformerDecodeModel:
         h = params["tok_emb"][tokens] + params["pos_emb"][pos]
         h = ln(h, params["emb_ln"])
         off = pos % self.page
-        new_k, new_v = [], []
+        kpool, vpool = state["k"], state["v"]
         for li, lp in enumerate(params["layers"]):
             qkv = h @ lp["qkv_w"] + lp["qkv_b"]
             q, k, v = jnp.split(qkv, 3, axis=-1)
             q = q.reshape(S, nh, hd)
-            k = k.reshape(S, nh, hd)
-            v = v.reshape(S, nh, hd)
-            kpool = state["k"][li].at[pidx, off].set(k)
-            vpool = state["v"][li].at[pidx, off].set(v)
-            new_k.append(kpool)
-            new_v.append(vpool)
-            att = self._paged_attention(q, kpool, vpool, table, pos)
+            # S rows a layer into the donated pools, in place, before
+            # the layer's attention reads them
+            kpool = kpool.at[li, pidx, off].set(k)
+            vpool = vpool.at[li, pidx, off].set(v)
+            att = self._paged_attention(q, kpool, vpool, li, table, pos)
             att = att.reshape(S, nh * hd) @ lp["out_w"] + lp["out_b"]
             h = ln(h + att, lp["ln1"])
             ffn = jax.nn.gelu(h @ lp["ffn_in_w"] + lp["ffn_in_b"])
@@ -529,8 +547,7 @@ class TransformerDecodeModel:
             h = ln(h + ffn, lp["ln2"])
         logits = h @ params["tok_emb"].T + params["mlm_bias"]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        new_state = {"k": jnp.stack(new_k), "v": jnp.stack(new_v)}
-        return nxt, new_state
+        return nxt, {"k": kpool, "v": vpool}
 
     def params_for_step(self):
         return self.params
@@ -540,7 +557,7 @@ class TransformerDecodeModel:
         out = self._jit_step(*args)
         if site is not None:
             compile_ledger.note_step(site, self._jit_step, args,
-                                     donation=())
+                                     donation=self.state_donation)
         return out
 
     def step_masked(self, state, tokens, pos, table, active, site=None):
@@ -549,7 +566,7 @@ class TransformerDecodeModel:
         out = self._jit_masked(*args)
         if site is not None:
             compile_ledger.note_step(site, self._jit_masked, args,
-                                     donation=())
+                                     donation=self.state_donation)
         return out
 
     def reset_slot(self, state, slot):
@@ -919,10 +936,11 @@ class DecodeEngine:
                            eos_id=eos_id).result(timeout=timeout)
 
     def warmup(self):
-        """Compile the full executable set with throwaway iterations,
-        leaving the engine state untouched (slot 0's carry is re-reset
-        afterwards; block warmups run with all counts zero). Every
-        executable lands in the compile ledger under a
+        """Compile the full executable set with throwaway iterations
+        that write the scratch page only (slot 0's carry is re-reset
+        afterwards; block warmups run with all counts zero). A launch
+        consumes the state it is given, so each one's result is carried
+        on. Every executable lands in the compile ledger under a
         ``decode:<name>:*`` site, so the zero-steady-state-recompile
         invariant is ledger-assertable for the whole set: token step +
         chunk prefill + verify + draft step + draft prefill (tests)."""
@@ -934,22 +952,22 @@ class DecodeEngine:
                                                       as _registry)
 
             _registry.get_registry()
-        state = self.model.reset_slot(self._state, 0)
+        self._state = self.model.reset_slot(self._state, 0)
         tokens = np.zeros((self.model.max_slots,), np.int32)
         pos = np.zeros((self.model.max_slots,), np.int32)
         # a REAL copy, not ascontiguousarray (which aliases an
         # already-contiguous table): admission mutates the table
         # between boundaries, and jax may zero-copy numpy inputs
         table = self._table.copy()
-        self._model_step(state, tokens, pos, table)
+        _, self._state = self._model_step(self._state, tokens, pos, table)
         if self._block is not None:
-            self._block.warmup(self._state, table,
-                               site=f"decode:{self.name}:prefill")
+            self._state = self._block.warmup(
+                self._state, table, site=f"decode:{self.name}:prefill")
             if self._spec is not None and \
                     self._spec.k + 1 != self._block.chunk:
-                self._block.warmup(self._state, table,
-                                   widths=(self._spec.k + 1,),
-                                   site=f"decode:{self.name}:verify")
+                self._state = self._block.warmup(
+                    self._state, table, widths=(self._spec.k + 1,),
+                    site=f"decode:{self.name}:verify")
         if self._spec is not None:
             self._spec.warmup()
         self._state = self.model.reset_slot(self._state, 0)
@@ -1199,6 +1217,23 @@ class DecodeEngine:
                       seconds=round(time.perf_counter() - req.t_submit,
                                     6))
 
+    def _fail_boundary(self, err):
+        """A launch raised: the engine goes on from fresh pools with
+        nothing cached over them, and every active request ends with
+        ``err`` (last, so a caller that tries again finds the engine
+        ready). The pool a launch was given is consumed whether or not
+        the launch came back, so neither it nor a page the prefix
+        caches published from it can be used again."""
+        try:
+            self._state = None      # let the old pool go before the new
+            self._state = self.model.init_state()
+            if self._spec is not None:
+                self._spec.reset_state()
+        finally:
+            self.clear_prefix_cache()
+            for req in list(self._active.values()):
+                self._finish(req, error=err)
+
     def _model_step(self, state, tokens, pos, table):
         if self._step_takes_site:
             return self.model.step(state, tokens, pos, table,
@@ -1295,10 +1330,8 @@ class DecodeEngine:
         except Exception as e:
             # OOM forensics (ISSUE 14): a device allocation failure at
             # this boundary fails the requests with the typed error
-            err = _boundary_error(e, f"decode:{self.name}:prefill",
-                                  "chunk prefill failed")
-            for req in list(self._active.values()):
-                self._finish(req, error=err)
+            self._fail_boundary(_boundary_error(
+                e, f"decode:{self.name}:prefill", "chunk prefill failed"))
             return False
         t_b1 = time.perf_counter()
         with _phase(inst, "emit"):
@@ -1355,10 +1388,8 @@ class DecodeEngine:
             with _phase(inst, "readback"):
                 nxt = np.asarray(nxt)
         except Exception as e:
-            err = _boundary_error(e, f"decode:{self.name}:step",
-                                  "decode step failed")
-            for req in list(self._active.values()):
-                self._finish(req, error=err)
+            self._fail_boundary(_boundary_error(
+                e, f"decode:{self.name}:step", "decode step failed"))
             return
         t_b1 = time.perf_counter()
         with _phase(inst, "emit"):
@@ -1446,10 +1477,9 @@ class DecodeEngine:
             with _phase(inst, "readback"):
                 outs = np.asarray(outs)
         except Exception as e:
-            err = _boundary_error(e, f"decode:{self.name}:verify",
-                                  "speculative decode failed")
-            for req in list(self._active.values()):
-                self._finish(req, error=err)
+            self._fail_boundary(_boundary_error(
+                e, f"decode:{self.name}:verify",
+                "speculative decode failed"))
             return
         t_b1 = time.perf_counter()
         with _phase(inst, "emit"):

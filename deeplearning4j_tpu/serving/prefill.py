@@ -56,7 +56,10 @@ class ChunkedPrefill:
             raise ValueError(f"chunk must be >= 1, got {chunk}")
         self.model = model
         self.chunk = int(chunk)
-        self._jit = jax.jit(self._fn)
+        # the state is the loop's carry: donated where the model's own
+        # step donates it, so the block too writes the pool in place
+        self._donation = tuple(getattr(model, "state_donation", ()))
+        self._jit = jax.jit(self._fn, donate_argnums=self._donation)
         # ISSUE 20: the prefill/verify executable rides the persistent
         # store like the token step (unsharded lane only; identity
         # when the store is off or the model has no program digest)
@@ -66,7 +69,8 @@ class ChunkedPrefill:
             from deeplearning4j_tpu.serving.decode import _maybe_store
 
             self._jit = _maybe_store(self._jit, "decode:prefill",
-                                     model, "prefill")
+                                     model, "prefill",
+                                     donation=self._donation)
 
     def _fn(self, params, state, blocks, pos0, counts, table):
         import jax.numpy as jnp
@@ -90,14 +94,16 @@ class ChunkedPrefill:
     def launch(self, state, blocks, pos0, counts, table, site=None):
         """Dispatch the block and return without waiting: ``outs`` is
         still on the device (the engine times the wait for it as its
-        own phase)."""
+        own phase). The ``state`` passed is consumed where the model
+        donates it: go on with the one returned."""
         args = (self.model.params_for_step(), state,
                 np.ascontiguousarray(blocks, dtype=np.int32),
                 np.ascontiguousarray(pos0, dtype=np.int32),
                 np.ascontiguousarray(counts, dtype=np.int32), table)
         outs, state = self._jit(*args)
         if site is not None:
-            compile_ledger.note_step(site, self._jit, args, donation=())
+            compile_ledger.note_step(site, self._jit, args,
+                                     donation=self._donation)
         return outs, state
 
     def run(self, state, blocks, pos0, counts, table, site=None):
@@ -112,11 +118,11 @@ class ChunkedPrefill:
 
     def warmup(self, state, table, widths=None, site=None):
         """Compile every block width the engine will dispatch (all
-        counts zero: the engine state rides through untouched except
-        scratch)."""
+        counts zero: the state rides through untouched except
+        scratch). Returns the state to go on with."""
         S = self.model.max_slots
         z = np.zeros((S,), np.int32)
         for width in (widths or (self.chunk,)):
-            self.run(state, np.zeros((S, int(width)), np.int32), z, z,
-                     table, site=site)
-        return self
+            _, state = self.run(state, np.zeros((S, int(width)), np.int32),
+                                z, z, table, site=site)
+        return state

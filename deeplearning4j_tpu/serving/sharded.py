@@ -30,7 +30,7 @@ BucketLadder / ModelRegistry / warmup / compile-ledger machinery:
   ``PagedKVCache``: the per-page flash-attention ``fori_loop`` of
   :class:`~.decode.TransformerDecodeModel` is already ring_attention's
   block accumulation, so pages-as-shards is the natural extension —
-  the device pools ``[L, n_pages+1, page, H, D]`` are sharded on the
+  the device pools ``[L, n_pages+1, page, H*D]`` are sharded on the
   PAGE axis over the ``model`` axis while the host-side refcounted
   page table (and with it prefix caching and speculative decoding)
   rides unchanged on top. The online-softmax accumulation order over
@@ -379,7 +379,7 @@ class ShardedTransformerDecodeModel(TransformerDecodeModel):
     """:class:`~.decode.TransformerDecodeModel` with the KV pools
     sharded over the mesh — pages-as-shards.
 
-    The pools ``[L, n_pages+1, page, H, D]`` get
+    The pools ``[L, n_pages+1, page, H*D]`` get
     ``PartitionSpec(None, "model")``: each device owns a contiguous
     block of PAGES. The per-page flash-attention ``fori_loop`` already
     accumulates page blocks with ring_attention's online softmax, so
@@ -420,9 +420,7 @@ class ShardedTransformerDecodeModel(TransformerDecodeModel):
         import jax
         import jax.numpy as jnp
 
-        shape = (self.n_layers, self.n_pages + 1, self.page,
-                 self.n_heads, self.head_dim)
-        zeros = jnp.zeros(shape, jnp.float32)
+        zeros = jnp.zeros(self._pool_shape(), jnp.float32)
         return {"k": jax.device_put(zeros, self._pool_sharding),
                 "v": jax.device_put(zeros, self._pool_sharding)}
 
@@ -449,8 +447,7 @@ class ShardedTransformerDecodeModel(TransformerDecodeModel):
         per-device ``kv_cache`` claims state. Devices that differ only
         along non-model axes hold replicas of the same page block, so
         every device's share is ``total / model_axis_size``."""
-        pool = 2 * (self.n_layers * (self.n_pages + 1) * self.page
-                    * self.n_heads * self.head_dim) * 4  # k+v, fp32
+        pool = 2 * math.prod(self._pool_shape()) * 4     # k+v, fp32
         per = pool // self.pool_shards
         return {label: per for label in mesh_device_labels(self.mesh)}
 

@@ -183,11 +183,20 @@ class SpeculativeDecoder:
         S = self.model.max_slots
         z = np.zeros((S,), np.int32)
         off = np.zeros((S,), bool)
-        self.model.step_masked(self._state, z, z, self._table(), off,
-                               site=f"decode:{self.name}:draft_step")
-        self._block.warmup(self._state, self._table(),
-                           site=f"decode:{self.name}:draft_prefill")
+        _, self._state = self.model.step_masked(
+            self._state, z, z, self._table(), off,
+            site=f"decode:{self.name}:draft_step")
+        self._state = self._block.warmup(
+            self._state, self._table(),
+            site=f"decode:{self.name}:draft_prefill")
         return self
+
+    def reset_state(self):
+        """A fresh draft pool: what the engine goes on with after a
+        launch that failed (the pool a launch was given is gone with
+        it; the engine has cleared the prefix caches over both)."""
+        self._state = None      # let the old pool go before the new
+        self._state = self.model.init_state()
 
     # -- acceptance / fallback ----------------------------------------------
     def observe(self, accepted, fed):

@@ -27,7 +27,8 @@ from deeplearning4j_tpu import telemetry
 from deeplearning4j_tpu.serving import (
     DecodeEngine, InferenceSession, PagedKVCache, PrefixCache,
     RnnDecodeModel, SpeculativeConfig, TransformerDecodeModel)
-from deeplearning4j_tpu.serving.decode import DecodeError, _DecodeRequest
+from deeplearning4j_tpu.serving.decode import (
+    DecodeError, _DecodeRequest, _pool_bytes_estimate)
 from deeplearning4j_tpu.telemetry import compile_ledger
 
 
@@ -553,3 +554,233 @@ class TestDecodeV2Soak:
         eng.clear_prefix_cache()
         assert eng._kv.free_pages == eng._kv.n_pages
         eng.close()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 27: the KV pool is donated to every executable over it and
+# written in place
+# ---------------------------------------------------------------------------
+
+# the executables over a TransformerDecodeModel's pool, and the widest
+# run of one slot's tokens a launch of each consumes
+POOL_EXECUTABLES = {"step": 1, "step_masked": 1, "block1": 1,
+                    "block_chunk": 4}
+
+
+def _exe(model, which):
+    """The jitted callable ``which`` names, as the model or its
+    ChunkedPrefill holds it."""
+    from deeplearning4j_tpu.serving.prefill import ChunkedPrefill
+
+    if which == "step":
+        return model._jit_step
+    if which == "step_masked":
+        return model._jit_masked
+    return ChunkedPrefill(model, POOL_EXECUTABLES[which])._jit
+
+
+def _draft_of(model):
+    """A draft lane over the target's own weights and geometry."""
+    return TransformerDecodeModel(
+        model.params, n_heads=model.n_heads, max_slots=model.max_slots,
+        page=model.page, max_pages_per_slot=model.max_pages_per_slot)
+
+
+def _launch_args(model, which, state, toks, pos0, table):
+    """The argument tuple the model's own ``step`` / ``step_masked`` /
+    ``ChunkedPrefill.launch`` hands its executable to consume ``toks``
+    (one slot's next tokens) in slot 0 from position ``pos0``."""
+    S, w = model.max_slots, POOL_EXECUTABLES[which]
+    n = len(toks)
+    if which in ("step", "step_masked"):
+        t = np.zeros((S,), np.int32)
+        p = np.zeros((S,), np.int32)
+        t[0], p[0] = toks[0], pos0
+        args = (model.params, state, t, p, table)
+        if which == "step_masked":
+            active = np.zeros((S,), bool)
+            active[0] = True
+            args += (active,)
+        return args
+    blocks = np.zeros((S, w), np.int32)
+    p0 = np.zeros((S,), np.int32)
+    counts = np.zeros((S,), np.int32)
+    blocks[0, :n], p0[0], counts[0] = toks, pos0, n
+    return (model.params, state, blocks, p0, counts, table)
+
+
+def _greedy(fn, model, which, prompt, max_new):
+    """One request in slot 0 through ``fn`` (an executable of kind
+    ``which``), the prompt in runs of the executable's width, then one
+    token at a time: the served tokens."""
+    kv = PagedKVCache(model.n_pages, model.page,
+                      model.max_pages_per_slot, model.max_slots)
+    kv.reserve(0, len(prompt) + max_new)
+    table = np.ascontiguousarray(kv.table)
+    state = model.init_state()
+    w = POOL_EXECUTABLES[which]
+    seq, out, ptr = list(prompt), [], 0
+    while len(out) < max_new:
+        n = min(w, len(seq) - ptr)
+        outs, state = fn(*_launch_args(model, which, state,
+                                       seq[ptr:ptr + n], ptr, table))
+        outs = np.asarray(outs)
+        ptr += n
+        if ptr == len(seq):
+            tok = int(outs[0] if outs.ndim == 1 else outs[0, n - 1])
+            out.append(tok)
+            seq.append(tok)
+    return out
+
+
+def _raise_after(real, site_suffix=""):
+    """``real`` made to raise once AFTER its launch went out (the
+    first whose ``site`` ends with ``site_suffix``): the state it was
+    given is consumed, as after a launch that died."""
+    fired = []
+
+    def wrapper(state, *a, site=None, **kw):
+        out = real(state, *a, site=site, **kw)
+        if fired or not (site or "").endswith(site_suffix):
+            return out
+        fired.append(True)
+        raise RuntimeError("injected launch failure")
+    return wrapper
+
+
+class TestDonatedPool:
+    @pytest.mark.parametrize("which", sorted(POOL_EXECUTABLES))
+    def test_executable_aliases_the_pool_and_consumes_it(self, which):
+        """(a) The executable the model really calls aliases the whole
+        pool to its output, and the arrays passed in are gone after the
+        call."""
+        model = _xf(seed=3, max_len=96, max_pages_per_slot=3)
+        fn = _exe(model, which)
+        table = np.zeros((model.max_slots, 3), np.int32)
+        state = model.init_state()
+        args = _launch_args(model, which, state, [5], 0, table)
+        mem = fn.lower(*args).compile().memory_analysis()
+        assert mem.alias_size_in_bytes >= _pool_bytes_estimate(model)
+        _, new_state = fn(*args)
+        assert state["k"].is_deleted() and state["v"].is_deleted()
+        assert not new_state["k"].is_deleted()
+        assert new_state["k"].shape == model._pool_shape()
+
+    @pytest.mark.parametrize("which", sorted(POOL_EXECUTABLES))
+    def test_tokens_bit_identical_to_undonated(self, which):
+        """(b) Tokens through the donated executable equal, bit for
+        bit, those of an undonated ``jax.jit(model._fn)`` driven by
+        hand over the same prompt."""
+        import jax
+
+        model = _xf(seed=9, max_len=96, max_pages_per_slot=3)
+        prompt = list(np.random.default_rng(4).integers(0, 40, size=37))
+        undonated = jax.jit(model._fn)
+        ref = _greedy(undonated, model, "step", prompt, 9)
+        state = model.init_state()
+        undonated(*_launch_args(model, "step", state, [1], 0,
+                                np.zeros((model.max_slots, 3),
+                                         np.int32)))
+        assert not state["k"].is_deleted()      # the control donates nothing
+        assert _greedy(_exe(model, which), model, which, prompt,
+                       9) == ref
+
+    @pytest.mark.parametrize("arm", ["plain", "chunk", "speculative",
+                                     "mesh"])
+    def test_engine_survives_warmup_then_serves(self, arm):
+        """(c) ``warmup()`` carries each throwaway launch's state on
+        (the pool it passed is consumed), so the engine serves after
+        it: with the block executable, with a draft lane, on a mesh."""
+        kw = dict(seed=6, max_len=96, max_pages_per_slot=3)
+        model, opts = _xf(**kw), {}
+        ref = offline_decode(_xf(**kw), [7, 3, 9, 1, 4, 4, 2], 8)
+        if arm == "chunk":
+            opts = dict(chunk=4, prefix_cache=True)
+        elif arm == "speculative":
+            opts = dict(chunk=4, speculative=SpeculativeConfig(
+                draft=_draft_of(model), k=2))
+        elif arm == "mesh":
+            import jax
+
+            from deeplearning4j_tpu.parallel.mesh import MeshConfig
+            from deeplearning4j_tpu.serving import (
+                ShardedTransformerDecodeModel)
+
+            if len(jax.devices()) < 2:
+                pytest.skip("no second device for a mesh")
+            mesh = MeshConfig(data=1, model=2,
+                              devices=jax.devices()[:2]).build()
+            model = ShardedTransformerDecodeModel(
+                model.params, 2, mesh, max_slots=2, page=32,
+                max_pages_per_slot=3)
+        eng = DecodeEngine(model, name=f"don-{arm}", **opts).warmup()
+        try:
+            for leaf in (eng._state["k"], eng._state["v"]):
+                assert not leaf.is_deleted()
+            assert eng.decode([7, 3, 9, 1, 4, 4, 2], 8,
+                              timeout=120.0) == ref
+            assert eng.decode([7, 3, 9, 1, 4, 4, 2], 8,
+                              timeout=120.0) == ref
+        finally:
+            eng.close()
+
+    @pytest.mark.parametrize("arm", ["step", "prefill", "verify"])
+    def test_failed_launch_fails_requests_then_serves_from_fresh_pool(
+            self, arm, monkeypatch):
+        """(d) After a launch that raised (its pool consumed), the
+        active requests end with the error, and the next request is
+        served correctly from a fresh pool with an empty prefix
+        cache."""
+        kw = dict(seed=8, max_len=96, max_pages_per_slot=3)
+        model = _xf(**kw)
+        prompt = list(np.random.default_rng(2).integers(0, 40, size=40))
+        ref = offline_decode(_xf(**kw), prompt, 6)
+        opts = {}
+        if arm != "step":
+            opts = dict(chunk=4, prefix_cache=True)
+        if arm == "verify":
+            opts["speculative"] = SpeculativeConfig(
+                draft=_draft_of(model), k=3)
+        eng = DecodeEngine(model, name=f"fail-{arm}", **opts).warmup()
+        try:
+            # a served request first: its prompt page is published
+            assert eng.decode(prompt, 6, timeout=120.0) == ref
+            if arm != "step":
+                assert eng._pcache.stats()["pages"] > 0
+            if arm == "step":
+                monkeypatch.setattr(model, "step",
+                                    _raise_after(model.step))
+            else:
+                monkeypatch.setattr(
+                    eng._block, "launch",
+                    _raise_after(eng._block.launch, f":{arm}"))
+            old = eng._state
+            with pytest.raises(Exception, match="injected launch"):
+                eng.decode(prompt[:-1] + [0], 6, timeout=120.0)
+            assert old["k"].is_deleted()
+            assert eng._state is not old
+            assert not eng._state["k"].is_deleted()
+            if arm != "step":
+                assert eng._pcache.stats()["pages"] == 0
+            assert eng._kv.free_pages == eng._kv.n_pages
+            assert eng.decode(prompt, 6, timeout=120.0) == ref
+        finally:
+            eng.close()
+
+    @pytest.mark.parametrize("lane", ["step", "prefill", "verify",
+                                      "draft_step"])
+    def test_compile_ledger_records_the_donation(self, lane):
+        """(e) ``GET /debug/compiles`` says what the executable does:
+        the state's donation, at each of the four sites."""
+        model = _xf(seed=7, max_len=96, max_pages_per_slot=3)
+        name = f"ledon-{lane}"
+        eng = DecodeEngine(
+            model, name=name, chunk=4, speculative=SpeculativeConfig(
+                draft=_draft_of(model), k=2)).warmup()
+        try:
+            recs = [r for r in compile_ledger.get_ledger().describe()
+                    if r["site"] == f"decode:{name}:{lane}"]
+            assert recs
+            assert all(r["signature"]["donation"] == [1] for r in recs)
+        finally:
+            eng.close()
