@@ -17,7 +17,6 @@ the LM head ties the embedding matrix."""
 
 from __future__ import annotations
 
-import contextlib
 import math
 from dataclasses import dataclass
 
@@ -32,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from deeplearning4j_tpu.parallel.mesh import (
     DATA_AXIS, MODEL_AXIS, SEQ_AXIS, spec_for)
 from deeplearning4j_tpu.parallel.ring_attention import ring_attention
+from deeplearning4j_tpu.parallel.step_engine import StepEngine, loss_and_adam
 
 
 @dataclass
@@ -347,66 +347,50 @@ def mlm_gather(labels, max_preds=None):
 
 
 class BertTrainer:
-    """One donated jitted step: fwd + bwd + Adam, with dp/tp/sp shardings."""
+    """One donated jitted step: fwd + bwd + Adam, with dp/tp/sp shardings.
+    Thin over `parallel.step_engine.StepEngine`, which `CausalLMTrainer`
+    shares: this class brings the masked-LM loss and its batch."""
 
     def __init__(self, cfg: BertConfig, mesh: Mesh, lr=1e-4, seed=0):
         self.cfg = cfg
         self.mesh = mesh
         self.lr = lr
         key = jax.random.key(seed)
-        specs = param_specs(cfg)
-        to_sharding = lambda s: NamedSharding(  # noqa: E731
-            mesh, P(*[a if a in mesh.axis_names else None
-                      for a in (s or P())]))
-        self.p_sh = jax.tree_util.tree_map(
-            to_sharding, specs, is_leaf=lambda x: isinstance(x, P))
-        params = init_params(cfg, key)
-        self.params = jax.device_put(params, self.p_sh)
-        zeros = lambda: jax.tree_util.tree_map(  # noqa: E731
-            jnp.zeros_like, self.params)
-        self.opt = {"m": zeros(), "v": zeros()}
-        self.o_sh = {"m": self.p_sh, "v": self.p_sh}
         self.batch_sh = NamedSharding(mesh, spec_for(mesh, DATA_AXIS,
                                                      SEQ_AXIS))
         # masked-position tensors [B,M]: data-sharded only (M != seq axis)
         self.pos_sh = NamedSharding(mesh, spec_for(mesh, DATA_AXIS))
-        self._step_fn = None
-        self._step = 0
-
-    def _step_math(self, params, opt, tokens, positions, mlm_labels,
-                   weights, rng, t):
-        cfg, mesh, lr = self.cfg, self.mesh, self.lr
-        loss, grads = jax.value_and_grad(mlm_loss_masked)(
-            params, cfg, tokens, positions, mlm_labels, weights,
-            mesh=mesh, deterministic=False, rng=rng)
-        b1, b2, eps = 0.9, 0.999, 1e-8
-        m = jax.tree_util.tree_map(
-            lambda m_, g: b1 * m_ + (1 - b1) * g, opt["m"], grads)
-        v = jax.tree_util.tree_map(
-            lambda v_, g: b2 * v_ + (1 - b2) * g * g, opt["v"], grads)
-        tt = t + 1
-        mhat = jax.tree_util.tree_map(lambda m_: m_ / (1 - b1 ** tt), m)
-        vhat = jax.tree_util.tree_map(lambda v_: v_ / (1 - b2 ** tt), v)
-        params = jax.tree_util.tree_map(
-            lambda p, mh, vh: p - lr * mh / (jnp.sqrt(vh) + eps),
-            params, mhat, vhat)
-        return loss, params, {"m": m, "v": v}
-
-    def _build(self):
-        repl = NamedSharding(self.mesh, P())
 
         def step(params, opt, tokens, positions, mlm_labels, weights, rng,
                  t):
             return self._step_math(params, opt, tokens, positions,
                                    mlm_labels, weights, rng, t)
 
-        return jax.jit(
-            step,
-            in_shardings=(self.p_sh, self.o_sh, self.batch_sh, self.pos_sh,
-                          self.pos_sh, self.pos_sh, repl, repl),
-            out_shardings=(repl, self.p_sh, self.o_sh),
-            donate_argnums=(0, 1),
-        )
+        self._engine = StepEngine(
+            mesh, init_params(cfg, key), param_specs(cfg), step,
+            (self.batch_sh, self.pos_sh, self.pos_sh, self.pos_sh,
+             NamedSharding(mesh, P())))
+        self.p_sh, self.o_sh = self._engine.p_sh, self._engine.o_sh
+
+    # the state lives in the engine; the trainer's names for it stay
+    params = property(lambda self: self._engine.params,
+                      lambda self, v: setattr(self._engine, "params", v))
+    opt = property(lambda self: self._engine.opt,
+                   lambda self, v: setattr(self._engine, "opt", v))
+    _step = property(lambda self: self._engine.steps,
+                     lambda self, v: setattr(self._engine, "steps", v))
+
+    def _step_math(self, params, opt, tokens, positions, mlm_labels,
+                   weights, rng, t):
+        cfg, mesh = self.cfg, self.mesh
+        return loss_and_adam(
+            lambda p: mlm_loss_masked(
+                p, cfg, tokens, positions, mlm_labels, weights, mesh=mesh,
+                deterministic=False, rng=rng),
+            params, opt, self.lr, t)
+
+    def _build(self):
+        return self._engine.build()
 
     def _build_multi(self, repeats=1):
         """K training steps in ONE device launch: lax.scan over a stacked
@@ -512,32 +496,17 @@ class BertTrainer:
         """tokens [B,T] int32; labels [B,T] with -100 at unmasked
         positions. The masked-position gather happens host-side so the
         device step only scores the ~15% of positions that matter."""
-        from deeplearning4j_tpu import telemetry
-
-        if self._step_fn is None:
-            self._step_fn = self._build()
-        # the step's two host phases as spans on the profiler's clock
-        # (pure annotations; nothing is made when telemetry is off)
-        span = (telemetry.span if telemetry.enabled()
-                else contextlib.nullcontext)
-        with span("dl4j.train.gather"):
-            positions, mlm_labels, weights = mlm_gather(
+        # rbg PRNG: XLA's RngBitGenerator is far cheaper than threefry
+        # for the ~380M dropout bits a BERT-base step draws (~17 ms/step
+        # on v5e); dropout only needs statistical, not
+        # reproducible-forever, randomness
+        return self._engine.run(
+            lambda: mlm_gather(
                 labels,
-                max_preds=self._max_preds(np.asarray(tokens).shape[1]))
-        with span("dl4j.train.dispatch"):
-            # rbg PRNG: XLA's RngBitGenerator is far cheaper than
-            # threefry for the ~380M dropout bits a BERT-base step draws
-            # (~17 ms/step on v5e); dropout only needs statistical, not
-            # reproducible-forever, randomness
-            rng = jax.random.key(self._step + 1, impl="rbg")
-            # step counter as a traced scalar — a static arg would
-            # recompile the executable every step
-            loss, self.params, self.opt = self._step_fn(
-                self.params, self.opt, jnp.asarray(tokens, jnp.int32),
-                positions, mlm_labels, weights, rng,
-                jnp.asarray(self._step, jnp.int32))
-        self._step += 1
-        return loss
+                max_preds=self._max_preds(np.asarray(tokens).shape[1])),
+            lambda gathered, steps: (
+                jnp.asarray(tokens, jnp.int32), *gathered,
+                jax.random.key(steps + 1, impl="rbg")))[0]
 
     def _max_preds(self, seq_len):
         return mlm_max_preds(seq_len)

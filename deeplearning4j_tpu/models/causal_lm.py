@@ -1,0 +1,475 @@
+"""A causal language model whose block is described by data, TPU-first.
+
+One pre-norm block, `h = x + Attn_l(RMSNorm(x))`, `y = h + MLP_l(RMSNorm(h))`,
+where each layer says for itself which attention it has (`full` or
+`sliding`: causal, a sliding layer also masks `i - j >= sliding_window`),
+how many query heads (grouped over `kv_heads` K and V heads), which rotary
+parameters (partial rotary, YaRN or plain) and which MLP (`dense`, a gated
+MLP; `sparse`, a sigmoid-routed expert layer plus one shared expert). The
+configuration is built from a published `config.json`'s own keys
+(`layer_types`, `num_attention_heads_per_layer`, `mlp_layer_types`,
+`rope_parameters`, ...), cut to the chip's share of a deployment:
+`experts_held = (first, count)` of each sparse layer's experts and
+`vocab_held` rows of embedding and head (`parallel/moe.py:moe_share_apply`).
+
+bfloat16 activations and matmul operands with float32 accumulation; norms,
+rotary tables, router scores and the loss in float32; float32 parameters;
+each layer under `jax.checkpoint`. `CausalLMTrainer` trains it through the
+step engine that `BertTrainer` uses."""
+
+from __future__ import annotations
+
+import math
+from dataclasses import dataclass
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, spec_for
+from deeplearning4j_tpu.parallel.moe import moe_share_apply, moe_share_init
+from deeplearning4j_tpu.parallel.step_engine import StepEngine, loss_and_adam
+
+# the splash kernel's tiles: a sequence it runs on is a multiple of this
+ATTENTION_BLOCK = 512
+# positions of each row whose logits the loss holds at a time
+LOSS_CHUNK = 4096
+# weights from a seed: normal(0, INIT_STD) for every matrix and the embedding
+INIT_STD = 0.02
+
+
+@dataclass(frozen=True)
+class LayerSpec:
+    attention: str          # "full" | "sliding"
+    heads: int              # query heads of this layer
+    mlp: str                # "dense" | "sparse"
+
+
+@dataclass(frozen=True)
+class CausalLMConfig:
+    layers: tuple            # of LayerSpec
+    rope: dict               # attention kind -> its rope_parameters entry
+    vocab_held: int
+    hidden: int
+    head_dim: int
+    kv_heads: int
+    sliding_window: int
+    dense_ffn: int
+    expert_ffn: int
+    shared_ffn: int
+    num_experts: int         # the router's width: every expert of the layer
+    top_k: int
+    routed_scale: float
+    experts_held: tuple      # (first, count) of the experts that live here
+    rms_eps: float = 1e-6
+    compute_dtype: str = "bfloat16"
+
+    @classmethod
+    def from_published(cls, published: dict, num_layers=None,
+                       experts_held=None, vocab_held=None, **kw):
+        """From a published config.json's keys; the three cuts default to
+        the whole model."""
+        n = num_layers or published["num_hidden_layers"]
+        kinds = {"full_attention": "full", "sliding_attention": "sliding"}
+        layers = tuple(
+            LayerSpec(kinds[a], h, m) for a, h, m in zip(
+                published["layer_types"][:n],
+                published["num_attention_heads_per_layer"][:n],
+                published["mlp_layer_types"][:n]))
+        ropes = published["rope_parameters"]
+        return cls(
+            layers=layers,
+            rope={kinds[k]: ropes[k] for k in kinds},
+            vocab_held=vocab_held or published["vocab_size"],
+            hidden=published["hidden_size"],
+            head_dim=published["head_dim"],
+            kv_heads=published["num_key_value_heads"],
+            sliding_window=published["sliding_window"],
+            dense_ffn=published["intermediate_size"],
+            expert_ffn=published["moe_intermediate_size"],
+            shared_ffn=published["shared_expert_intermediate_size"],
+            num_experts=published["num_experts"],
+            top_k=published["num_experts_per_tok"],
+            routed_scale=published.get("moe_routed_scaling_factor", 1.0),
+            experts_held=tuple(experts_held
+                               or (0, published["num_experts"])),
+            rms_eps=published["rms_norm_eps"], **kw)
+
+    @property
+    def sparse_layers(self):
+        return [i for i, s in enumerate(self.layers) if s.mlp == "sparse"]
+
+
+# -- parameters ---------------------------------------------------------------
+
+def _normal(key, shape, std):
+    return jax.random.normal(key, shape, jnp.float32) * std
+
+
+def _gated_mlp_init(key, hidden, ffn, std):
+    k = jax.random.split(key, 3)
+    return {"gate": _normal(k[0], (hidden, ffn), std),
+            "up": _normal(k[1], (hidden, ffn), std),
+            "down": _normal(k[2], (ffn, hidden), std)}
+
+
+def init_params(cfg: CausalLMConfig, key) -> dict:
+    d, hd, std = cfg.hidden, cfg.head_dim, INIT_STD
+    keys = jax.random.split(key, 2 + len(cfg.layers))
+    norm = lambda kk, shape: _normal(kk, shape, std)  # noqa: E731
+    params = {"embed": norm(keys[0], (cfg.vocab_held, d)),
+              "head": norm(keys[1], (d, cfg.vocab_held)),
+              "final_norm": jnp.ones((d,)), "layers": []}
+    for spec, lk in zip(cfg.layers, keys[2:]):
+        k = jax.random.split(lk, 8)
+        layer = {
+            "attn_norm": jnp.ones((d,)), "mlp_norm": jnp.ones((d,)),
+            "wq": norm(k[0], (d, spec.heads * hd)),
+            "wk": norm(k[1], (d, cfg.kv_heads * hd)),
+            "wv": norm(k[2], (d, cfg.kv_heads * hd)),
+            "wg": norm(k[3], (d, spec.heads)),
+            "wo": norm(k[4], (spec.heads * hd, d)),
+        }
+        if spec.mlp == "dense":
+            layer["mlp"] = _gated_mlp_init(k[5], d, cfg.dense_ffn, std)
+        else:
+            layer["moe"] = moe_share_init(
+                k[5], d, cfg.expert_ffn, cfg.num_experts,
+                cfg.experts_held[1], std)
+            layer["shared"] = _gated_mlp_init(k[6], d, cfg.shared_ffn, std)
+        params["layers"].append(layer)
+    return params
+
+
+def param_specs(cfg: CausalLMConfig) -> dict:
+    """Everything replicated: the share of the experts and of the
+    vocabulary is this program's whole state, and `data` splits the rows."""
+    shapes = jax.eval_shape(lambda: init_params(cfg, jax.random.key(0)))
+    return jax.tree_util.tree_map(lambda _: P(), shapes)
+
+
+# -- rotary tables ------------------------------------------------------------
+
+def rope_inv_freq(rope: dict, head_dim: int):
+    """(inverse frequencies float64 [rot/2], factor on cos and sin) of one
+    `rope_parameters` entry: the first `partial_rotary_factor * head_dim`
+    dimensions rotate. `yarn` blends the interpolated frequencies (over
+    `factor`) into the extrapolated ones by a linear ramp over the rotary
+    dimensions, between the dimensions that turn `beta_fast` and
+    `beta_slow` times within the original context."""
+    rot = int(rope.get("partial_rotary_factor", 1) * head_dim)
+    base = float(rope["rope_theta"])
+    pos = base ** (np.arange(0, rot, 2, dtype=np.float64) / rot)
+    if rope.get("rope_type", "default") != "yarn":
+        return 1.0 / pos, 1.0
+    factor, orig = rope["factor"], rope["original_max_position_embeddings"]
+    turn = lambda n: rot * math.log(  # noqa: E731
+        orig / (n * 2 * math.pi)) / (2 * math.log(base))
+    low = max(math.floor(turn(rope["beta_fast"])), 0)
+    high = min(math.ceil(turn(rope["beta_slow"])), rot - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / (high - low), 0, 1)
+    inv = (1.0 / (factor * pos)) * ramp + (1.0 / pos) * (1 - ramp)
+    scale = rope.get("attention_factor")
+    if scale is None:
+        scale = 0.1 * math.log(factor) + 1.0
+    return inv, float(scale)
+
+
+def rope_tables(rope: dict, head_dim: int, seq: int):
+    """(cos, sin) float32 [seq, rot/2] for positions 0..seq-1."""
+    inv, scale = rope_inv_freq(rope, head_dim)
+    ang = np.arange(seq, dtype=np.float64)[:, None] * inv[None, :]
+    return (jnp.asarray(np.cos(ang) * scale, jnp.float32),
+            jnp.asarray(np.sin(ang) * scale, jnp.float32))
+
+
+def apply_rope(x, cos, sin):
+    """Rotate-half over the first 2 * cos.shape[-1] dimensions of each head,
+    in float32; the rest pass. x: [B, T, H, D]."""
+    half = cos.shape[-1]
+    xf = x.astype(jnp.float32)
+    x1, x2, rest = xf[..., :half], xf[..., half:2 * half], xf[..., 2 * half:]
+    c, s = cos[None, :, None, :], sin[None, :, None, :]
+    return jnp.concatenate(
+        [x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1).astype(x.dtype)
+
+
+# -- attention ----------------------------------------------------------------
+
+def _on_tpu():
+    return jax.default_backend() == "tpu"
+
+
+def causal_attention(q, k, v, window=None):
+    """Causal attention with grouped K and V. q: [B, T, H, D]; k, v:
+    [B, T, KV, D] with H a multiple of KV; query head h reads KV head
+    h // (H / KV). `window` also masks `i - j >= window`. -> [B, T, H, D].
+
+    On a TPU, for sequences the kernel's tiles divide, the splash kernel
+    (blocked online softmax) visits only the blocks the mask leaves:
+    a sliding layer does the work of its window, not of the sequence.
+    Elsewhere a plain masked product."""
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    q = (q * (1.0 / math.sqrt(d))).astype(q.dtype)
+    # [B, KV, G, T, D] and [B, KV, T, D]
+    qg = jnp.transpose(q.reshape(b, t, kv, g, d), (0, 2, 3, 1, 4))
+    kt, vt = (jnp.transpose(a, (0, 2, 1, 3)) for a in (k, v))
+    if _on_tpu() and t % ATTENTION_BLOCK == 0:
+        out = _splash(qg, kt, vt, window)
+    else:
+        i, j = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+        seen = j <= i
+        if window is not None:
+            seen &= i - j < window
+        s = jnp.einsum("bkgqd,bksd->bkgqs", qg, kt,
+                       preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        out = jnp.einsum("bkgqs,bksd->bkgqd", p.astype(vt.dtype), vt,
+                         preferred_element_type=jnp.float32).astype(q.dtype)
+    return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, t, h, d)
+
+
+def _splash(qg, kt, vt, window):
+    """One multi-query kernel (G query heads on one K and V head) mapped
+    over the KV heads and the batch."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel, splash_attention_mask as mask)
+
+    g, t = qg.shape[2], qg.shape[3]
+    one = (mask.CausalMask((t, t)) if window is None else
+           mask.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
+    blk = ATTENTION_BLOCK
+    sizes = kernel.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
+        block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
+        block_kv_dq=blk)
+    fn = kernel.make_splash_mqa_single_device(
+        mask.MultiHeadMask([one] * g), block_sizes=sizes)
+    return jax.vmap(jax.vmap(fn))(qg, kt, vt)
+
+
+# -- the block ----------------------------------------------------------------
+
+def rms_norm(x, g, eps):
+    xf = x.astype(jnp.float32)
+    return xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps) * g
+
+
+def _mm(a, w):
+    """Operands in the activations' dtype, float32 accumulation."""
+    return jnp.matmul(a, w.astype(a.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def gated_mlp(p, u):
+    return _mm((jax.nn.silu(_mm(u, p["gate"])) * _mm(u, p["up"]))
+               .astype(u.dtype), p["down"])
+
+
+def attention_block(lp, u, cfg: CausalLMConfig, spec: LayerSpec, tables):
+    """u: the layer's normed input [B, T, d] -> [B, T, d] float32."""
+    b, t, _ = u.shape
+    hd = cfg.head_dim
+    heads = lambda w, n: _mm(u, w).astype(u.dtype).reshape(  # noqa: E731
+        b, t, n, hd)
+    q, k, v = (heads(lp["wq"], spec.heads), heads(lp["wk"], cfg.kv_heads),
+               heads(lp["wv"], cfg.kv_heads))
+    cos, sin = tables[spec.attention]
+    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    o = causal_attention(
+        q, k, v, cfg.sliding_window if spec.attention == "sliding" else None)
+    # the output gate, one value a head, from the same normed input
+    gate = jax.nn.sigmoid(_mm(u, lp["wg"]))
+    o = (o * gate[..., None].astype(o.dtype)).reshape(b, t, spec.heads * hd)
+    return _mm(o, lp["wo"])
+
+
+def layer_forward(lp, x, cfg: CausalLMConfig, spec: LayerSpec, tables):
+    """x [B, T, d] in the compute dtype -> (y, choices int32 [held experts],
+    dropped int32); the two counts are nought on a dense layer."""
+    dtype = x.dtype
+    b, t, d = x.shape
+    with jax.named_scope("attention." + spec.attention):
+        u = rms_norm(x, lp["attn_norm"], cfg.rms_eps).astype(dtype)
+        h = (x + attention_block(lp, u, cfg, spec, tables)).astype(dtype)
+    u = rms_norm(h, lp["mlp_norm"], cfg.rms_eps).astype(dtype)
+    count = cfg.experts_held[1]
+    if spec.mlp == "dense":
+        with jax.named_scope("mlp.dense"):
+            out = gated_mlp(lp["mlp"], u)
+        choices = jnp.zeros((count,), jnp.int32)
+        dropped = jnp.zeros((), jnp.int32)
+    else:
+        routed, choices, dropped = moe_share_apply(
+            lp["moe"], u.reshape(b * t, d), top_k=cfg.top_k,
+            experts_held=cfg.experts_held, routed_scale=cfg.routed_scale)
+        with jax.named_scope("moe.shared"):
+            out = routed.reshape(b, t, d) + gated_mlp(lp["shared"], u)
+    return (h + out).astype(dtype), choices, dropped
+
+
+def forward(params, cfg: CausalLMConfig, tokens):
+    """tokens [B, T] int32 -> (final hidden states [B, T, d], normed, in
+    the compute dtype; choices int32 [sparse layers, held experts]; dropped
+    int32 [sparse layers])."""
+    dtype = jnp.dtype(cfg.compute_dtype)
+    t = tokens.shape[1]
+    tables = {kind: rope_tables(cfg.rope[kind], cfg.head_dim, t)
+              for kind in {s.attention for s in cfg.layers}}
+    x = params["embed"][tokens].astype(dtype)
+    choices, dropped = [], []
+    for lp, spec in zip(params["layers"], cfg.layers):
+        # recomputation a layer: the backward pass keeps one layer's
+        # activations and every layer's input
+        x, c, dr = jax.checkpoint(
+            lambda lp_, x_, spec=spec: layer_forward(
+                lp_, x_, cfg, spec, tables))(lp, x)
+        if spec.mlp == "sparse":
+            choices.append(c)
+            dropped.append(dr)
+    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(dtype)
+    held = cfg.experts_held[1]
+    return (x, jnp.stack(choices) if choices
+            else jnp.zeros((0, held), jnp.int32),
+            jnp.stack(dropped) if dropped else jnp.zeros((0,), jnp.int32))
+
+
+def logits(params, cfg: CausalLMConfig, tokens):
+    """Float32 logits [B, T, vocab_held]."""
+    x, _, _ = forward(params, cfg, tokens)
+    with jax.named_scope("lm_head"):
+        return _mm(x, params["head"])
+
+
+def lm_loss(params, cfg: CausalLMConfig, tokens, labels):
+    """Next-token cross-entropy: float32 log-softmax over the held rows of
+    the vocabulary, mean over the positions that have a next token
+    (`labels >= 0`; -100 elsewhere). -> (loss, (choices, dropped))."""
+    x, choices, dropped = forward(params, cfg, tokens)
+    valid = labels >= 0
+    safe = jnp.where(valid, labels, 0)
+
+    @jax.checkpoint
+    def chunk_nll(head, x_c, safe_c, valid_c):
+        # one chunk's [B, chunk, V] logits at a time, made again going
+        # backward
+        lp = jax.nn.log_softmax(_mm(x_c, head), axis=-1)
+        got = jnp.take_along_axis(lp, safe_c[..., None], axis=-1)[..., 0]
+        return -jnp.sum(jnp.where(valid_c, got, 0.0))
+
+    b, t = tokens.shape
+    n = t // math.gcd(t, LOSS_CHUNK)
+    chunks = lambda a: jnp.moveaxis(  # noqa: E731
+        a.reshape(b, n, t // n, *a.shape[2:]), 1, 0)
+    with jax.named_scope("lm_head"):
+        nll = jax.lax.map(lambda a: chunk_nll(params["head"], *a),
+                          (chunks(x), chunks(safe), chunks(valid)))
+    loss = jnp.sum(nll) / jnp.maximum(jnp.sum(valid), 1)
+    return loss, (choices, dropped)
+
+
+# -- the trainer --------------------------------------------------------------
+
+MOE_CHOICES_HELP = ("Expert choices the sparse layer's router made "
+                    "(tokens x experts per token), by layer")
+MOE_HELD_HELP = ("Expert choices that fell on an expert this program "
+                 "holds, by layer")
+MOE_DROPPED_HELP = ("Held expert choices that did not fit the expert "
+                    "layer's buffer and were left out, by layer: 0 while "
+                    "the layer is dropless")
+MOE_LOAD_HELP = ("Sum over steps of the fullest held expert's choices over "
+                 "the held experts' mean; over dl4j_moe_steps_total it is "
+                 "the mean imbalance, by layer")
+MOE_STEPS_HELP = "Train steps whose router counts have been published"
+
+
+class CausalLMTrainer:
+    """`train_step(tokens, labels)` over the shared step engine: fwd + bwd
+    + Adam in one donated executable, rows over `data`. Beside the loss the
+    step returns what each sparse layer's router did; the trainer
+    publishes those counts one step behind, so that reading them never
+    holds up a dispatch. `params` starts from weights of the caller's (a
+    tree shaped as `init_params`'s) where the seed's are not wanted; with
+    `warmup_steps` the rate climbs linearly to `lr` over that many steps."""
+
+    def __init__(self, cfg: CausalLMConfig, mesh: Mesh, lr=1e-4, seed=0,
+                 params=None, warmup_steps=0):
+        self.cfg, self.mesh, self.lr = cfg, mesh, lr
+        self.warmup_steps = warmup_steps
+        rows = NamedSharding(mesh, spec_for(mesh, DATA_AXIS))
+        repl = NamedSharding(mesh, P())
+
+        def step(params, opt, tokens, labels, t):
+            return self._step_math(params, opt, tokens, labels, t)
+
+        self._engine = StepEngine(
+            mesh, params if params is not None
+            else lambda: init_params(cfg, jax.random.key(seed)),
+            param_specs(cfg), step, (rows, rows), aux_sh=((repl, repl),))
+        # the last step's (choices [sparse layers, held experts], dropped
+        # [sparse layers]) on the device, and what waits to be published
+        self.router_counts = None
+        self._unpublished = None
+        self._series = None      # the dl4j_moe_* families, once bound
+
+    params = property(lambda self: self._engine.params)
+    opt = property(lambda self: self._engine.opt)
+
+    def _step_math(self, params, opt, tokens, labels, t):
+        cfg, lr = self.cfg, self.lr
+        if self.warmup_steps:
+            lr = lr * jnp.minimum(
+                (t + 1).astype(jnp.float32) / self.warmup_steps, 1.0)
+        return loss_and_adam(
+            lambda p: lm_loss(p, cfg, tokens, labels), params, opt, lr, t,
+            has_aux=True)
+
+    def train_step(self, tokens, labels):
+        """tokens [B, T] int32; labels [B, T], the next token at each
+        position and -100 where there is none. Returns the loss (on the
+        device)."""
+        loss, counts = self._engine.run(
+            lambda: (np.asarray(tokens, np.int32),
+                     np.asarray(labels, np.int32)),
+            lambda batch, steps: batch)
+        self.publish_router_counts()
+        self.router_counts = counts
+        self._unpublished = (counts, int(np.size(tokens)))
+        return loss
+
+    def publish_router_counts(self):
+        """Add the counts of the last step that has not been published to
+        the registry's `dl4j_moe_*` series (and wait for that step). After
+        a `train_step` that is the step before it; a caller that wants the
+        last step's too calls this once more."""
+        from deeplearning4j_tpu import telemetry
+
+        waiting, self._unpublished = self._unpublished, None
+        if waiting is None or not telemetry.enabled():
+            return
+        choices, dropped = (np.asarray(a) for a in waiting[0])
+        if self._series is None:
+            reg = telemetry.get_registry()
+            self._series = [
+                reg.counter(name, text, ("layer",)) for name, text in (
+                    ("dl4j_moe_choices_total", MOE_CHOICES_HELP),
+                    ("dl4j_moe_held_choices_total", MOE_HELD_HELP),
+                    ("dl4j_moe_dropped_total", MOE_DROPPED_HELP),
+                    ("dl4j_moe_load_max_over_mean_sum", MOE_LOAD_HELP))
+            ] + [reg.counter("dl4j_moe_steps_total", MOE_STEPS_HELP)]
+        n_all, held, lost, load, steps = self._series
+        every = waiting[1] * self.cfg.top_k
+        for i, layer in enumerate(self.cfg.sparse_layers):
+            label = {"layer": str(layer)}
+            n_all.labels(**label).inc(every)
+            held.labels(**label).inc(int(choices[i].sum()))
+            lost.labels(**label).inc(int(dropped[i]))
+            load.labels(**label).inc(
+                float(choices[i].max() / max(choices[i].mean(), 1e-9)))
+        steps.inc()

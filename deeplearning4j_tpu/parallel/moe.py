@@ -7,6 +7,11 @@ dispatch/combine tensors, expert FFNs are batched einsums with the expert
 axis sharded over `expert`, and XLA inserts the all-to-alls that move
 tokens to their experts. No custom scheduler, no per-expert kernels —
 the MXU sees E parallel [C, H] x [H, F] matmuls.
+
+Beside it, `moe_share_apply`: the dropless layer of a program that holds a
+share of the experts (sigmoid router over all of them, sort by expert,
+grouped products over the held ones, weighted scatter-add), which
+`models/causal_lm.py` trains.
 """
 
 from __future__ import annotations
@@ -96,6 +101,101 @@ def moe_apply(params, x, k: int = 2, capacity_factor: float = 1.5):
                   + params["b2"][:, None, :])
     y = jnp.einsum("nec,ech->nh", comb, expert_out)
     return y, aux
+
+
+def moe_share_init(key, hidden: int, ffn: int, n_experts: int, held: int,
+                   std: float = 0.02) -> dict:
+    """A router over all `n_experts` and the gated MLPs of the `held`
+    experts that live here, float32."""
+    k = jax.random.split(key, 4)
+    norm = lambda kk, shape: jax.random.normal(  # noqa: E731
+        kk, shape, jnp.float32) * std
+    return {"router": norm(k[0], (hidden, n_experts)),
+            "gate": norm(k[1], (held, hidden, ffn)),
+            "up": norm(k[2], (held, hidden, ffn)),
+            "down": norm(k[3], (held, ffn, hidden))}
+
+
+# The buffer of a share's (token, choice) pairs holds this many times the even
+# share. At the worst case instead (every choice of every token: 8x for 32 of
+# 256 experts) the step of PERF.md's cell took 879 ms against 743 (PR 28).
+SHARE_BUFFER = 2.0
+
+
+def moe_share_rows(n_tokens: int, top_k: int, n_experts: int,
+                   held: int) -> int:
+    """Rows of the buffer `moe_share_apply` works on: `SHARE_BUFFER` times
+    the even share of the choices (a multiple of 8), and never more than
+    every choice of every token, which is what the whole layer gets."""
+    worst = n_tokens * top_k
+    even = worst * held / n_experts
+    return min(worst, int(math.ceil(SHARE_BUFFER * even / 8.0)) * 8)
+
+
+def moe_share_apply(params, x, *, top_k: int, experts_held,
+                    routed_scale: float = 1.0):
+    """The part of a sigmoid-routed expert layer that the experts held here
+    give. x: [N, H] -> (y [N, H] float32, choices int32 [held], dropped
+    int32 scalar).
+
+    `experts_held = (first, count)` names the experts whose weights
+    `params` holds (`gate`, `up` [count, H, F], `down` [count, F, H]). The
+    router scores every token over ALL experts (`router` [H, E], float32
+    sigmoid), takes the `top_k` largest and weighs each chosen expert by
+    `routed_scale * s_e / sum of the chosen s`. The (token, choice) pairs
+    whose expert lives here are sorted by expert into one buffer of static
+    size, pass through three grouped products (`jax.lax.ragged_dot`, which
+    on a TPU is a kernel that skips the tiles no group fills) and are
+    added, weighted, into their tokens' rows. The buffer holds
+    `moe_share_rows` pairs, twice the even share; `dropped` counts the held
+    pairs that did not fit and were left out, and a caller that wants the
+    layer dropless holds that count to nought (the whole layer's buffer is
+    the worst case and drops nothing). What the absent experts would add is
+    left out: on one chip the layer runs without its exchange, and the
+    shares of all chips add up to the whole layer. `choices[e]` counts the
+    pairs routed to held expert `e`."""
+    n, _ = x.shape
+    first, count = experts_held
+    n_experts = params["router"].shape[1]
+    rows = moe_share_rows(n, top_k, n_experts, count)
+    dtype = x.dtype
+    with jax.named_scope("moe.route"):
+        scores = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), params["router"].astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        top_s, top_i = jax.lax.top_k(scores, top_k)            # [N, k]
+        weight = (routed_scale * top_s
+                  / jnp.sum(top_s, -1, keepdims=True)).reshape(-1)
+        local = top_i - first
+        here = (local >= 0) & (local < count)
+        # pairs of absent experts sort behind every held one
+        group = jnp.where(here, local, count).reshape(-1)       # [N*k]
+        pair = jnp.argsort(group, stable=True)[:rows]
+        choices = jnp.sum(
+            group[:, None] == jnp.arange(count, dtype=group.dtype)[None],
+            axis=0, dtype=jnp.int32)
+        # where each held group ends in the buffer: cut at its end
+        ends = jnp.minimum(jnp.cumsum(choices), rows)
+        dropped = jnp.sum(choices) - ends[-1]
+        sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+        token = pair // top_k
+        filled = jnp.arange(rows) < ends[-1]
+        w_rows = jnp.where(filled, weight[pair], 0.0)
+    with jax.named_scope("moe.experts"):
+        # On a TPU the grouped product leaves the rows past the last group
+        # as it found them, forward and backward: whatever crosses such a
+        # product is selected (never multiplied) back to nought on the
+        # other side, so that neither the rows nor their cotangents reach a
+        # token.
+        live = lambda a: jnp.where(filled[:, None], a, 0)  # noqa: E731
+        xs = live(x[token])                                     # [rows, H]
+        dot = lambda a, b: live(jax.lax.ragged_dot(  # noqa: E731
+            a, b.astype(dtype), sizes, preferred_element_type=jnp.float32))
+        mid = (jax.nn.silu(dot(xs, params["gate"]))
+               * dot(xs, params["up"])).astype(dtype)
+        out = dot(mid, params["down"]) * w_rows[:, None]        # f32
+        y = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
+    return y, choices, dropped
 
 
 class MoELayerTrainer:

@@ -1,0 +1,357 @@
+"""The causal LM block, the dropless expert share and the step engine at a
+small size on the CPU, against the plain reference
+`benchmark/reference/laguna_plain.py` (which imports nothing from the
+program): widths 64, 8 experts top-2, window 8 at 32 positions, five layers
+in the published pattern (full dense, three sliding sparse, full sparse),
+seeded weights."""
+
+import json
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+from benchmark.drivers import train_lm  # noqa: E402
+from benchmark.reference import laguna_plain as plain  # noqa: E402
+from deeplearning4j_tpu.models import causal_lm as lm  # noqa: E402
+from deeplearning4j_tpu.parallel import moe  # noqa: E402
+from deeplearning4j_tpu.parallel.mesh import MeshConfig  # noqa: E402
+
+with open(os.path.join(ROOT, "tests", "benchmark", "data", "toy-lm",
+                       "configs", "toy-laguna.json")) as f:
+    TOY = json.load(f)
+PUB = TOY["published"]
+SEQ = 32
+
+
+def config(held=(0, 4), dtype="float32"):
+    return lm.CausalLMConfig.from_published(
+        PUB, num_layers=5, experts_held=held, vocab_held=64,
+        compute_dtype=dtype)
+
+
+def sizes(held=(0, 4)):
+    toy = dict(TOY, model=dict(TOY["model"], experts_held=list(held)))
+    return train_lm.reference_sizes(toy)
+
+
+def batch(seed=0, rows=2):
+    return next(train_lm.traffic_lm.lm_batches(
+        {"rows": rows, "seq": SEQ}, 64, seed))
+
+
+def close(a, b, tol):
+    a, b = np.asarray(a, np.float64), np.asarray(b, np.float64)
+    return np.max(np.abs(a - b)) <= tol * max(np.max(np.abs(b)), 1e-30)
+
+
+def test_layers_follow_the_published_pattern():
+    cfg = config()
+    assert [(s.attention, s.heads, s.mlp) for s in cfg.layers] == [
+        ("full", 4, "dense"), ("sliding", 6, "sparse"),
+        ("sliding", 6, "sparse"), ("sliding", 6, "sparse"),
+        ("full", 4, "sparse")]
+    assert cfg.sparse_layers == [1, 2, 3, 4]
+    p = lm.init_params(cfg, jax.random.key(0))
+    assert p["layers"][1]["wq"].shape == (64, 6 * 16)
+    assert p["layers"][4]["wg"].shape == (64, 4)
+    assert p["layers"][2]["moe"]["gate"].shape == (4, 64, 32)
+    assert p["layers"][2]["moe"]["router"].shape == (64, 8)
+    assert "moe" not in p["layers"][0] and p["embed"].shape == (64, 64)
+
+
+def test_logits_and_loss_match_the_plain_reference():
+    cfg, tok_lab = config(), batch()
+    params = lm.init_params(cfg, jax.random.key(1))
+    ref_p = train_lm.rename(params)
+    got = lm.logits(params, cfg, jnp.asarray(tok_lab[0]))
+    nll = 0.0
+    for r in range(2):
+        want, _ = plain.row_logits(ref_p, sizes(), jnp.asarray(tok_lab[0][r]),
+                                   block_q=8)
+        assert close(got[r], want, 2e-5)
+        nll += float(plain.row_nll(ref_p, sizes(), jnp.asarray(tok_lab[0][r]),
+                                   jnp.asarray(tok_lab[1][r]), block_q=8)[0])
+    loss, (choices, dropped) = lm.lm_loss(params, cfg, *map(jnp.asarray,
+                                                           tok_lab))
+    assert abs(float(loss) - nll / (2 * (SEQ - 1))) < 1e-5
+    assert choices.shape == (4, 4) and int(dropped.sum()) == 0
+
+
+def test_the_reference_draws_the_weights_the_program_is_fed():
+    """The weights of a run are the reference's own, from the seed, in its
+    layout; renamed they are a tree the program's trainer takes."""
+    p0 = plain.draw_params(sizes(), 2 ** 31 + 5)
+    again, other = plain.draw_params(sizes(), 2 ** 31 + 5), \
+        plain.draw_params(sizes(), 6)
+    names = plain.leaf_names(p0)
+    for name, leaf in names.items():
+        assert leaf.dtype == np.float32
+        assert (leaf == plain.leaf_names(again)[name]).all(), name
+        if leaf.ndim > 1:
+            assert (leaf != plain.leaf_names(other)[name]).any(), name
+    shapes = jax.eval_shape(lambda: lm.init_params(config(),
+                                                   jax.random.key(0)))
+    want = {k: v.shape for k, v in plain.leaf_names(
+        train_lm.rename(shapes)).items()}
+    assert {k: v.shape for k, v in names.items()} == want
+    # rows of unit scale, matrices of 0.02, gains of one
+    assert abs(float(np.std(p0["embed_tokens"])) - 1.0) < 0.05
+    assert abs(float(np.std(p0["lm_head"])) - 0.02) < 0.002
+    assert abs(float(np.std(p0["layers"][2]["experts"]["up_proj"]))
+               - 0.02) < 0.002
+    assert (p0["layers"][3]["input_layernorm"] == 1).all()
+    # the two renamings undo each other, leaf for leaf
+    back = plain.leaf_names(train_lm.rename(train_lm.to_program(p0)))
+    assert all(back[k] is v for k, v in names.items())
+    assert jax.tree_util.tree_structure(train_lm.to_program(p0)) == \
+        jax.tree_util.tree_structure(shapes)
+
+
+def test_the_rate_warms_up():
+    """Adam's first step moves every element by the rate: a quarter of `lr`
+    at the first of four warm-up steps, all of it after them."""
+    mesh = MeshConfig(data=1, devices=jax.devices()[:1]).build()
+    for warmup, moved in ((4, 2.5e-4), (0, 1e-3)):
+        trainer = lm.CausalLMTrainer(config(), mesh, lr=1e-3, seed=1,
+                                     warmup_steps=warmup)
+        before = np.asarray(trainer.params["head"])
+        trainer.train_step(*batch())
+        step = np.abs(np.asarray(trainer.params["head"]) - before)
+        assert np.median(step) == pytest.approx(moved, rel=1e-3)
+
+
+@pytest.mark.parametrize("dtype, tol, change_tol, warmup", [
+    ("float32", 2e-4, 0.05, 0), ("bfloat16", 0.1, 0.5, 0),
+    ("float32", 2e-4, 0.05, 4)])
+def test_first_gradient_and_three_adam_steps_match(dtype, tol, change_tol,
+                                                   warmup):
+    """From the reference's own weights, loaded into the trainer."""
+    cfg = config(dtype=dtype)
+    mesh = MeshConfig(data=1, devices=jax.devices()[:1]).build()
+    p0 = plain.draw_params(sizes(), 3)
+    trainer = lm.CausalLMTrainer(cfg, mesh, lr=1e-3,
+                                 params=train_lm.to_program(p0),
+                                 warmup_steps=warmup)
+    ref = plain.LmReference(sizes(), p0, 1e-3, block_q=8,
+                            warmup_steps=warmup)
+    batches = train_lm.traffic_lm.lm_batches({"rows": 2, "seq": SEQ}, 64, 5)
+    for i in range(3):
+        tok, lab = next(batches)
+        loss = float(trainer.train_step(tok, lab))
+        want, _, choices = ref.step(tok, lab)
+        assert abs(loss - want) < tol * 0.1 * want
+        gap = train_lm.held_choices_gap(
+            [np.asarray(trainer.router_counts[0])], [choices])
+        assert gap <= (0.0 if dtype == "float32" else 0.05)
+        if i == 0:
+            g1 = jax.tree_util.tree_map(
+                lambda m: m / np.float32(0.1),
+                train_lm.rename(jax.device_get(trainer.opt["m"])))
+            for name, want_g in plain.leaf_names(ref.g1).items():
+                assert close(plain.leaf_names(g1)[name], want_g, tol), name
+    got = plain.leaf_names(train_lm.rename(jax.device_get(trainer.params)))
+    # an element whose gradient is nought to rounding moves under Adam by
+    # its sign alone: the change is compared as a whole, leaf by leaf
+    for name, want_p in plain.leaf_names(ref.params).items():
+        start = plain.leaf_names(p0)[name]
+        moved = np.asarray(want_p) - start
+        off = np.linalg.norm(got[name] - start - moved)
+        assert off <= change_tol * np.linalg.norm(moved), (name, off)
+
+
+def _layer_input(seed=0, n=2 * SEQ):
+    return jax.random.normal(jax.random.key(seed), (n, 64), jnp.float32)
+
+
+def _whole_layer(seed=2):
+    """An uncut sparse layer's weights in the reference's layout."""
+    whole = moe.moe_share_init(jax.random.key(seed), 64, 32, 8, 8)
+    shared = lm._gated_mlp_init(jax.random.key(seed + 1), 64, 32, 0.02)
+    names = train_lm.MLP_NAMES
+    ref_lp = {"router": whole["router"],
+              "experts": {names[k]: whole[k] for k in names},
+              "shared_expert": {names[k]: shared[k] for k in names}}
+    return whole, shared, ref_lp
+
+
+@pytest.mark.parametrize("count", [1, 2, 8])
+def test_the_shares_add_up_to_the_uncut_layer(count):
+    """The routed parts that the shares of a layer give (8 shares of one
+    expert, 4 of two, or the one share that holds all: `(0, E)` is the
+    whole), plus the shared expert once, equal the uncut reference's layer."""
+    whole, shared, ref_lp = _whole_layer()
+    u = _layer_input()
+    total, chosen = lm.gated_mlp(shared, u), 0
+    for first in range(0, 8, count):
+        share = {"router": whole["router"],
+                 **{k: whole[k][first:first + count]
+                    for k in ("gate", "up", "down")}}
+        y, choices, dropped = moe.moe_share_apply(
+            share, u, top_k=2, experts_held=(first, count),
+            routed_scale=2.5)
+        assert int(dropped) == 0 and choices.shape == (count,)
+        total, chosen = total + y, chosen + int(choices.sum())
+    want, all_choices = plain.sparse_mlp(ref_lp, u, sizes((0, 8)), "f32")
+    assert chosen == 2 * u.shape[0] == int(all_choices.sum())
+    assert close(total, want, 2e-5)
+
+
+def test_nothing_is_dropped_when_every_token_chooses_one_expert():
+    whole, _, ref_lp = _whole_layer()
+    # a router that sends every token to expert 1 first (and 0 second)
+    router = jnp.zeros((64, 8)).at[:, 1].set(1.0).at[:, 0].set(0.5)
+    u = jnp.abs(_layer_input())
+    share = {"router": router, **{k: whole[k][:4]
+                                  for k in ("gate", "up", "down")}}
+    y, choices, dropped = moe.moe_share_apply(
+        share, u, top_k=2, experts_held=(0, 4), routed_scale=2.5)
+    n = u.shape[0]
+    assert choices.tolist() == [n, n, 0, 0] and int(dropped) == 0
+    want, _ = plain.sparse_mlp(dict(ref_lp, router=router), u,
+                               sizes((0, 8)), "f32")
+    shared = plain.gated_mlp(ref_lp["shared_expert"], u, "f32")
+    assert close(y, want - shared, 2e-5)
+
+
+def test_a_buffer_that_falls_short_counts_what_it_left_out():
+    """Two held experts of eight get twice their even share of rows: with a
+    router that sends every token to both, the second's choices do not fit;
+    they are counted, and the first's part of the result stands."""
+    whole, _, ref_lp = _whole_layer()
+    router = jnp.zeros((64, 8)).at[:, 0].set(1.0).at[:, 1].set(0.5)
+    u = jnp.abs(_layer_input())
+    n = u.shape[0]
+    assert moe.moe_share_rows(n, 2, 8, 2) == n
+    assert moe.moe_share_rows(n, 2, 8, 8) == 2 * n      # the whole layer
+    assert moe.moe_share_rows(16384, 8, 256, 32) == 32768
+    share = {"router": router, **{k: whole[k][:2]
+                                  for k in ("gate", "up", "down")}}
+    y, choices, dropped = moe.moe_share_apply(
+        share, u, top_k=2, experts_held=(0, 2), routed_scale=2.5)
+    assert choices.tolist() == [n, n] and int(dropped) == n
+    # four held experts' buffer takes every choice: there, silence the second
+    quiet = {"router": router, "gate": whole["gate"][:4],
+             "up": whole["up"][:4], "down": whole["down"][:4].at[1].set(0.0)}
+    want, _, none = moe.moe_share_apply(
+        quiet, u, top_k=2, experts_held=(0, 4), routed_scale=2.5)
+    assert int(none) == 0 and close(y, want, 1e-6)
+
+
+def test_rotary_tables_against_the_closed_form():
+    ropes = PUB["rope_parameters"]
+    # plain, all 16 dimensions: theta^(-2i/16)
+    inv, scale = lm.rope_inv_freq(ropes["sliding_attention"], 16)
+    assert scale == 1.0 and np.allclose(
+        inv, [10000.0 ** (-2 * i / 16) for i in range(8)], rtol=1e-12)
+    # yarn over the first half of the head (8 dimensions, 4 frequencies)
+    y = ropes["full_attention"]
+    inv, scale = lm.rope_inv_freq(y, 16)
+    assert scale == pytest.approx(0.1 * math.log(64) + 1.0, rel=1e-12)
+    rot, base, orig = 8, 500000.0, 16
+    dim = lambda turns: rot * math.log(  # noqa: E731
+        orig / (turns * 2 * math.pi)) / (2 * math.log(base))
+    low, high = max(math.floor(dim(4)), 0), min(math.ceil(dim(1)), rot - 1)
+    want = []
+    for i in range(rot // 2):
+        f = base ** (-2 * i / rot)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        want.append(f / 64 * ramp + f * (1 - ramp))
+    assert low == 0 and high == 1     # so the blend is not all one side
+    assert np.allclose(inv, want, rtol=1e-12) and inv[0] == 1.0
+    assert inv[1] == pytest.approx(base ** (-2 / rot) / 64)
+    cos, sin = lm.rope_tables(y, 16, 6)
+    assert cos.shape == (6, 4) and cos.dtype == jnp.float32
+    assert np.allclose(cos[5], scale * np.cos(5 * np.asarray(want)),
+                       atol=1e-6)
+    # partial rotary: dimensions 8.. of a head pass untouched, 0..7 turn
+    x = jax.random.normal(jax.random.key(0), (1, 6, 2, 16))
+    out = lm.apply_rope(x, cos, sin)
+    assert np.array_equal(out[..., 8:], x[..., 8:])
+    assert np.allclose(out[0, 5, 0, 0],
+                       x[0, 5, 0, 0] * cos[5, 0] - x[0, 5, 0, 4] * sin[5, 0],
+                       atol=1e-6)
+    # the published full-attention entry: factor 64 over 4,096, 64 dimensions
+    pub = {"rope_theta": 500000, "rope_type": "yarn", "factor": 64,
+           "original_max_position_embeddings": 4096, "beta_slow": 1,
+           "beta_fast": 64, "attention_factor": 1.4158883083359672,
+           "partial_rotary_factor": 0.5}
+    inv, scale = lm.rope_inv_freq(pub, 128)
+    assert inv.shape == (32,) and scale == 1.4158883083359672
+    assert inv[0] == 1.0 and inv[-1] == pytest.approx(
+        500000.0 ** (-62 / 64) / 64)
+
+
+def test_the_sliding_mask_ends_at_the_window():
+    """At the published window: key i - 511 is seen, key i - 512 is not."""
+    t, window, i = 1024, 512, 900
+    q, k, v = (jax.random.normal(jax.random.key(s), (1, t, 2, 8))
+               for s in range(3))
+    row = lambda v_: lm.causal_attention(  # noqa: E731
+        q, k, v_, window=window)[0, i].sum()
+    g = np.abs(np.asarray(jax.grad(row)(v))[0]).sum(axis=(1, 2))
+    assert g[i - window + 1] > 0 and g[i] > 0
+    assert g[i - window] == 0 and g[i + 1] == 0
+    full = lambda v_: lm.causal_attention(q, k, v_)[0, i].sum()  # noqa: E731
+    g = np.abs(np.asarray(jax.grad(full)(v))[0]).sum(axis=(1, 2))
+    assert g[0] > 0 and g[i - window] > 0 and g[i + 1] == 0
+
+
+def test_grouped_heads_read_their_own_kv_head():
+    q, k, v = (jax.random.normal(jax.random.key(s), (1, 8, n, 4))
+               for s, n in ((0, 6), (1, 2), (2, 2)))
+    out = lm.causal_attention(q, k, v)
+    for h in range(6):
+        one = lm.causal_attention(q[:, :, h:h + 1], k[:, :, h // 3:h // 3 + 1],
+                                  v[:, :, h // 3:h // 3 + 1])
+        assert close(out[:, :, h], one[:, :, 0], 1e-5)
+
+
+def test_router_counts_are_published_one_step_behind():
+    from deeplearning4j_tpu import telemetry
+
+    old = telemetry.get_registry()
+    telemetry.set_registry(telemetry.MetricsRegistry())
+    try:
+        cfg = config()
+        mesh = MeshConfig(data=1, devices=jax.devices()[:1]).build()
+        trainer = lm.CausalLMTrainer(cfg, mesh, seed=0)
+        trainer.train_step(*batch(1))
+        snap = telemetry.get_registry().snapshot()
+        assert not any(k.startswith("dl4j_moe") for k in snap)
+        first = np.asarray(trainer.router_counts[0])
+        trainer.train_step(*batch(2))
+        snap = telemetry.get_registry().snapshot()
+        assert snap["dl4j_moe_steps_total"] == 1
+        assert snap['dl4j_moe_choices_total{layer="1"}'] == 2 * SEQ * 2
+        assert snap['dl4j_moe_held_choices_total{layer="4"}'] == first[3].sum()
+        assert snap['dl4j_moe_load_max_over_mean_sum{layer="2"}'] == \
+            pytest.approx(first[1].max() / first[1].mean())
+        trainer.publish_router_counts()
+        snap = telemetry.get_registry().snapshot()
+        assert snap["dl4j_moe_steps_total"] == 2
+        assert sum(v for k, v in snap.items()
+                   if k.startswith("dl4j_moe_dropped_total")) == 0
+    finally:
+        telemetry.set_registry(old)
+
+
+def test_both_trainers_share_one_engine_and_one_adam():
+    from deeplearning4j_tpu.models.bert import BertConfig, BertTrainer
+    from deeplearning4j_tpu.parallel.step_engine import StepEngine
+
+    mesh = MeshConfig(data=1, devices=jax.devices()[:1]).build()
+    bert = BertTrainer(BertConfig(vocab_size=64, hidden=32, num_layers=1,
+                                  num_heads=2, ffn=64, max_len=16), mesh)
+    causal = lm.CausalLMTrainer(config(), mesh)
+    assert isinstance(bert._engine, StepEngine)
+    assert isinstance(causal._engine, StepEngine)
+    assert bert._build().__wrapped__.__name__ == "step"
+    assert causal._engine.build().__wrapped__.__name__ == "step"
