@@ -19,12 +19,15 @@ The KV cache is PAGED (`PagedKVCache`): a pool of fixed-size
 ``[page]``-token blocks with a per-slot page table. A joining sequence
 reserves ``ceil(total_len / page)`` pages up front (no mid-flight
 eviction), a leaving one returns them; page 0 is a scratch page that
-idle slots write into so the step function stays branch-free. The
-blocked attention accumulation — iterate over pages, carry flash-style
-online-softmax ``(m, l, o)`` — is `parallel/ring_attention.py`'s ring
-body with pages in place of ring ranks (and no collectives: a decode
-replica is single-device; the engine thread must stay collective-free
-per the dl4jlint collective-thread rule).
+idle slots write into so the step function stays branch-free.
+Attention visits the batch's LIVE pages only: one list of (slot, page)
+pairs a step, made on the device from ``pos`` and the page table, one
+loop a layer over chunks of it whose trip count is read from the data,
+each page reduced on its own to a softmax partial ``(m, l, o)`` and a
+slot's partials combined in its page table's order (split-K over
+pages; no collectives: a decode replica is single-device; the engine
+thread must stay collective-free per the dl4jlint collective-thread
+rule).
 
 Two shipped models:
 
@@ -361,16 +364,79 @@ class RnnDecodeModel:
         return self._jit_reset(state, np.int32(slot))
 
 
+# entries of the live-page list that one iteration of the attention loop
+# reduces: a constant of the code, settled on the chip (PERF.md, PR 29)
+LIVE_CHUNK = 64
+
+
+def _dot_01(x, ones):
+    """``x @ ones`` in float32 for a 0/1 matrix given in bfloat16. ``x``
+    goes through the matrix unit as three bfloat16 pieces that add up to
+    it bit for bit (8 + 8 + 8 bits of its significand); a piece times 0
+    or 1 is exact, and the pieces' products are summed in float32. It
+    is what ``Precision.HIGHEST`` computes in six passes, because that
+    splits both operands; ``ones`` needs no splitting (on the chip
+    3.18 ms a step against 3.75: PERF.md, PR 29)."""
+    import jax.numpy as jnp
+
+    out, rest = 0.0, x
+    for _ in range(3):
+        piece = rest.astype(jnp.bfloat16)
+        out = out + jnp.dot(piece, ones,
+                            preferred_element_type=jnp.float32)
+        rest = rest - piece.astype(jnp.float32)
+    return out
+
+
+def live_pages(pos, table, page):
+    """The step's list of live (slot, page) pairs, from what the step is
+    given: slot ``s`` at position ``pos[s]`` has ``n[s] = pos[s] // page
+    + 1`` live pages, an exclusive prefix sum of ``n`` gives its offset,
+    and flat entry ``j < n_live = sum(n)`` belongs to the slot whose
+    range holds ``j``, as its page index ``i = j - offset[slot]``. The
+    list is as long as the page table, ``S * P``, rounded up to whole
+    chunks of ``LIVE_CHUNK`` entries (one chunk where the table is
+    shorter than that).
+
+    For the loop: ``slot``, ``page`` (the pool page ``table[slot, i]``;
+    the scratch page past ``n_live``), ``last`` (``pos[slot]`` counted
+    from that page's first row: the rows up to it are seen; -1 past
+    ``n_live``, where none is) and ``n_live``. For the combination:
+    ``own [S, P]``, entry ``offset[s] + i`` of slot ``s``, and ``dead
+    [S, P]``, True where ``i >= n[s]``."""
+    import jax.numpy as jnp
+
+    S, P = table.shape
+    chunk = min(LIVE_CHUNK, S * P)
+    n = pos // page + 1
+    ends = jnp.cumsum(n)
+    offset = ends - n
+    j = jnp.arange(-(-S * P // chunk) * chunk)
+    alive = j < ends[-1]
+    slot = jnp.minimum(jnp.sum(j[:, None] >= ends[None, :], axis=1),
+                       S - 1)
+    i = jnp.where(alive, j - offset[slot], 0)
+    i_own = jnp.arange(P)
+    dead = i_own[None, :] >= n[:, None]
+    return {
+        "slot": slot,
+        "page": jnp.where(alive, table[slot, i], 0),
+        "last": jnp.where(alive, pos[slot] - i * page, -1),
+        "n_live": ends[-1],
+        "own": jnp.where(dead, 0, offset[:, None] + i_own[None, :]),
+        "dead": dead}
+
+
 class TransformerDecodeModel:
     """Causal single-token decode over a paged KV pool.
 
     Mirrors `models/bert.py`'s post-LN encoder block (qkv/out/ln1/ffn/
     ln2 naming, gelu FFN, tied LM head), so `from_bert()` serves a
-    trained encoder's weights as a token stream. Attention per slot
-    iterates its OWN page-table pages with the flash-style online
-    softmax carried from `ring_attention._ring_attention_local` (pages
-    play the role of ring ranks; no collectives — replicas are
-    single-device)."""
+    trained encoder's weights as a token stream. Attention reads the
+    batch's live pages and no others (`live_pages`, `_paged_attention`):
+    a step over short contexts costs what they hold, a full pool what
+    it always did, through one executable (the loop's trip count is
+    data, not shape)."""
 
     uses_pages = True
     # the KV pool is donated to every executable over it (``state`` is
@@ -455,47 +521,68 @@ class TransformerDecodeModel:
         return {"k": jnp.zeros(self._pool_shape(), jnp.float32),
                 "v": jnp.zeros(self._pool_shape(), jnp.float32)}
 
-    def _paged_attention(self, q, kpool, vpool, li, table, pos):
-        """q [S,H,D] against this slot's pages of layer ``li``, gathered
-        out of the whole pools (no layer of a pool is ever a value of
-        its own). Blockwise online softmax over the page axis —
-        ring_attention's accumulation with pages instead of ring ranks;
-        masked pages contribute exactly zero, so a slot's output never
-        depends on its neighbors."""
+    def _paged_attention(self, q, kpool, vpool, li, live):
+        """q [S, H*D] against each slot's own context in layer ``li``,
+        over the step's list of live pages (``live_pages``): a loop over
+        chunks of ``LIVE_CHUNK`` entries, ``ceil(n_live / LIVE_CHUNK)``
+        of them, gathers each entry's K and V page out of the whole
+        pools (no layer of a pool is ever a value of its own) and
+        reduces the page ON ITS OWN to its scores' maximum ``m [H]``,
+        their sum ``l [H]`` and the weighted values ``o [H*D]``, written
+        at the entry's place. A slot then combines its entries in its
+        page table's order, dead ones as exact zeros (split-K over
+        pages). An entry's partial is row-wise math on its own page and
+        the combination runs over a fixed range, so a slot's output
+        depends neither on what its neighbours hold nor on where a
+        chunk's edge falls.
+
+        A row is scored as it lies, H*D wide: the product with q summed
+        over each head's D lanes by a constant 0/1 ``[H*D, H]`` matrix,
+        the probabilities spread back over the same lanes by its
+        transpose, both in float32 (``_dot_01``; reshaping a gathered
+        block into heads costs the device a relayout: PERF.md, PR 27's
+        trace)."""
         import jax.numpy as jnp
         from jax import lax
 
-        s_, h_, d_ = q.shape
-        scale = 1.0 / math.sqrt(d_)
-        page = self.page
+        hd = q.shape[1]
+        H, page = self.n_heads, self.page
+        N = live["slot"].shape[0]       # whole chunks: ``live_pages``
+        C = min(LIVE_CHUNK, N)
+        heads = jnp.asarray(np.repeat(np.eye(H), hd // H, axis=0),
+                            jnp.bfloat16)                    # [H*D, H]
+        lanes = heads.T
+        scale = 1.0 / math.sqrt(self.head_dim)
+        rows = jnp.arange(page)
 
-        def body(i, carry):
-            m, l, o = carry
-            kb = kpool[li, table[:, i]].reshape(s_, page, h_, d_)
-            vb = vpool[li, table[:, i]].reshape(s_, page, h_, d_)
-            s = jnp.einsum("shd,sphd->shp", q, kb) * scale
-            k_pos = i * page + jnp.arange(page)      # this block's slots
-            mask = k_pos[None, :] <= pos[:, None]    # causal + length
-            s = jnp.where(mask[:, None, :], s, -jnp.inf)
-            blk_max = jnp.max(s, axis=-1)            # [S, H]
-            new_m = jnp.maximum(m, blk_max)
-            new_m_safe = jnp.where(jnp.isfinite(new_m), new_m, 0.0)
-            p = jnp.exp(s - new_m_safe[..., None])
-            p = jnp.where(jnp.isfinite(s), p, 0.0)
-            corr = jnp.exp(
-                jnp.where(jnp.isfinite(m), m - new_m_safe, -jnp.inf))
-            corr = jnp.where(jnp.isfinite(m), corr, 0.0)
-            new_l = l * corr + jnp.sum(p, axis=-1)
-            new_o = o * corr[..., None] + jnp.einsum("shp,sphd->shd",
-                                                     p, vb)
-            return new_m, new_l, new_o
+        def body(c, bufs):
+            at = c * C
+            slot, pg, last = (lax.dynamic_slice_in_dim(live[k], at, C)
+                              for k in ("slot", "page", "last"))
+            kb, vb = kpool[li, pg], vpool[li, pg]       # [C, page, H*D]
+            s = _dot_01(kb * q[slot][:, None, :], heads) * scale
+            seen = rows[None, :] <= last[:, None]       # causal + length
+            s = jnp.where(seen[:, :, None], s, -jnp.inf)
+            m = jnp.max(s, axis=1)                      # [C, H]
+            p = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0)[:, None])
+            o = jnp.sum(_dot_01(p, lanes) * vb, axis=1)  # [C, H*D]
+            return tuple(
+                lax.dynamic_update_slice_in_dim(buf, x, at, axis=0)
+                for buf, x in zip(bufs, (m, jnp.sum(p, axis=1), o)))
 
-        m0 = jnp.full((s_, h_), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((s_, h_), jnp.float32)
-        o0 = jnp.zeros((s_, h_, d_), jnp.float32)
-        m, l, o = lax.fori_loop(0, self.max_pages_per_slot, body,
-                                (m0, l0, o0))
-        return o / jnp.maximum(l, 1e-30)[..., None]
+        bufs = (jnp.full((N, H), -jnp.inf, jnp.float32),
+                jnp.zeros((N, H), jnp.float32),
+                jnp.zeros((N, hd), jnp.float32))
+        ms, ls, os_ = lax.fori_loop(0, (live["n_live"] + C - 1) // C,
+                                    body, bufs)
+        # slot s's entries are offset[s] + i, i = 0..P-1; those past its
+        # last live page weigh exp(-inf) = 0
+        own, dead = live["own"], live["dead"]
+        m_i = jnp.where(dead[:, :, None], -jnp.inf, ms[own])   # [S, P, H]
+        w = jnp.exp(m_i - jnp.max(m_i, axis=1, keepdims=True))
+        l = jnp.sum(ls[own] * w, axis=1)                       # [S, H]
+        o = jnp.sum(os_[own] * _dot_01(w, lanes), axis=1)      # [S, H*D]
+        return o / _dot_01(l, lanes)
 
     def _fn(self, params, state, tokens, pos, table):
         import jax.numpy as jnp
@@ -524,23 +611,21 @@ class TransformerDecodeModel:
         import jax
         import jax.numpy as jnp
 
-        S = self.max_slots
-        nh, hd = self.n_heads, self.head_dim
         ln = lambda x, p: _layer_norm(x, p["g"], p["b"], self.eps)  # noqa: E731
         h = params["tok_emb"][tokens] + params["pos_emb"][pos]
         h = ln(h, params["emb_ln"])
         off = pos % self.page
+        live = live_pages(pos, table, self.page)    # once a step
         kpool, vpool = state["k"], state["v"]
         for li, lp in enumerate(params["layers"]):
             qkv = h @ lp["qkv_w"] + lp["qkv_b"]
             q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = q.reshape(S, nh, hd)
             # S rows a layer into the donated pools, in place, before
             # the layer's attention reads them
             kpool = kpool.at[li, pidx, off].set(k)
             vpool = vpool.at[li, pidx, off].set(v)
-            att = self._paged_attention(q, kpool, vpool, li, table, pos)
-            att = att.reshape(S, nh * hd) @ lp["out_w"] + lp["out_b"]
+            att = self._paged_attention(q, kpool, vpool, li, live)
+            att = att @ lp["out_w"] + lp["out_b"]
             h = ln(h + att, lp["ln1"])
             ffn = jax.nn.gelu(h @ lp["ffn_in_w"] + lp["ffn_in_b"])
             ffn = ffn @ lp["ffn_out_w"] + lp["ffn_out_b"]
@@ -1279,17 +1364,24 @@ class DecodeEngine:
         return (len(req.generated) >= req.max_new
                 or (req.eos_id is not None and tok == req.eos_id))
 
-    def _boundary_done(self, inst, executable, prompt=0, answer=0):
+    def _boundary_done(self, inst, executable, prompt=0, answer=0,
+                       positions=None):
         """What the engine counts once a boundary, at the end of its
         emit phase: the boundary, the positions it fed, and the gauges
         with the pool's fill summed beside its gauge (a mean without
-        polling)."""
+        polling). ``positions`` are those the token step's launch fed
+        its active slots: the pages their contexts reach are what its
+        attention visited (a block boundary passes none and is left
+        out of that sum)."""
         inst.boundary(executable, prompt, answer)
         inst.slots.set(len(self._active))
         if self._kv is not None:
             fill = self._kv.used_pages / max(1, self._kv.n_pages)
             inst.kv_occupancy.set(fill)
             inst.kv_fill_sum.inc(fill)
+            if positions is not None:
+                inst.live_pages_sum.inc(
+                    int((positions // self._kv.page + 1).sum()))
 
     def _prefill_boundary(self, inst) -> bool:
         """Boundary phase 1 (ISSUE 12 tentpole a): retire up to
@@ -1429,7 +1521,8 @@ class DecodeEngine:
                     self._finish(req)
             if inst is not None:
                 inst.tokens.inc(n_decoded)
-                self._boundary_done(inst, "step", n_prompt, n_answer)
+                self._boundary_done(inst, "step", n_prompt, n_answer,
+                                    pos[active])
 
     def _speculative_boundary(self, inst):
         """Boundary phase 2, speculative (ISSUE 12 tentpole c): the

@@ -27,14 +27,15 @@ BucketLadder / ModelRegistry / warmup / compile-ledger machinery:
   tests/test_sharded_serving.py);
 
 - :class:`ShardedTransformerDecodeModel` — the mesh-sharded
-  ``PagedKVCache``: the per-page flash-attention ``fori_loop`` of
-  :class:`~.decode.TransformerDecodeModel` is already ring_attention's
-  block accumulation, so pages-as-shards is the natural extension —
-  the device pools ``[L, n_pages+1, page, H*D]`` are sharded on the
-  PAGE axis over the ``model`` axis while the host-side refcounted
-  page table (and with it prefix caching and speculative decoding)
-  rides unchanged on top. The online-softmax accumulation order over
-  pages is sequential either way, so decode is bit-identical too.
+  ``PagedKVCache``: :class:`~.decode.TransformerDecodeModel`'s
+  attention gathers whole pages out of the pool and reduces each on
+  its own, so pages-as-shards is the natural extension — the device
+  pools ``[L, n_pages+1, page, H*D]`` are sharded on the PAGE axis
+  over the ``model`` axis while the host-side refcounted page table
+  (and with it prefix caching and speculative decoding) rides
+  unchanged on top. A page's partial and the order in which a slot
+  combines its pages are the same whichever device held the page, so
+  decode is bit-identical too.
 
 Capacity planning is upgraded from admitting to *placing* (ISSUE 19
 satellite): a sharded registration is judged per device — each
@@ -381,10 +382,10 @@ class ShardedTransformerDecodeModel(TransformerDecodeModel):
 
     The pools ``[L, n_pages+1, page, H*D]`` get
     ``PartitionSpec(None, "model")``: each device owns a contiguous
-    block of PAGES. The per-page flash-attention ``fori_loop`` already
-    accumulates page blocks with ring_attention's online softmax, so
-    the page axis is the natural shard axis: the accumulation order is
-    sequential over pages either way, which is what keeps sharded
+    block of PAGES. The attention loop gathers whole pages and reduces
+    each on its own, so the page axis is the natural shard axis: a
+    page's partial and the order in which a slot combines its pages do
+    not depend on where the page lay, which is what keeps sharded
     decode bit-identical to the single-device reference. The host-side
     :class:`~.decode.PagedKVCache` (refcounts, page tables, prefix
     caching, speculative adoption) never sees device layout — it

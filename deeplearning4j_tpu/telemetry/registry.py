@@ -552,6 +552,11 @@ DECODE_POSITIONS_HELP = ("Sequence positions the decode engine advanced, "
 DECODE_KV_FILL_HELP = ("Sum over boundaries of the paged KV pool's "
                        "reserved fraction; over "
                        "dl4j_decode_boundaries_total it is the mean fill")
+DECODE_LIVE_PAGES_HELP = ("Sum over token-step boundaries of the KV pages "
+                          "the active slots' contexts reach (position // "
+                          "page + 1 each): what the step's attention "
+                          "visits; over dl4j_decode_boundaries_total"
+                          "{executable=\"step\"} it is the mean a launch")
 DECODE_QUEUE_WAIT_HELP = ("Seconds from decode submit to the boundary "
                           "at which the request took a slot")
 
@@ -566,7 +571,7 @@ class ServingInstruments:
                  "_replica_load", "_shed", "tokens", "slots",
                  "prefix_hits", "prefix_misses", "ttft", "_accepted",
                  "kv_occupancy", "_phases", "_boundaries", "_positions",
-                 "kv_fill_sum", "decode_queue_wait")
+                 "kv_fill_sum", "live_pages_sum", "decode_queue_wait")
 
     def __init__(self, registry, model):
         self.model = model
@@ -631,6 +636,9 @@ class ServingInstruments:
             ("model", "executable", "kind"))
         self.kv_fill_sum = registry.counter(
             "dl4j_decode_kv_fill_sum", DECODE_KV_FILL_HELP,
+            ("model",)).labels(model=model)
+        self.live_pages_sum = registry.counter(
+            "dl4j_decode_live_pages_sum", DECODE_LIVE_PAGES_HELP,
             ("model",)).labels(model=model)
         self.decode_queue_wait = registry.histogram(
             "dl4j_decode_queue_wait_seconds", DECODE_QUEUE_WAIT_HELP,

@@ -784,3 +784,210 @@ class TestDonatedPool:
             assert all(r["signature"]["donation"] == [1] for r in recs)
         finally:
             eng.close()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 29: the token step's attention visits the live pages only
+# ---------------------------------------------------------------------------
+
+# 8 slots of 32 pages of 4 rows: a list of 256 entries, four chunks of 64
+LIVE = dict(vocab=40, hidden=32, n_layers=1, n_heads=4, max_len=128,
+            max_slots=8, page=4, max_pages_per_slot=32)
+
+
+def _live_model(seed=1, **kw):
+    return TransformerDecodeModel.init(seed=seed, **dict(LIVE, **kw))
+
+
+def _own_pages(model):
+    """Slot s holds pool pages 1 + s*P .. (s+1)*P, in order."""
+    S, P = model.max_slots, model.max_pages_per_slot
+    return (1 + np.arange(S * P, dtype=np.int32)).reshape(S, P)
+
+
+def _random_pool(model, seed):
+    rng = np.random.default_rng(seed)
+    return {n: rng.normal(size=model._pool_shape()).astype(np.float32)
+            for n in ("k", "v")}
+
+
+def _softmax_float64(model, q, kpool, vpool, table, pos, s):
+    """Plain softmax attention of slot ``s`` over its own context
+    (positions 0..pos[s], through its page table), in float64."""
+    H, D = model.n_heads, model.head_dim
+    n = int(pos[s]) + 1
+    pages = table[s, :(n - 1) // model.page + 1]
+    k = np.asarray(kpool)[0][pages].reshape(-1, H, D)[:n].astype(np.float64)
+    v = np.asarray(vpool)[0][pages].reshape(-1, H, D)[:n].astype(np.float64)
+    sc = np.einsum("hd,nhd->hn", np.asarray(q)[s].reshape(H, D)
+                   .astype(np.float64), k) / np.sqrt(D)
+    p = np.exp(sc - sc.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("hn,nhd->hd", p, v).reshape(-1)
+
+
+# positions, the slots masked_fn is told are active, another geometry
+RAGGED = {
+    # a slot at position 0, one at the last row of page 31
+    "ragged": ([0, 127, 5, 64, 3, 17, 99, 31], None, {}),
+    "all_full": ([127] * 8, None, {}),
+    "one_alone": ([0, 0, 0, 77, 0, 0, 0, 0], None, {}),
+    "masked_inactive": ([9, 127, 0, 64, 3, 50, 99, 31],
+                        [True, False, True, True, False, False, True,
+                         True], {}),
+    # a table of 90 entries is not whole chunks: the list is 128 long
+    "table_not_whole_chunks": ([119, 100, 118], None,
+                               dict(max_slots=3, max_pages_per_slot=30)),
+}
+
+
+class TestLivePageAttention:
+    @pytest.mark.parametrize("case", sorted(RAGGED))
+    def test_step_attention_equals_plain_softmax(self, case, monkeypatch):
+        """(a) What ``_fn`` / ``masked_fn`` hand the layer as its
+        attention output is, for every slot that was fed, a plain
+        softmax over the slot's own context within 1e-5."""
+        import jax.numpy as jnp
+
+        pos, active, geometry = RAGGED[case]
+        model = _live_model(**geometry)
+        pos = np.asarray(pos, np.int32)
+        seen = []
+        inner = model._paged_attention
+
+        def recording(q, kpool, vpool, li, live):
+            out = inner(q, kpool, vpool, li, live)
+            seen.append((q, kpool, vpool, out))
+            return out
+        monkeypatch.setattr(model, "_paged_attention", recording)
+        table = _own_pages(model)
+        state = {n: jnp.asarray(a)
+                 for n, a in _random_pool(model, 0).items()}
+        toks = np.arange(3, 3 + model.max_slots, dtype=np.int32)
+        if active is None:
+            model._fn(model.params, state, toks, pos, table)
+            fed = range(model.max_slots)
+        else:
+            model.masked_fn(model.params, state, toks, pos, table,
+                            np.asarray(active))
+            fed = np.flatnonzero(active)
+        (q, kpool, vpool, out), = seen
+        for s in fed:
+            ref = _softmax_float64(model, q, kpool, vpool, table, pos, s)
+            np.testing.assert_allclose(np.asarray(out)[s], ref,
+                                       atol=1e-5, rtol=0)
+
+    def test_slot_is_bit_identical_whatever_its_neighbours_hold(self):
+        """(b) Slot 2's attention output and token do not move by a bit
+        with its neighbours' lengths and contents: its ten entries
+        start at 2 of the list, at 57 (they straddle the first chunk's
+        edge), at 64 (on the edge), and there again before other
+        followers."""
+        import jax
+        import jax.numpy as jnp
+
+        from deeplearning4j_tpu.serving.decode import live_pages
+
+        model = _live_model(seed=2)
+        attend = jax.jit(lambda q, k, v, pos, table: model._paged_attention(
+            q, k, v, 0, live_pages(pos, table, model.page)))
+        table = _own_pages(model)
+        mine = _random_pool(model, 7)
+        rng = np.random.default_rng(5)
+        q = rng.normal(size=(model.max_slots, model.hidden)) \
+            .astype(np.float32)
+        outs, toks = [], []
+        for i, (a, b) in enumerate([(0, 0), (127, 99), (127, 127),
+                                    (127, 127)]):
+            pos = np.asarray([a, b, 37, 0, 127, 6, 0, 15], np.int32)
+            if i == 3:
+                pos[3:] = [127, 0, 88, 127, 1]
+            pool = _random_pool(model, 100 + i)
+            for n in pool:      # the neighbours' pages differ, mine do not
+                pool[n][:, table[2]] = mine[n][:, table[2]]
+            qi = rng.normal(size=q.shape).astype(np.float32)
+            qi[2] = q[2]
+            outs.append(np.asarray(attend(qi, pool["k"], pool["v"], pos,
+                                          table))[2])
+            t = rng.integers(0, 40, size=model.max_slots).astype(np.int32)
+            t[2] = 11
+            nxt, _ = model.step({n: jnp.asarray(a) for n, a in pool.items()},
+                                t, pos, table)
+            toks.append(int(np.asarray(nxt)[2]))
+        for o in outs[1:]:
+            assert np.array_equal(o, outs[0])
+        assert len(set(toks)) == 1
+
+    def test_live_list_against_a_hand_count(self):
+        """(c) The list builder as a pure function."""
+        from deeplearning4j_tpu.serving.decode import live_pages
+
+        table = np.asarray([[7, 0, 0, 0], [3, 9, 4, 0], [5, 6, 0, 0]],
+                           np.int32)
+        live = {k: np.asarray(v) for k, v in live_pages(
+            np.asarray([0, 9, 4], np.int32), table, 4).items()}
+        assert int(live["n_live"]) == 6          # 1 + 3 + 2 pages
+        assert list(live["slot"][:6]) == [0, 1, 1, 1, 2, 2]
+        assert list(live["page"]) == [7, 3, 9, 4, 5, 6] + [0] * 6
+        # position 9 is row 1 of its slot's third page
+        assert list(live["last"]) == [0, 9, 5, 1, 4, 0] + [-1] * 6
+        assert live["own"].tolist() == [[0, 0, 0, 0], [1, 2, 3, 0],
+                                        [4, 5, 0, 0]]
+        assert live["dead"].tolist() == [[False, True, True, True],
+                                         [False, False, False, True],
+                                         [False, False, True, True]]
+
+    @pytest.mark.parametrize("pos, n_live", [
+        ([0] * 8, 8), ([0, 127, 5, 64, 3, 17, 99, 31], 91),
+        ([127] * 8, 256)])
+    def test_trip_count_follows_the_live_pages(self, pos, n_live,
+                                               monkeypatch):
+        """(c) The loop runs ``ceil(n_live / LIVE_CHUNK)`` times, not
+        ``max_pages_per_slot``: its body's calls counted with jit off
+        (three slices of the list an iteration)."""
+        import jax
+
+        from deeplearning4j_tpu.serving import decode
+
+        model = _live_model()
+        calls = []
+        inner = jax.lax.dynamic_slice_in_dim
+
+        def counted(*a, **kw):
+            calls.append(1)
+            return inner(*a, **kw)
+        monkeypatch.setattr(jax.lax, "dynamic_slice_in_dim", counted)
+        pool = _random_pool(model, 3)
+        q = np.ones((model.max_slots, model.hidden), np.float32)
+        pos = np.asarray(pos, np.int32)
+        with jax.disable_jit():
+            live = decode.live_pages(pos, _own_pages(model), model.page)
+            assert int(live["n_live"]) == n_live
+            model._paged_attention(q, pool["k"], pool["v"], 0, live)
+        assert len(calls) == 3 * -(-n_live // decode.LIVE_CHUNK)
+        assert len(calls) // 3 <= 4 < model.max_pages_per_slot
+
+    def test_live_pages_counter_over_a_scripted_run(self):
+        """(d) ``dl4j_decode_live_pages_sum``: every position a request
+        is fed (all but its last answer token) adds the pages its
+        context reaches, once."""
+        from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
+
+        reg = MetricsRegistry()
+        prev = telemetry.set_registry(reg)
+        telemetry.enable()
+        eng = DecodeEngine(
+            _live_model(), name="livepages",
+            instruments=telemetry.serving_instruments("livepages")).warmup()
+        given = [(9, 5), (1, 3), (30, 7)]        # prompt length, max_new
+        try:
+            reqs = [eng.submit(list(range(1, n + 1)), m) for n, m in given]
+            for r in reqs:
+                r.result(timeout=120.0)
+        finally:
+            eng.close()
+            telemetry.set_registry(prev)
+        snap = reg.snapshot()
+        want = sum(p // LIVE["page"] + 1
+                   for n, m in given for p in range(n + m - 1))
+        assert snap['dl4j_decode_live_pages_sum{model="livepages"}'] == want
