@@ -5,8 +5,7 @@ One process drives the main paths once, through the entry points a user
 calls, at the full width of the models the repo supports (weights random,
 from a seed):
 
-  bert_train    BertTrainer.train_step x5 + one train_steps(K=4) launch,
-                BERT-base at 16x512
+  bert_train    BertTrainer.train_step x5, BERT-base at 16x512
   kernels       MultiLayerNetwork.fit() of the char-LSTM at batch 1024 with
                 the Pallas recurrence kernels proven present in the compiled
                 step's HLO; gru_seq forward+backward compiled
@@ -115,7 +114,6 @@ def _fit_twice(fit, net, what):
 
 def _bert(sm, n_data, batch):
     import jax
-    import numpy as np
 
     from deeplearning4j_tpu.models import (BertConfig, BertTrainer,
                                            synthetic_mlm_batch)
@@ -154,24 +152,6 @@ def _bert(sm, n_data, batch):
            f"step(block_until_ready)={min(dt_block):.4f}s "
            f"step(float read-back)={min(dt_float):.4f}s "
            f"loss {losses[0]:.4f} -> {losses[-1]:.4f}")
-
-    k = 4
-    stacks = [synthetic_mlm_batch(cfg, batch, seq, seed=s)
-              for s in range(k)]
-    tok_k = np.stack([s[0] for s in stacks])
-    lab_k = np.stack([s[1] for s in stacks])
-    t = time.perf_counter()
-    first = np.asarray(trainer.train_steps(tok_k, lab_k))
-    setup_k = time.perf_counter() - t
-    t = time.perf_counter()
-    again = np.asarray(trainer.train_steps(tok_k, lab_k))
-    dt_k = time.perf_counter() - t
-    if first.shape != (k,) or not (np.isfinite(first).all()
-                                   and np.isfinite(again).all()):
-        raise AssertionError(f"train_steps(K={k}) losses: {first} {again}")
-    sm.say(f"  train_steps K={k}: setup+first={setup_k:.2f}s "
-           f"launch={dt_k:.4f}s ({dt_k / k:.4f}s/step) "
-           f"losses {[round(float(v), 4) for v in again]}")
     return trainer
 
 

@@ -8,7 +8,7 @@ orders it by priority.
 
 This is an API-parity seam only: no training, serving or benchmark path
 consults it. Those run on whatever jax resolved at start-up, and the
-entry points that need a chip (chip_smoke.py, bench.py) check
+entry points that need a chip (chip_smoke.py, benchmark/run.py) check
 ``jax.devices()[0].platform`` themselves and refuse anything else —
 ``load()`` offering the CPU at priority 0 must never become "carry on
 without the chip".

@@ -16,8 +16,8 @@ growing a new one:
   = resume from checkpoint, not a lost job), and every training step
   holds a ``train``-class admission ticket — the PR-8 controller
   arbitrates trainer-vs-serving on the shared host, shedding the
-  trainer FIRST so serving p99 degradation is bounded (and measured:
-  ``bench.py --only fleet_loop``). On completion the newest checkpoint
+  trainer FIRST so serving p99 degradation is bounded (on the chip:
+  not measured, no cell yet). On completion the newest checkpoint
   auto-publishes through ``router.start_rollout`` with the
   ``from_checkpoint`` spec kind, so the PR-15 canary machinery judges
   the fine-tuned model against its own parent before clients see it.

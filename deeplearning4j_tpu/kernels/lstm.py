@@ -2,14 +2,15 @@
 
 Why a custom kernel (SURVEY.md §7: "Pallas-style custom kernels enter as
 XLA custom-calls if/when generic HLO can't hit MFU targets"): the
-XLA-lowered lax.scan recurrence measures ~80-155 us PER SEQUENTIAL STEP
-on v5e (tools/probe_lstm.py) while the step's actual work — one
-[N,H]x[H,4H] MXU matmul plus elementwise gates — rooflines at single-
-digit microseconds. The scan pays per-iteration HBM round-trips for the
-carried h/c; this kernel keeps h, c and R resident in VMEM across ALL
-timesteps (the cuDNN-LSTM design; reference analog: libnd4j's cudnn
-platform helper for lstmLayer, SURVEY.md §2.1 platform-helper tier) and
-runs the whole recurrence in ONE kernel launch. An A/B on the char-RNN
+XLA-lowered lax.scan recurrence measured ~80-155 us PER SEQUENTIAL STEP
+on v5e (tools/RESNET_MFU.md section 4: July 2026, not re-measured)
+while the step's actual work — one [N,H]x[H,4H] MXU matmul plus
+elementwise gates — rooflines at single-digit microseconds. The scan
+pays per-iteration HBM round-trips for the carried h/c; this kernel
+keeps h, c and R resident in VMEM across ALL timesteps (the cuDNN-LSTM
+design; reference analog: libnd4j's cudnn platform helper for lstmLayer,
+SURVEY.md §2.1 platform-helper tier) and runs the whole recurrence in ONE
+kernel launch. An A/B on the char-RNN
 bench config (b1024, T=100, H=256) in July 2026, on another libtpu
 build, read 13.3 ms/step against the scan lowering's 24.4; on this
 installation: not measured (PERF.md).

@@ -392,106 +392,6 @@ class BertTrainer:
     def _build(self):
         return self._engine.build()
 
-    def _build_multi(self, repeats=1):
-        """K training steps in ONE device launch: lax.scan over a stacked
-        [K, ...] batch dimension. Amortizes per-dispatch host latency
-        the way an on-device input pipeline would.
-        repeats > 1 makes R passes over the same K batches (slope-based
-        benchmarking / tiny-corpus epochs); last pass's losses return."""
-        repl = NamedSharding(self.mesh, P())
-
-        def stack_sh(sh):
-            return NamedSharding(self.mesh, P(None, *sh.spec))
-
-        def many(params, opt, tokens_k, pos_k, lab_k, w_k, rng0, t0):
-            def body(carry, xs):
-                params, opt, t = carry
-                tokens, pos, lab, w = xs
-                rng = jax.random.fold_in(rng0, t)
-                loss, params, opt = self._step_math(
-                    params, opt, tokens, pos, lab, w, rng, t)
-                return (params, opt, t + 1), loss
-
-            def scan_once(carry, _):
-                return jax.lax.scan(body, carry,
-                                    (tokens_k, pos_k, lab_k, w_k))
-
-            carry = (params, opt, t0)
-            if repeats == 1:
-                carry, losses = scan_once(carry, None)
-            else:
-                carry, losses_r = jax.lax.scan(scan_once, carry, None,
-                                               length=repeats)
-                losses = losses_r[-1]
-            params, opt, _ = carry
-            return losses, params, opt
-
-        return jax.jit(
-            many,
-            in_shardings=(self.p_sh, self.o_sh, stack_sh(self.batch_sh),
-                          stack_sh(self.pos_sh), stack_sh(self.pos_sh),
-                          stack_sh(self.pos_sh), repl, repl),
-            out_shardings=(repl, self.p_sh, self.o_sh),
-            donate_argnums=(0, 1),
-        )
-
-    def train_steps(self, tokens_k, labels_k, repeats: int = 1):
-        """Run K = tokens_k.shape[0] optimizer steps in one launch
-        (R*K with repeats=R). tokens_k/labels_k: [K, B, T]. Returns the
-        [K] losses of the last pass."""
-        if not isinstance(getattr(self, "_multi_fn", None), dict):
-            self._multi_fn = {}
-        if repeats not in self._multi_fn:
-            self._multi_fn[repeats] = self._build_multi(repeats)
-        k, b, t = np.asarray(tokens_k).shape
-        pos_k, lab_k, w_k = [], [], []
-        for i in range(k):
-            p_, l_, w_ = mlm_gather(labels_k[i],
-                                    max_preds=self._max_preds(t))
-            pos_k.append(p_)
-            lab_k.append(l_)
-            w_k.append(w_)
-        rng0 = jax.random.key(self._step + 1, impl="rbg")
-        import time
-
-        from deeplearning4j_tpu import telemetry
-
-        t_launch = (time.perf_counter() if telemetry.enabled()
-                    else None)
-        it0 = self._step
-        losses, self.params, self.opt = self._multi_fn[repeats](
-            self.params, self.opt, jnp.asarray(tokens_k, jnp.int32),
-            np.stack(pos_k), np.stack(lab_k), np.stack(w_k), rng0,
-            jnp.asarray(self._step, jnp.int32))
-        self._step += k * repeats
-        if t_launch is not None:
-            # ISSUE 10 cost attribution: per-step FLOPs from the HLO
-            # cost model of the scanned module (lower-only, no second
-            # compile), published as dl4j_flops_per_step{executable=
-            # "bert"}; the live dl4j_mfu gauge uses the launch's
-            # dispatch wall from the SECOND launch on, when dispatch-
-            # queue backpressure makes it equal device time (the PR-1
-            # step-time argument — the first launch returns as soon as
-            # the work is enqueued and would overstate MFU wildly)
-            from deeplearning4j_tpu.telemetry import costmodel
-
-            n_steps = k * repeats
-            per_step = (time.perf_counter() - t_launch) / max(1, n_steps)
-            self._launches = getattr(self, "_launches", 0) + 1
-            # warm from the second launch on: dispatch-queue
-            # backpressure from launch N-1 makes the wall honest (the
-            # throttle inside attribute_launch additionally keeps an
-            # unmaterialized microsecond dispatch wall from printing an
-            # absurd over-peak MFU)
-            costmodel.attribute_launch(
-                "bert", self._multi_fn[repeats],
-                (self.params, self.opt,
-                 jnp.asarray(tokens_k, jnp.int32), np.stack(pos_k),
-                 np.stack(lab_k), np.stack(w_k), rng0,
-                 jnp.asarray(it0, jnp.int32)),
-                self, per_step, self._launches >= 2)
-        return losses
-
     def train_step(self, tokens, labels):
         """tokens [B,T] int32; labels [B,T] with -100 at unmasked
         positions. The masked-position gather happens host-side so the

@@ -222,7 +222,7 @@ class AlexNet(ZooModel):
 
 
 class VGG16(ZooModel):
-    """Reference: zoo.model.VGG16. BLOCKS = (channels, conv-repeats) per
+    """Reference: zoo.model.VGG16. BLOCKS = (channels, convs in a row) per
     pooled stage; VGG19 overrides it."""
 
     BLOCKS = ((64, 2), (128, 2), (256, 3), (512, 3), (512, 3))

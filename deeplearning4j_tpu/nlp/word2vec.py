@@ -114,10 +114,11 @@ def _compaction_dests(val_s, cap):
 class Word2Vec:
     class Builder:
         def __init__(self):
-            # batchSize 8192: r4's probe_sgns measured step throughput
-            # rising 1.5 -> 4.3 Mpairs/s from 2048 -> 8192 (per-step
-            # fixed costs amortize); SGNS quality is batch-tolerant
-            # (hogwild heritage) and the pair order is shuffled
+            # batchSize 8192: step throughput rose 1.5 -> 4.3 Mpairs/s
+            # from 2048 -> 8192 (per-step fixed costs amortize;
+            # tools/RESNET_MFU.md section 4: July 2026, not re-measured);
+            # SGNS quality is batch-tolerant (hogwild heritage) and the
+            # pair order is shuffled
             self._kw = dict(minWordFrequency=5, layerSize=100, windowSize=5,
                             negative=5, learningRate=0.025, epochs=1,
                             iterations=1, seed=42, batchSize=8192,
@@ -193,8 +194,8 @@ class Word2Vec:
             (the r4 semantics). Default OFF: negatives come from a
             per-launch pool of iid unigram^0.75 draws, each step
             slicing a pseudo-random window — cheaper per step
-            (tools/probe_w2v_step.py; July 2026, not re-measured on
-            this installation), same marginal distribution, but pool
+            (tools/RESNET_MFU.md section 4; July 2026, not re-measured
+            on this installation), same marginal distribution, but pool
             windows can overlap across steps."""
             self._kw["exactNegatives"] = bool(b)
             return self
@@ -380,7 +381,7 @@ class Word2Vec:
             gathers measured ~0.19 GB/s on this chip where slices run
             at full bandwidth — the r4 gather formulation spent ~3.4 s
             of the 4.4 s pair-gen in 10 shifted gathers
-            (tools/probe_w2v_pairgen.py, r5)."""
+            (tools/RESNET_MFU.md section 4; July 2026, r5)."""
             p = a.shape[0]
             if d > 0:
                 return jnp.concatenate(
@@ -417,7 +418,7 @@ class Word2Vec:
             # measured SLOWER than these two element scatters — r4; a
             # [cap, 2] row-scatter variant measured 4x slower still,
             # and scatter-free searchsorted compaction 10x slower —
-            # tools/probe_w2v_pairgen.py, r5)
+            # tools/RESNET_MFU.md section 4; July 2026, r5)
             out_c = jnp.zeros((cap,), jnp.int32).at[dest].set(
                 cent_s, mode="drop", unique_indices=True)
             out_x = jnp.zeros((cap,), jnp.int32).at[dest].set(
@@ -512,7 +513,7 @@ class Word2Vec:
         randint+table-gather of n_pool draws, with each step taking a
         pseudo-random contiguous slice. The r4 per-step fold_in +
         randint + gather cost 0.65 ms of the 1.9 ms step
-        (tools/probe_w2v_step.py G variant) — a dynamic slice is free,
+        (tools/RESNET_MFU.md section 4, July 2026) — a dynamic slice is free,
         and each slice is still iid unigram^0.75 draws independent of
         the step's pairs (windows may overlap across steps; set
         exactNegatives(True) for per-step draws). Step losses are not
@@ -590,12 +591,12 @@ class Word2Vec:
                 # Analytic SGNS gradients + SORTED row scatters instead
                 # of jax.grad: the grad-of-gather path materializes a
                 # DENSE [V,D] gradient table per step (plus a dense
-                # axpy), which r4's probe_sgns measured as the real
-                # bound — the sorted in-place row update is ~3x faster
-                # at the same math (sort cost ~2% of step;
-                # indices_are_sorted lets XLA's scatter skip the
-                # unsorted-duplicate slow path, probe_scatter r4:
-                # 125M vs 78M rows/s).
+                # axpy), measured as the real bound — the sorted
+                # in-place row update is ~3x faster at the same math
+                # (sort cost ~2% of step; indices_are_sorted lets XLA's
+                # scatter skip the unsorted-duplicate slow path: 125M vs
+                # 78M rows/s; tools/RESNET_MFU.md section 4, July 2026,
+                # not re-measured).
                 c = syn0[cent]
                 pos = syn1[ctx]
                 neg = syn1[negs]
@@ -732,8 +733,8 @@ class Word2Vec:
                 for it in range(cfg["iterations"]):
                     # threefry, not rbg: the per-step fold_in+randint
                     # inside the scan measured 0.26 ms/step cheaper
-                    # (1.55 vs 1.81 ms, tools/probe_w2v_step.py F
-                    # variants, r5) — rbg's fold_in is the slow part
+                    # (1.55 vs 1.81 ms; tools/RESNET_MFU.md section 4,
+                    # July 2026, r5) — rbg's fold_in is the slow part
                     key = jax.random.key(int(rng.integers(0, 2**31)))
                     _losses, syn0, syn1 = self._multi_fn(
                         syn0, syn1, cent_k, ctx_k, w_k,

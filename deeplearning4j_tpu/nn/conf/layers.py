@@ -639,7 +639,8 @@ class BatchNormalization(BaseLayer):
             # Stats strategy by activation dtype:
             # - bf16/f16: ONE-PASS E[x^2]-mean^2 with f32 accumulators —
             #   reads x once instead of twice (+9% ResNet-50 bf16 train
-            #   throughput on v5e, tools/probe_resnet.py --bn onepass);
+            #   throughput on v5e in July 2026, not re-measured:
+            #   tools/RESNET_MFU.md section 4);
             #   any mean>>std cancellation is below the activations' own
             #   quantization noise at these dtypes.
             # - f32: TWO-PASS centered stats — one-pass cancels

@@ -274,7 +274,7 @@ class ComputationGraph:
             self._train_step_plan = plan
         return plan
 
-    def _build_multi_step(self, repeats=1, health_plan=None):
+    def _build_multi_step(self, health_plan=None):
         from deeplearning4j_tpu.telemetry import health as _health
 
         plan = health_plan or _health.INACTIVE
@@ -291,38 +291,27 @@ class ComputationGraph:
                 ys = (loss, health) if plan.collect else loss
                 return (params, states, opts, prec, it + 1), ys
 
-            def scan_once(carry, _):
-                return jax.lax.scan(body, carry,
-                                    (inputs_k, labels_k, masks_k))
-
-            carry = (params, states, opts, prec, it0)
-            if repeats == 1:
-                carry, ys = scan_once(carry, None)
-            else:
-                carry, ys_r = jax.lax.scan(scan_once, carry, None,
-                                           length=repeats)
-                ys = jax.tree_util.tree_map(lambda a: a[-1], ys_r)
+            carry, ys = jax.lax.scan(
+                body, (params, states, opts, prec, it0),
+                (inputs_k, labels_k, masks_k))
             losses, healths = ys if plan.collect else (ys, None)
             params, states, opts, prec, _ = carry
             return losses, params, states, opts, healths, prec
 
         return jax.jit(many, donate_argnums=(0, 1, 2))
 
-    def fitMultiBatch(self, features_k, labels_k, repeats: int = 1):
+    def fitMultiBatch(self, features_k, labels_k):
         """K optimizer steps in ONE device launch over stacked [K, B, ...]
-        minibatches via lax.scan (see MultiLayerNetwork.fitMultiBatch:
-        amortizes per-dispatch RPC latency; repeats=R makes R passes in
-        the launch). Single-input single-output graphs only. Returns the
-        [K] losses (last pass)."""
+        minibatches via lax.scan (see MultiLayerNetwork.fitMultiBatch).
+        Single-input single-output graphs only. Returns the [K] losses."""
         self._check_init()
         from deeplearning4j_tpu.telemetry import health as _health
 
         plan = _health.build_plan(self._listeners)
         if not isinstance(getattr(self, "_multi_step", None), dict):
             self._multi_step = {}
-        key = (repeats, plan)
-        if key not in self._multi_step:
-            self._multi_step[key] = self._build_multi_step(repeats, plan)
+        if plan not in self._multi_step:
+            self._multi_step[plan] = self._build_multi_step(plan)
         # keep device-resident stacks on device (a _host_array bounce
         # would round-trip the whole [K,B,...] block D2H then H2D)
         f_k = _unwrap(features_k) if isinstance(
@@ -341,11 +330,11 @@ class ComputationGraph:
         if pm is not None:
             pm.baseline_from(self._prec_state)
         (losses, self._params, self._states, self._opt_states, healths,
-         self._prec_state) = self._multi_step[key](
+         self._prec_state) = self._multi_step[plan](
                 self._params, self._states, self._opt_states,
                 self._prec_state, inputs_k, labels_k, masks_k, rng0,
                 jnp.asarray(self._iteration, jnp.int32))
-        self._iteration += int(f_k.shape[0]) * repeats
+        self._iteration += int(f_k.shape[0])
         self._score = float(losses[-1])
         if pm is not None:
             pm.on_launch(range(it0, self._iteration), self._prec_state)
@@ -354,9 +343,8 @@ class ComputationGraph:
                                      self._listeners)
             if hm is not None:
                 hm.precision = pm
-                base = it0 + (repeats - 1) * int(f_k.shape[0])
                 for k in range(int(f_k.shape[0])):
-                    hm.on_step(base + k, healths[k])
+                    hm.on_step(it0 + k, healths[k])
                 hm.flush()
         return losses
 

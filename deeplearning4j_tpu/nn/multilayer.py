@@ -325,7 +325,7 @@ class MultiLayerNetwork:
             self._train_step_plan = plan
         return plan
 
-    def _build_multi_step(self, repeats=1, health_plan=None):
+    def _build_multi_step(self, health_plan=None):
         from deeplearning4j_tpu.telemetry import health as _health
 
         plan = health_plan or _health.INACTIVE
@@ -342,51 +342,40 @@ class MultiLayerNetwork:
                 ys = (loss, health) if plan.collect else loss
                 return (params, states, opts, prec, it + 1), ys
 
-            def scan_once(carry, _):
-                return jax.lax.scan(body, carry, (f_k, l_k, m_k))
-
-            carry = (params, states, opts, prec, it0)
-            if repeats == 1:
-                carry, ys = scan_once(carry, None)
-            else:
-                # R passes over the same K batches in one launch (used by
-                # slope-based benchmarking; also a legit small-dataset
-                # multi-epoch fit) — only the last pass's losses return
-                carry, ys_r = jax.lax.scan(scan_once, carry,
-                                           None, length=repeats)
-                ys = jax.tree_util.tree_map(lambda a: a[-1], ys_r)
+            carry, ys = jax.lax.scan(
+                body, (params, states, opts, prec, it0), (f_k, l_k, m_k))
             losses, healths = ys if plan.collect else (ys, None)
             params, states, opts, prec, _ = carry
             return losses, params, states, opts, healths, prec
 
         return jax.jit(many, donate_argnums=(0, 1, 2))
 
-    def fitMultiBatch(self, features_k, labels_k, repeats: int = 1):
+    def fitMultiBatch(self, features_k, labels_k):
         """K optimizer steps in ONE device launch: features_k/labels_k are
         stacked [K, batch, ...] minibatches consumed by a lax.scan. This
         amortizes per-dispatch host latency the way an on-device input
         pipeline would; semantics match K
-        successive fit() calls on the K slices. Returns the [K] losses
-        (of the last pass when repeats > 1)."""
+        successive fit() calls on the K slices. Returns the [K] losses."""
         self._check_init()
         from deeplearning4j_tpu.telemetry import health as _health
 
         plan = _health.build_plan(self._listeners)
         if not isinstance(self._multi_step, dict):
             self._multi_step = {}
-        key = (repeats, plan)
-        if key not in self._multi_step:
-            many = self._build_multi_step(repeats, plan)
+        # a plan's FIRST launch compiles inside the timed region, so its
+        # per-step wall is useless for MFU (10-100x understated)
+        warm = plan in self._multi_step
+        if not warm:
+            many = self._build_multi_step(plan)
             from deeplearning4j_tpu import compilestore
 
             if compilestore.enabled():
                 many = compilestore.StoredJit(
                     many, "fit:multi",
-                    program=self._step_program(plan, kind="multi")
-                    + f":repeats={repeats}",
+                    program=self._step_program(plan, kind="multi"),
                     policy=self._policy_label(plan),
                     donation=(0, 1, 2))
-            self._multi_step[key] = many
+            self._multi_step[plan] = many
         # keep device-resident stacks on device (a _host_array bounce
         # would round-trip the whole [K,B,...] block D2H then H2D)
         f_k = _unwrap(features_k) if isinstance(
@@ -410,7 +399,7 @@ class MultiLayerNetwork:
         t_launch = _time.perf_counter() if telemetry.enabled() else None
         try:
             (losses, self._params, self._states, self._opt_states,
-             healths, self._prec_state) = self._multi_step[key](
+             healths, self._prec_state) = self._multi_step[plan](
                     self._params, self._states, self._opt_states,
                     self._prec_state, f_k, l_k, m_k, rng0,
                     jnp.asarray(self._iteration, jnp.int32))
@@ -420,23 +409,15 @@ class MultiLayerNetwork:
             memledger.raise_if_oom(e, site="train.fitMultiBatch",
                                    step=self._iteration)
             raise
-        self._iteration += int(f_k.shape[0]) * repeats
+        self._iteration += int(f_k.shape[0])
         self._score = float(losses[-1])
         if t_launch is not None:
             # float(losses[-1]) materialized the launch, so this wall
             # time covers the device work
-            n_steps = int(f_k.shape[0]) * repeats
+            n_steps = int(f_k.shape[0])
             per_step = (_time.perf_counter() - t_launch) / max(1, n_steps)
-            timed = getattr(self, "_multi_timed", None)
-            if timed is None:
-                timed = self._multi_timed = set()
-            # the FIRST launch of a (repeats, plan) key compiled inside
-            # the timed region, so its per-step wall is useless for MFU
-            # (10-100x understated): only a key already seen is warm
-            warm = key in timed
-            timed.add(key)
             costmodel.attribute_launch(
-                "fit", self._multi_step[key],
+                "fit", self._multi_step[plan],
                 (self._params, self._states, self._opt_states,
                  self._prec_state, f_k, l_k, m_k, rng0,
                  jnp.asarray(it0, jnp.int32)),
@@ -453,9 +434,8 @@ class MultiLayerNetwork:
                 hm.precision = pm
                 # the [K, L, 5] stack is already materialized (we just
                 # read losses), so processing here adds no sync
-                base = it0 + (repeats - 1) * int(f_k.shape[0])
                 for k in range(int(f_k.shape[0])):
-                    hm.on_step(base + k, healths[k])
+                    hm.on_step(it0 + k, healths[k])
                 hm.flush()
         return losses
 

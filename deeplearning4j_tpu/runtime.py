@@ -87,8 +87,8 @@ class RuntimeConfig:
     def enable_compile_cache() -> str:
         """Place JAX's persistent compilation cache; returns its path.
 
-        Called by the entry points (chip_smoke.py, bench.py main,
-        fleet/worker.py main) before their first compile — never at
+        Called by the entry points (chip_smoke.py, fleet/worker.py
+        main) before their first compile — never at
         package import and never from tests/conftest.py: tier-1 tests
         count backend compiles, and a warm cache would change the counts.
 

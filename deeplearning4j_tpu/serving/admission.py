@@ -15,9 +15,8 @@ production tier wants. Admission control makes overload a POLICY:
   headroom is reserved: ``train`` traffic is shed first, ``batch``
   next, then ``normal``, and ``high`` keeps the full budget. Under 2x
   overload the best-effort tail absorbs the shedding and high-priority
-  p99 stays near its unloaded value (the bench.py `serving_load` row
-  measures exactly this; `fleet_loop` measures the train-vs-serve
-  arbitration);
+  p99 stays near its unloaded value (tests/test_serving_scale.py
+  holds the shedding order; on the chip: not measured, no cell yet);
 - shed responses carry a computed ``retry_after`` (seconds), derived
   from the recent per-request service rate and the current standing
   load — an honest backoff hint for HTTP 429 Retry-After instead of a

@@ -1,13 +1,13 @@
 """XLA cost-model performance attribution (ISSUE 10 tentpole, second
 half).
 
-MFU used to exist only as hand-written FLOP formulas in bench.py — the
-gap ROADMAP item 4 keeps tripping over: an operator watching /metrics
-could see a step get slower but had no authoritative FLOP count to say
-*how far from peak* the executable runs, and the analytic formulas can
-silently disagree with what XLA actually compiled (the PR-10 audit
-caught bench.py's ResNet formula counting multiply-accumulates as one
-FLOP — a 2x MFU understatement against a peak quoted in real FLOP/s).
+MFU used to exist only as hand-written FLOP formulas beside a timing
+loop: an operator watching /metrics could see a step get slower but had
+no authoritative FLOP count to say *how far from peak* the executable
+runs, and the analytic formulas can silently disagree with what XLA
+actually compiled (the PR-10 audit caught a ResNet formula counting
+multiply-accumulates as one FLOP — a 2x MFU understatement against a
+peak quoted in real FLOP/s).
 
 Sources of truth:
 
@@ -171,11 +171,10 @@ def step_cost(executable, jitted, args, cache=None):
     keyed here by the args' shape signature, so refits re-publish from
     the cache instead of re-lowering.
 
-    K-step scanned launches (fitMultiBatch / BertTrainer.train_steps)
-    need no normalization: the HLO cost model visits a While/scan body
-    exactly ONCE (the trip count is not in the module), so the count
-    it returns already IS per-step — measured within 3% of the
-    analytic per-step FLOPs for a scanned BERT launch."""
+    K-step scanned launches (fitMultiBatch) need no normalization: the
+    HLO cost model visits a While/scan body exactly ONCE (the trip count
+    is not in the module), so the count it returns already IS
+    per-step."""
     if not _registry.enabled():
         return None
     try:
@@ -222,8 +221,8 @@ def maybe_attribute(tele, executable, jitted, args, owner, steps_seen,
 
 
 def attribute_launch(executable, jitted, args, owner, per_step, warm):
-    """The scanned-launch attribution idiom, shared by
-    ``fitMultiBatch`` and ``BertTrainer.train_steps``: attribute when
+    """The scanned-launch attribution idiom of ``fitMultiBatch``:
+    attribute when
     the per-step wall clears the throttle, but publish MFU only for
     ``warm`` launches — the caller knows which walls are honest (a
     first launch compiles inside the timed region; an unmaterialized

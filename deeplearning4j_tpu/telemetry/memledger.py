@@ -74,10 +74,6 @@ CATEGORIES = ("train", "kv_cache", "executable", "prefetch",
 
 _state = {
     "ledger": None,
-    # sub-switch under the master telemetry flag (the compile_ledger
-    # pattern): lets the bench isolate ledger-on vs ledger-off with
-    # the rest of telemetry held constant
-    "enabled": True,
     # capacity budget for backends that do not report memory_stats
     # (CPU): headroom() treats it as bytes_limit, with live-array
     # accounting standing in for bytes_in_use
@@ -94,17 +90,14 @@ _lock = threading.Lock()
 
 
 def configure(budget_bytes=..., min_headroom_bytes=...,
-              min_headroom_fraction=None, top_n=None, enabled=None):
+              min_headroom_fraction=None, top_n=None):
     """Tune the ledger: ``budget_bytes`` is the assumed device capacity
     where the backend reports no ``memory_stats`` (None forgets an
     override and re-reads ``DL4J_DEVICE_BUDGET_BYTES``);
     ``min_headroom_bytes`` / ``min_headroom_fraction`` set the /healthz
     degradation floor; ``top_n`` bounds the claims an ``oom`` flight
-    event names; ``enabled`` is the ledger's sub-switch under the
-    master telemetry flag (bench isolation)."""
+    event names."""
     with _lock:
-        if enabled is not None:
-            _state["enabled"] = bool(enabled)
         if budget_bytes is not ...:
             _state["budget"] = (None if budget_bytes is None
                                 else int(budget_bytes))
@@ -423,9 +416,8 @@ def set_ledger(ledger):
 
 
 def enabled() -> bool:
-    """The ledger follows the one telemetry switch (PR-1 contract),
-    with its own sub-switch for bench isolation."""
-    return _registry.enabled() and _state["enabled"]
+    """The ledger follows the one telemetry switch (PR-1 contract)."""
+    return _registry.enabled()
 
 
 def claim(category, name, nbytes=None, tree=None, device=None,
@@ -869,7 +861,6 @@ def reset_state():
     """Forget claims and configuration (tests)."""
     with _lock:
         _state["ledger"] = None
-        _state["enabled"] = True
         _state["budget"] = None
         _state["budget_resolved"] = False
         _state["min_headroom_bytes"] = None

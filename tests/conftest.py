@@ -57,3 +57,26 @@ def lock_witness(request):
     assert not w.inversions, (
         "lock-order inversion witnessed at runtime:\n"
         + w.format_inversions())
+
+
+@pytest.fixture
+def assert_rows_close():
+    """Two programs of different shape or placement (another bucket, a
+    replica's clone, a mesh) that compute the same rows: compared at a
+    float32 tolerance, not bit for bit (ROADMAP D4).
+
+    XLA picks a matmul's tiling and a reduction's order per shape and per
+    layout, so the two sides may round differently in the last place or
+    two; observed here at most 2.4e-7 absolute and 1.9e-6 relative (1-8
+    float32 ulps). The bound is 1e-5 relative (some 5x the widest seen,
+    and 84 ulps: a wrong row, weight or bucket misses by orders more) with
+    an absolute floor of 1e-6 for entries near zero. Where both sides run
+    the SAME executable (kill-and-resume, capture replay, a slot reused)
+    tests keep `assert_array_equal`."""
+    import numpy as np
+
+    def check(actual, expected):
+        np.testing.assert_allclose(np.asarray(actual), np.asarray(expected),
+                                   rtol=1e-5, atol=1e-6)
+
+    return check

@@ -1,8 +1,8 @@
 """Compile-side observability tests (ISSUE 11): the executable ledger,
 recompile forensics (cause taxonomy + exact-changed-field diffs), the
 serving-warmup ledger invariant, the /debug/compiles + /debug/hlo
-routes, the /healthz compile section, the HLO audit parser,
-tools/benchdiff.py, and the disabled-mode zero-call contract."""
+routes, the /healthz compile section, the HLO audit parser, and the
+disabled-mode zero-call contract."""
 
 import json
 import urllib.error
@@ -555,100 +555,6 @@ class TestHloAuditParser:
         # the synthetic module's ROOT adds are in the histogram
         full = hlo_audit.audit_text(_SYNTH_HLO)
         assert full["opcode_histogram"]["add"] == 2
-
-
-# ---------------------------------------------------------------------------
-# tools/benchdiff.py (ISSUE 11 satellite: the bench CI gate)
-# ---------------------------------------------------------------------------
-
-class TestBenchDiff:
-    def _mod(self):
-        import importlib.util
-        import os
-
-        path = os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "tools", "benchdiff.py")
-        spec = importlib.util.spec_from_file_location("benchdiff", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
-    def test_throughput_regression_detected(self):
-        bd = self._mod()
-        base = {"lenet_cpu": {"value": 100.0, "unit": "images/sec",
-                              "metric": "lenet_mnist_images_per_sec",
-                              "platform": "cpu"}}
-        fresh = {"lenet": {"value": 80.0, "unit": "images/sec",
-                           "metric": "lenet_mnist_images_per_sec",
-                           "platform": "cpu"}}
-        rows = bd.compare(fresh, base)
-        assert len(rows) == 1
-        assert rows[0]["key"] == "lenet_cpu"
-        assert rows[0]["regression"] and rows[0]["change_pct"] == 20.0
-        # within threshold -> ok
-        fresh["lenet"]["value"] = 95.0
-        assert not bd.compare(fresh, base)[0]["regression"]
-        # an IMPROVEMENT is never a regression
-        fresh["lenet"]["value"] = 130.0
-        assert not bd.compare(fresh, base)[0]["regression"]
-
-    def test_lower_is_better_direction(self):
-        bd = self._mod()
-        base = {"trace_overhead_cpu": {
-            "value": 0.2, "unit": "%", "platform": "cpu",
-            "metric": "trace_overhead_sampled_off_pct"}}
-        fresh = {"trace_overhead_cpu": {
-            "value": 1.5, "unit": "%", "platform": "cpu",
-            "metric": "trace_overhead_sampled_off_pct"}}
-        rows = bd.compare(fresh, base)
-        assert rows[0]["regression"]          # overhead went UP >1 point
-        fresh["trace_overhead_cpu"]["value"] = 0.1
-        assert not bd.compare(fresh, base)[0]["regression"]
-
-    def test_percent_rows_gate_on_absolute_points(self):
-        bd = self._mod()
-        # near-zero overhead rows: relative change is pure noise; the
-        # gate is one direction-normalized percentage POINT (the <=1%
-        # acceptance band these rows carry), and a zero baseline is
-        # legal
-        base = {"ov_cpu": {"value": 0.0, "unit": "%",
-                           "platform": "cpu", "metric": "x_overhead"}}
-        fresh = {"ov_cpu": {"value": 0.8, "unit": "%",
-                            "platform": "cpu", "metric": "x_overhead"}}
-        assert not bd.compare(fresh, base)[0]["regression"]
-        fresh["ov_cpu"]["value"] = 1.5
-        assert bd.compare(fresh, base)[0]["regression"]
-
-    def test_platform_suffix_never_gates_chip_rows(self):
-        bd = self._mod()
-        base = {"resnet50": {"value": 600.0, "unit": "images/sec",
-                             "platform": "tpu",
-                             "metric": "resnet50_images_per_sec"}}
-        fresh = {"resnet50": {"value": 5.0, "unit": "images/sec",
-                              "platform": "cpu",
-                              "metric": "resnet50_images_per_sec"}}
-        # cpu row normalizes to resnet50_cpu: no match, nothing gated
-        assert bd.compare(fresh, base) == []
-
-    def test_error_and_nonnumeric_rows_skipped(self):
-        bd = self._mod()
-        base = {"x_cpu": {"value": 1.0, "unit": "s", "platform": "cpu"}}
-        fresh = {"x": {"error": "boom", "platform": "cpu"},
-                 "y": 3}
-        assert bd.compare(fresh, base) == []
-
-    def test_step_time_ratio_rows_are_lower_is_better(self):
-        """Regression: the precision row's unit is 'x (bf16_mixed/fp32
-        step time; <1 is a speedup)' — a DROP is an improvement."""
-        bd = self._mod()
-        row = {"metric": "precision_bf16_vs_fp32_step_ratio",
-               "unit": "x (bf16_mixed/fp32 step time; <1 is a speedup)",
-               "platform": "cpu"}
-        base = {"precision_cpu": {**row, "value": 1.5}}
-        fresh = {"precision_cpu": {**row, "value": 0.75}}
-        assert not bd.compare(fresh, base)[0]["regression"]   # speedup
-        fresh["precision_cpu"]["value"] = 3.0
-        assert bd.compare(fresh, base)[0]["regression"]       # slower
 
 
 # ---------------------------------------------------------------------------

@@ -4,8 +4,7 @@ the serving warm-registration zero-compile smoke (ledger-asserted via
 the new cache_hit cause), StoredJit train-step resolution with
 bit-identical math, Supervisor kill-and-resume over a warm store,
 the donation-safety clone for deserialized executables, the rewarm /
-cache_hit cause split, the /debug/compiles store section, and the
-benchdiff host-bound gating satellite."""
+cache_hit cause split, and the /debug/compiles store section."""
 
 import json
 import os
@@ -547,56 +546,6 @@ class TestOffByDefault:
         net = _mlp(seed=31)
         net._refresh_train_step()
         assert isinstance(net._train_step, compilestore.StoredJit)
-
-
-# ---------------------------------------------------------------------------
-# benchdiff: host-bound rows are reported, never gated off-chip
-# ---------------------------------------------------------------------------
-
-class TestBenchdiffHostBound:
-    def _benchdiff(self):
-        tools = pathlib.Path(__file__).resolve().parent.parent / "tools"
-        sys.path.insert(0, str(tools))
-        try:
-            import benchdiff
-        finally:
-            sys.path.remove(str(tools))
-        return benchdiff
-
-    def test_host_bound_cpu_row_not_gated(self):
-        bd = self._benchdiff()
-        base = {"serving_load_cpu": {
-            "value": 1.0, "unit": "x rows/s", "platform": "cpu",
-            "host_bound": True, "metric": "serving_load_saturation"}}
-        fresh = {"serving_load_cpu": {
-            "value": 0.5, "unit": "x rows/s", "platform": "cpu",
-            "host_bound": True}}
-        rows = bd.compare(fresh, base)
-        assert rows[0]["regression"] is False   # 2x worse, NOT gated
-        assert rows[0]["gated"] is False
-
-    def test_host_bound_chip_row_still_gates(self):
-        # the skip is platform-scoped: even a host_bound-tagged row
-        # gates when it WAS measured on its intended chip
-        bd = self._benchdiff()
-        base = {"decode": {
-            "value": 100.0, "unit": "tokens/s", "platform": "tpu",
-            "host_bound": True, "metric": "decode_tokens_per_s"}}
-        fresh = {"decode": {
-            "value": 10.0, "unit": "tokens/s", "platform": "tpu",
-            "host_bound": True}}
-        rows = bd.compare(fresh, base)
-        assert rows[0]["regression"] is True and rows[0]["gated"]
-
-    def test_plain_row_unaffected(self):
-        bd = self._benchdiff()
-        base = {"word2vec_cpu": {
-            "value": 100.0, "unit": "words/sec", "platform": "cpu",
-            "metric": "word2vec_words_per_sec"}}
-        fresh = {"word2vec_cpu": {
-            "value": 10.0, "unit": "words/sec", "platform": "cpu"}}
-        rows = bd.compare(fresh, base)
-        assert rows[0]["regression"] is True
 
 
 # ---------------------------------------------------------------------------

@@ -43,15 +43,13 @@ def fresh_ledger():
     reg = MetricsRegistry()
     prev_reg = telemetry.set_registry(reg)
     prev_led = memledger.set_ledger(MemLedger())
-    memledger.configure(budget_bytes=None, min_headroom_bytes=None,
-                        enabled=True)
+    memledger.configure(budget_bytes=None, min_headroom_bytes=None)
     telemetry.enable()
     flight.get_recorder().clear()
     yield reg
     telemetry.set_registry(prev_reg)
     memledger.set_ledger(prev_led)
-    memledger.configure(budget_bytes=None, min_headroom_bytes=None,
-                        enabled=True)
+    memledger.configure(budget_bytes=None, min_headroom_bytes=None)
     telemetry.enable()
 
 

@@ -201,9 +201,8 @@ class TestAsyncCheckpointer:
                             keepLast=2, asyncSave=True)
         tr.fit(data, epochs=2)    # warm: train step, cloner, writer path
         # wall-clock ratio on a 2-core container that swings +-40% run
-        # to run (see the bench notes): one scheduler hiccup during a
-        # ~1 ms snapshot blows the mean, so a failed window gets ONE
-        # re-measure — same never-time-a-single-pass doctrine as bench.py
+        # to run: one scheduler hiccup during a ~1 ms snapshot blows the
+        # mean, so a failed window gets ONE re-measure
         for attempt in range(2):
             fresh_registry.reset()
             tr.fit(data, epochs=4)    # measured, steady state

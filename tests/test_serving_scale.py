@@ -238,9 +238,11 @@ class TestReplicaSet:
             np.testing.assert_array_equal(f.result(timeout=1.0), x * 2)
         assert all(not r.is_alive() for r in rset.replicas)
 
-    def test_replica_results_bit_identical_and_zero_recompiles(self):
+    def test_replica_results_match_single_device_and_zero_recompiles(
+            self, assert_rows_close):
         """Real network: every replica's device-pinned executable
-        produces exactly the single-device output, with zero compiles
+        produces the single-device output within the float32 tolerance
+        (another placement: ROADMAP D4), with exactly zero compiles
         after warmup."""
         import jax
 
@@ -250,9 +252,7 @@ class TestReplicaSet:
                              ladder=BucketLadder((1, 4)), warmup=True)
         X = np.random.default_rng(3).normal(size=(4, 6)) \
             .astype(np.float32)
-        # per-row reference: bit-identity is a per-executable-shape
-        # guarantee — a batch-4 output() is a differently tiled XLA
-        # program that may differ from the bucket-1 executable by 1 ulp
+        # per-row reference, so that both sides are batch-1 programs
         y_ref = np.concatenate([net.output(X[i:i + 1]).toNumpy()
                                 for i in range(4)])
         rset = ReplicaSet(entry, n_replicas=min(4, len(jax.devices())))
@@ -262,8 +262,8 @@ class TestReplicaSet:
         futs = [b.submit(X[i % 4:i % 4 + 1], timeout=10.0)
                 for i in range(24)]
         for i, f in enumerate(futs):
-            np.testing.assert_array_equal(f.result(timeout=10.0),
-                                          y_ref[i % 4:i % 4 + 1])
+            assert_rows_close(f.result(timeout=10.0),
+                              y_ref[i % 4:i % 4 + 1])
         assert compiles.value == c0
         b.close()
 

@@ -24,9 +24,6 @@ Usage::
     python tools/coldstart.py                 # tmp store, full report
     python tools/coldstart.py --store DIR     # inspect/extend a store
     python tools/coldstart.py --json          # machine-readable report
-
-``bench.py --only coldstart`` runs the same trials and records the
-``coldstart`` row into BENCH_ALL.json.
 """
 
 from __future__ import annotations
@@ -195,9 +192,8 @@ def run_child(kind, store_dir, ckpt_dir=None, timeout=600):
     """Spawn one trial in a fresh interpreter; returns its JSON row."""
     env = dict(os.environ)
     env["DL4J_EXECUTABLE_STORE"] = store_dir
-    # hard-pin children to the host platform: the bench row is stamped
-    # platform="cpu"/host_bound, and a parent holding the chip cannot
-    # hand it to subprocesses anyway — inheriting a JAX_PLATFORMS=tpu
+    # hard-pin children to the host platform: a parent holding the
+    # chip cannot hand it to subprocesses — inheriting a JAX_PLATFORMS=tpu
     # would crash the trials or mislabel chip numbers as cpu
     env["JAX_PLATFORMS"] = "cpu"
     cmd = [sys.executable, os.path.abspath(__file__), "--child", kind]

@@ -124,8 +124,8 @@ def build_graves_lstm(batch, seq=50, vocab=77):
 
 def audit_bert(batch, seq):
     """BERT-base MLM train step through BertTrainer's own jitted step
-    (single-device mesh): the same executable bench.py's flagship row
-    measures."""
+    (single-device mesh): the `jit_step` that the benchmark's
+    `bert-large-mlm.train-16x512` cell times, at BERT-base's sizes."""
     import jax
     import jax.numpy as jnp
     import numpy as np
