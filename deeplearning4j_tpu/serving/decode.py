@@ -72,9 +72,13 @@ class DecodeShutdown(RuntimeError):
 def _phase(inst, phase):
     """The Timer over one phase of a boundary (histogram and
     `dl4j.decode.<phase>` span), or nothing when telemetry is off. The
-    five phases are leaves that tile an iteration of the engine's loop;
-    no span stands around the whole of it, so that a reader of a device
-    trace can give every idle gap to the phase that covers it."""
+    five phases are leaves, each observed once a delivered boundary, and
+    no span stands around the whole of an iteration, so that a reader of
+    a device trace can give every idle gap to the phase that covers it.
+    An iteration of the engine's loop runs `admit`, `build` and
+    `dispatch` of one boundary and then `readback` and `emit`: of the
+    same boundary where the engine is serial, of the boundary before it
+    where one token step is in flight (`DecodeEngine._step_boundary`)."""
     return contextlib.nullcontext() if inst is None else inst.phase(phase)
 
 
@@ -225,7 +229,7 @@ def _pool_bytes_estimate(model):
 # decode models
 # ---------------------------------------------------------------------------
 
-def _maybe_store(jitted, site, model, lane, donation=()):
+def _maybe_store(jitted, site, model, lane, donation=(), program=None):
     """Route a decode-model jit through the PR-13 persistent executable
     store (ISSUE 20 satellite: the remaining cold-start gap). Warm
     engine construction then deserializes every step/prefill/verify
@@ -234,7 +238,9 @@ def _maybe_store(jitted, site, model, lane, donation=()):
     SPMD executables bake in a device assignment), and the wrapper is
     the identity when the store is off. ``donation`` is the jit's own
     ``donate_argnums``: the store keys on it and owns a donated argument
-    before a deserialized executable's first call."""
+    before a deserialized executable's first call. ``program`` stands in
+    for the model's own digest where the executable does not depend on
+    the model's math."""
     from deeplearning4j_tpu import compilestore
 
     if getattr(model, "mesh", None) is not None:
@@ -242,7 +248,8 @@ def _maybe_store(jitted, site, model, lane, donation=()):
     if not compilestore.enabled():
         return jitted
     return compilestore.StoredJit(
-        jitted, site, program=f"{model._store_program()}:{lane}",
+        jitted, site,
+        program=program or f"{model._store_program()}:{lane}",
         donation=donation)
 
 
@@ -673,6 +680,16 @@ def _layer_norm(x, g, b, eps):
 # the engine
 # ---------------------------------------------------------------------------
 
+def _pick_token(feed, nxt):
+    """The tokens of a step dispatched while the one before it is still
+    in flight: the host's ``feed`` where it holds a token, and where it
+    holds the marker -1 (a slot that answers) the token that the launch
+    in flight chose for the slot, which never leaves the device."""
+    import jax.numpy as jnp
+
+    return jnp.where(feed < 0, nxt, feed)
+
+
 class _DecodeRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "future", "stream",
                  "slot", "ptr", "generated", "t_submit", "req_id",
@@ -689,7 +706,7 @@ class _DecodeRequest:
         self.future: Future = Future()
         self.stream: _queue.Queue = _queue.Queue()
         self.slot = None
-        self.ptr = 0            # next prompt position to feed
+        self.ptr = 0            # next position to feed (dispatched)
         self.generated: list[int] = []
         self.t_submit = time.perf_counter()
         self.req_id = req_id
@@ -733,11 +750,30 @@ class DecodeEngine:
       decodes from its first token (no separate prefill executable,
       no second compiled shape);
     - a finished sequence (max_new reached or eos) frees its slot and
-      pages at the SAME token boundary, and the next pending request
-      takes them over immediately;
-    - `warmup()` runs one throwaway step + slot reset so every
-      executable exists before traffic; after it, `dl4j_compile_total`
-      stays flat (asserted in tests).
+      pages at the boundary that DELIVERS its last token, and the next
+      pending request takes them over at the next admission;
+    - ONE TOKEN STEP IN FLIGHT: the engine dispatches the token step of
+      boundary t+1 before it reads boundary t's tokens, and reads,
+      emits and retires boundary t while the device runs t+1. A
+      request's position (``ptr``) advances when a launch is
+      dispatched; its tokens are appended, streamed and counted when
+      that launch is read, from the record the launch left. A slot that
+      answers is fed the marker -1 and takes its token from the launch
+      in flight, on the device (``_pick_token``). Who is fed at t+1 is
+      known by count (prompt length, ``max_new``); a request with an
+      ``eos_id`` rides t+1 on the device's token, and that row is
+      discarded when t's token turns out to be ``eos`` (its K/V row
+      lies inside the request's own pages, above every length mask).
+      Greedy output is token for token the serial engine's. What
+      drains the launch in flight before the next dispatch: an engine
+      with a block executable or a draft (``chunk``, ``speculative``:
+      their boundaries need the tokens on the host, so every boundary
+      of theirs is dispatched, read and emitted in that order), and a
+      boundary after which no active request has a position left to
+      feed;
+    - `warmup()` runs throwaway steps + slot reset so every executable
+      exists before traffic; after it, `dl4j_compile_total` stays flat
+      (asserted in tests).
 
     ISSUE 12 layers (all default-off, composable):
 
@@ -914,6 +950,23 @@ class DecodeEngine:
             self._pcache = (prefix_cache if isinstance(prefix_cache,
                                                        PrefixCache)
                             else PrefixCache(self._kv.page))
+        # one token step in flight (class docstring): the launch whose
+        # tokens are still on the device, as (nxt, fed, t_b0) with fed =
+        # [(slot, request, position fed)]. The choice between the host's
+        # feed and that launch's tokens exists only where the engine
+        # overlaps: with a block executable or a draft it is serial. It
+        # goes through the executable store under a key of its own (it
+        # does not depend on the model's math), because a warm engine
+        # compiles nothing: tests/test_compilestore.py's
+        # test_warm_decode_engine_zero_compiles counts this one too
+        self._flight = None
+        self._pick = None
+        if self._block is None and self._spec is None:
+            import jax
+
+            self._pick = _maybe_store(
+                jax.jit(_pick_token), "decode:pick", model, "pick",
+                program=f"decode:pick:slots={model.max_slots}")
         self.backlog_timeout = float(backlog_timeout)
         # duck-typed models (tests, foreign adapters) may predate the
         # ledger-site kwarg on step() — detect once, not per boundary
@@ -1028,7 +1081,8 @@ class DecodeEngine:
         on. Every executable lands in the compile ledger under a
         ``decode:<name>:*`` site, so the zero-steady-state-recompile
         invariant is ledger-assertable for the whole set: token step +
-        chunk prefill + verify + draft step + draft prefill (tests)."""
+        chunk prefill + verify + draft step + draft prefill, or token
+        step + the choice of its tokens on the device (tests)."""
         if compile_ledger.enabled():
             # the jax.monitoring hook installs on first registry use;
             # without it the warmup compiles below would never be
@@ -1044,7 +1098,18 @@ class DecodeEngine:
         # already-contiguous table): admission mutates the table
         # between boundaries, and jax may zero-copy numpy inputs
         table = self._table.copy()
-        _, self._state = self._model_step(self._state, tokens, pos, table)
+        nxt, self._state = self._model_step(self._state, tokens, pos,
+                                            table)
+        if self._pick is not None:
+            # the step as a boundary with one in flight calls it: its
+            # tokens a device array, chosen where the last ones live
+            feed = np.full((self.model.max_slots,), -1, np.int32)
+            tokens = self._pick(feed, nxt)
+            compile_ledger.note_step(
+                f"decode:{self.name}:pick", self._pick, (feed, nxt),
+                donation=())
+            _, self._state = self._model_step(self._state, tokens, pos,
+                                              table)
         if self._block is not None:
             self._state = self._block.warmup(
                 self._state, table, site=f"decode:{self.name}:prefill")
@@ -1308,7 +1373,11 @@ class DecodeEngine:
         ``err`` (last, so a caller that tries again finds the engine
         ready). The pool a launch was given is consumed whether or not
         the launch came back, so neither it nor a page the prefix
-        caches published from it can be used again."""
+        caches published from it can be used again. The error may have
+        surfaced at the read-back of one launch with the next already
+        dispatched on the consumed pool: that one's record goes with
+        the state, nothing of it is delivered."""
+        self._flight = None
         try:
             self._state = None      # let the old pool go before the new
             self._state = self.model.init_state()
@@ -1336,11 +1405,12 @@ class DecodeEngine:
             n += self._spec.clear_prefix_cache()
         return n
 
-    def _publish(self, req, slot):
+    def _publish(self, req, slot, written):
         """Put the request's full prompt pages into the prefix cache —
-        once, at the boundary where its prompt is fully written."""
+        once, at the boundary that delivers a launch after which
+        ``written`` positions, the whole prompt, are known written."""
         if self._pcache is None or req.published or \
-                req.ptr < len(req.prompt):
+                written < len(req.prompt):
             return
         req.published = True
         n_full = len(req.prompt) // self._kv.page
@@ -1443,52 +1513,114 @@ class DecodeEngine:
                                     prompt=int(counts.sum()))
         return True
 
+    def _has_position(self, req):
+        """Whether ``req`` has a position left to feed, by count: the
+        prompt's and all but the last of its answer's. (A request that
+        asked for no token is given one, as ever.)"""
+        return req.ptr < len(req.prompt) + max(req.max_new, 1) - 1
+
     def _step_boundary(self, inst):
-        """One per-token boundary through the step executable — the
-        PR-8 path, semantics unchanged: every active slot advances one
-        token (prefilling slots feed their next prompt token)."""
+        """One per-token boundary through the step executable: every
+        active slot with a position left advances one token (prefilling
+        slots feed their next prompt token), and one token step stays
+        in flight. The launch of this boundary is built and dispatched
+        BEFORE the launch in flight is read: a slot that answers is fed
+        the marker -1 and takes that launch's token on the device
+        (``_pick_token``), positions advance here, and the boundary in
+        flight is then read, emitted and retired (``_deliver``) while
+        the device runs this one. A slot that is not fed, free or
+        still holding its pages while its last launch is out, is given
+        a zero row of the page table and writes the scratch page. This
+        boundary stays in flight in its turn unless the engine is
+        serial (a block executable or a draft: their boundaries need
+        its tokens on the host) or nobody active has a position left to
+        feed; then it is delivered at once, as a boundary with nothing
+        to overlap."""
+        prev, self._flight = self._flight, None
         S = self.model.max_slots
         with _phase(inst, "build"):
-            tokens = np.zeros((S,), np.int32)
+            feed = np.zeros((S,), np.int32)
             pos = np.zeros((S,), np.int32)
             active = np.zeros((S,), bool)
-            n_prompt = n_answer = 0
+            fed = []
             # snapshot: close() may clear _active concurrently
             for slot, req in list(self._active.items()):
-                if req.ptr < len(req.prompt):
-                    tokens[slot] = req.prompt[req.ptr]
-                    n_prompt += 1
+                if not self._has_position(req):
+                    continue        # its last launch is out, not read
+                k = req.ptr - len(req.prompt)
+                if k < 0:
+                    feed[slot] = req.prompt[req.ptr]
+                elif k < len(req.generated):
+                    feed[slot] = req.generated[k]
                 else:
-                    tokens[slot] = req.generated[-1]
-                    n_answer += 1
+                    feed[slot] = -1     # in flight: prev's nxt[slot]
                 pos[slot] = req.ptr
                 active[slot] = True
+                fed.append((slot, req, req.ptr))
             # a REAL copy, not ascontiguousarray (which aliases an
             # already-contiguous table): admission mutates the table
             # between boundaries, and jax may zero-copy numpy inputs
             table = self._table.copy()
+            # a slot that is not fed writes K/V of (token 0, position 0)
+            # all the same: to the scratch page, as a free slot's zero
+            # row does, not to the first page of a request whose last
+            # launch is out (a prefix page that others may share)
+            table[~active] = 0
         t_b0 = time.perf_counter()
         try:
             with _phase(inst, "dispatch"):
+                tokens = feed
+                if (feed < 0).any():
+                    tokens = self._pick(feed, prev[0])
                 nxt, self._state = self._model_step(self._state, tokens,
                                                     pos, table)
                 if self._spec is not None:
                     # fallback boundaries keep the draft pool in sync so
                     # a later speculation probe proposes from real
                     # context
-                    self._spec.track(tokens, pos, active)
+                    self._spec.track(feed, pos, active)
+        except Exception as e:
+            self._fail_boundary(_boundary_error(
+                e, f"decode:{self.name}:step", "decode step failed"))
+            return
+        for _, req, _ in fed:
+            req.ptr += 1
+        launch = (nxt, fed, t_b0)
+        if prev is not None and not self._deliver(inst, prev, True):
+            return
+        if self._pick is not None and any(
+                self._has_position(r)
+                for r in list(self._active.values())):
+            self._flight = launch
+        else:
+            self._deliver(inst, launch, False)
+
+    def _deliver(self, inst, launch, overlapped):
+        """Read a dispatched token step's tokens and give them out: the
+        `readback` and `emit` phases of its boundary, from the record
+        the launch left of what it fed. ``overlapped`` says that its
+        successor was dispatched before this read. A row whose request
+        has ended since the dispatch (its ``eos`` came with the
+        boundary before, or it was failed or closed) is discarded:
+        nothing of it is emitted or counted. Returns False when the
+        read raised (every request was failed)."""
+        nxt, fed, t_b0 = launch
+        try:
             with _phase(inst, "readback"):
                 nxt = np.asarray(nxt)
         except Exception as e:
             self._fail_boundary(_boundary_error(
                 e, f"decode:{self.name}:step", "decode step failed"))
-            return
+            return False
         t_b1 = time.perf_counter()
         with _phase(inst, "emit"):
             self._last_boundary = time.monotonic()
-            n_decoded = 0
-            for slot, req in list(self._active.items()):
-                prefilling = req.ptr + 1 < len(req.prompt)
+            n_decoded = n_prompt = n_answer = 0
+            positions = []
+            for slot, req, p in fed:
+                if self._active.get(slot) is not req:
+                    continue
+                prefilling = p + 1 < len(req.prompt)
                 if req.trace is not None:
                     # one child span per token boundary this sequence
                     # took part in (ISSUE 10): prefill and decode
@@ -1504,16 +1636,18 @@ class DecodeEngine:
                         tracing.emit(
                             "decode.prefill" if prefilling
                             else "decode.token",
-                            req.trace, t_b0, t_b1, slot=slot,
-                            pos=req.ptr)
+                            req.trace, t_b0, t_b1, slot=slot, pos=p)
                     elif req.t_suppressed is None:
                         req.t_suppressed = t_b0
-                req.ptr += 1
-                self._publish(req, slot)
-                if req.ptr < len(req.prompt):
-                    continue            # still prefilling
-                tok = int(nxt[slot])
-                done = self._emit_token(req, tok, inst)
+                positions.append(p)
+                if p < len(req.prompt):
+                    n_prompt += 1
+                else:
+                    n_answer += 1
+                self._publish(req, slot, p + 1)
+                if prefilling:
+                    continue
+                done = self._emit_token(req, int(nxt[slot]), inst)
                 n_decoded += 1
                 if self._spec is not None and inst is not None:
                     inst.accepted("fallback", 1)
@@ -1522,7 +1656,10 @@ class DecodeEngine:
             if inst is not None:
                 inst.tokens.inc(n_decoded)
                 self._boundary_done(inst, "step", n_prompt, n_answer,
-                                    pos[active])
+                                    np.asarray(positions, np.int32))
+                if overlapped:
+                    inst.overlapped.inc()
+        return True
 
     def _speculative_boundary(self, inst):
         """Boundary phase 2, speculative (ISSUE 12 tentpole c): the
@@ -1613,7 +1750,7 @@ class DecodeEngine:
                 # true tokens overwrite those same positions (no
                 # rollback)
                 req.ptr += m
-                self._publish(req, slot)
+                self._publish(req, slot, req.ptr)
                 done = False
                 for j in range(m):
                     done = self._emit_token(req, int(outs[slot, j]),
@@ -1638,7 +1775,8 @@ class DecodeEngine:
             with _phase(inst if busy else None, "admit"):
                 self._admit(inst)
                 for req in list(self._active.values()):
-                    if not req.generated:
+                    # until the launch of its first token is dispatched
+                    if req.ptr < len(req.prompt):
                         req.ttft_boundaries += 1
             if not self._active:
                 self._last_boundary = None   # idle: nothing to wedge
@@ -1656,3 +1794,4 @@ class DecodeEngine:
                 self._speculative_boundary(inst)
             else:
                 self._step_boundary(inst)
+        self._flight = None     # closed: what is in flight is dropped

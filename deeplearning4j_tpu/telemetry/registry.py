@@ -540,9 +540,12 @@ SERVING_KV_OCCUPANCY_HELP = ("Fraction of the paged decode KV pool "
 DECODE_PHASES = ("admit", "build", "dispatch", "readback", "emit")
 DECODE_BOUNDARY_HELP = ("Seconds the decode engine's thread spent in "
                         "each phase of a boundary (admit|build|dispatch|"
-                        "readback|emit: the five tile one iteration of "
-                        "its loop; the same spans stand in a profiler "
-                        "trace as dl4j.decode.<phase>)")
+                        "readback|emit: each once a delivered boundary; "
+                        "with one token step in flight an iteration of "
+                        "its loop runs readback and emit of the boundary "
+                        "before the one it dispatched; the same spans "
+                        "stand in a profiler trace as "
+                        "dl4j.decode.<phase>)")
 DECODE_BOUNDARIES_HELP = ("Decode engine boundaries by the executable "
                           "that ran (step|prefill|verify)")
 DECODE_POSITIONS_HELP = ("Sequence positions the decode engine advanced, "
@@ -557,6 +560,11 @@ DECODE_LIVE_PAGES_HELP = ("Sum over token-step boundaries of the KV pages "
                           "page + 1 each): what the step's attention "
                           "visits; over dl4j_decode_boundaries_total"
                           "{executable=\"step\"} it is the mean a launch")
+DECODE_OVERLAPPED_HELP = ("Token-step boundaries whose successor was "
+                          "dispatched before their tokens were read (one "
+                          "token step in flight); over "
+                          "dl4j_decode_boundaries_total{executable="
+                          "\"step\"} it is the overlapped share")
 DECODE_QUEUE_WAIT_HELP = ("Seconds from decode submit to the boundary "
                           "at which the request took a slot")
 
@@ -571,7 +579,8 @@ class ServingInstruments:
                  "_replica_load", "_shed", "tokens", "slots",
                  "prefix_hits", "prefix_misses", "ttft", "_accepted",
                  "kv_occupancy", "_phases", "_boundaries", "_positions",
-                 "kv_fill_sum", "live_pages_sum", "decode_queue_wait")
+                 "kv_fill_sum", "live_pages_sum", "overlapped",
+                 "decode_queue_wait")
 
     def __init__(self, registry, model):
         self.model = model
@@ -640,6 +649,9 @@ class ServingInstruments:
         self.live_pages_sum = registry.counter(
             "dl4j_decode_live_pages_sum", DECODE_LIVE_PAGES_HELP,
             ("model",)).labels(model=model)
+        self.overlapped = registry.counter(
+            "dl4j_decode_overlapped_boundaries_total",
+            DECODE_OVERLAPPED_HELP, ("model",)).labels(model=model)
         self.decode_queue_wait = registry.histogram(
             "dl4j_decode_queue_wait_seconds", DECODE_QUEUE_WAIT_HELP,
             ("model",)).labels(model=model)

@@ -18,6 +18,8 @@ PR-8 head-of-line wedge fix (admission reclaims refcount==1 idle
 cached pages).
 """
 
+import os
+import sys
 import time
 
 import numpy as np
@@ -30,6 +32,10 @@ from deeplearning4j_tpu.serving import (
 from deeplearning4j_tpu.serving.decode import (
     DecodeError, _DecodeRequest, _pool_bytes_estimate)
 from deeplearning4j_tpu.telemetry import compile_ledger
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.lib.program_spans import sample_sum  # noqa: E402
 
 
 def _counter(name, **labels):
@@ -74,6 +80,22 @@ def offline_decode(model, prompt, max_new):
         if len(out) >= max_new:
             break
     return out
+
+
+def _char_rnn(vocab=11):
+    from deeplearning4j_tpu.nn import (
+        InputType, LossFunction, LSTM, MultiLayerNetwork,
+        NeuralNetConfiguration, RnnOutputLayer)
+    from deeplearning4j_tpu.optimize.updaters import Adam
+
+    conf = (NeuralNetConfiguration.Builder().seed(4)
+            .updater(Adam(1e-3)).list()
+            .layer(LSTM.Builder().nOut(12).build())
+            .layer(RnnOutputLayer.Builder().nOut(vocab)
+                   .activation("softmax")
+                   .lossFunction(LossFunction.MCXENT).build())
+            .setInputType(InputType.recurrent(vocab)).build())
+    return MultiLayerNetwork(conf).init()
 
 
 class TestPagedKVRefcount:
@@ -197,20 +219,7 @@ class TestChunkedPrefill:
         eng.close()
 
     def test_rnn_chunked_prefill_bit_identity(self):
-        from deeplearning4j_tpu.nn import (
-            InputType, LossFunction, LSTM, MultiLayerNetwork,
-            NeuralNetConfiguration, RnnOutputLayer)
-        from deeplearning4j_tpu.optimize.updaters import Adam
-
-        vocab = 11
-        conf = (NeuralNetConfiguration.Builder().seed(4)
-                .updater(Adam(1e-3)).list()
-                .layer(LSTM.Builder().nOut(12).build())
-                .layer(RnnOutputLayer.Builder().nOut(vocab)
-                       .activation("softmax")
-                       .lossFunction(LossFunction.MCXENT).build())
-                .setInputType(InputType.recurrent(vocab)).build())
-        net = MultiLayerNetwork(conf).init()
+        net = _char_rnn()
         prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9, 3]
         ref = offline_decode(RnnDecodeModel(net, max_slots=3),
                              prompt, 7)
@@ -633,6 +642,33 @@ def _greedy(fn, model, which, prompt, max_new):
     return out
 
 
+class _LostTokens:
+    """The tokens of a launch that died on the device: reading them
+    raises, dispatching the next launch does not."""
+
+    def __init__(self, on_read):
+        self._on_read = on_read
+
+    def __array__(self, *a, **kw):
+        self._on_read()
+        raise RuntimeError("injected launch failure")
+
+
+def _lose_tokens_once(real, seen):
+    """``real`` (a model's ``step``) whose first launch comes back with
+    tokens that cannot be read; ``seen`` gets the number of launches
+    that had gone out when the engine tried."""
+    calls = []
+
+    def wrapper(state, *a, **kw):
+        nxt, new_state = real(state, *a, **kw)
+        calls.append(True)
+        if len(calls) > 1:
+            return nxt, new_state
+        return _LostTokens(lambda: seen.append(len(calls))), new_state
+    return wrapper
+
+
 def _raise_after(real, site_suffix=""):
     """``real`` made to raise once AFTER its launch went out (the
     first whose ``site`` ends with ``site_suffix``): the state it was
@@ -724,19 +760,22 @@ class TestDonatedPool:
         finally:
             eng.close()
 
-    @pytest.mark.parametrize("arm", ["step", "prefill", "verify"])
+    @pytest.mark.parametrize("arm", ["step", "prefill", "verify",
+                                     "step_in_flight"])
     def test_failed_launch_fails_requests_then_serves_from_fresh_pool(
             self, arm, monkeypatch):
         """(d) After a launch that raised (its pool consumed), the
         active requests end with the error, and the next request is
         served correctly from a fresh pool with an empty prefix
-        cache."""
+        cache. ``step_in_flight``: the error surfaces at the launch's
+        read-back, with a second launch already dispatched on the
+        consumed pool."""
         kw = dict(seed=8, max_len=96, max_pages_per_slot=3)
         model = _xf(**kw)
         prompt = list(np.random.default_rng(2).integers(0, 40, size=40))
         ref = offline_decode(_xf(**kw), prompt, 6)
-        opts = {}
-        if arm != "step":
+        opts, plain, in_flight = {}, arm.startswith("step"), []
+        if not plain:
             opts = dict(chunk=4, prefix_cache=True)
         if arm == "verify":
             opts["speculative"] = SpeculativeConfig(
@@ -745,11 +784,15 @@ class TestDonatedPool:
         try:
             # a served request first: its prompt page is published
             assert eng.decode(prompt, 6, timeout=120.0) == ref
-            if arm != "step":
+            if not plain:
                 assert eng._pcache.stats()["pages"] > 0
             if arm == "step":
                 monkeypatch.setattr(model, "step",
                                     _raise_after(model.step))
+            elif arm == "step_in_flight":
+                monkeypatch.setattr(
+                    model, "step", _lose_tokens_once(model.step,
+                                                     in_flight))
             else:
                 monkeypatch.setattr(
                     eng._block, "launch",
@@ -760,7 +803,9 @@ class TestDonatedPool:
             assert old["k"].is_deleted()
             assert eng._state is not old
             assert not eng._state["k"].is_deleted()
-            if arm != "step":
+            if arm == "step_in_flight":
+                assert in_flight == [2] and eng._flight is None
+            if not plain:
                 assert eng._pcache.stats()["pages"] == 0
             assert eng._kv.free_pages == eng._kv.n_pages
             assert eng.decode(prompt, 6, timeout=120.0) == ref
@@ -784,6 +829,266 @@ class TestDonatedPool:
             assert all(r["signature"]["donation"] == [1] for r in recs)
         finally:
             eng.close()
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 31: one token step in flight
+# ---------------------------------------------------------------------------
+
+def _overlap_models(kind):
+    """(the engine's model, the serial loop's, the vocabulary): three
+    slots; the transformer's pool holds 12 pages of 8 rows where its
+    slots could ask for 21, so requests wait for pages as for slots."""
+    if kind == "rnn":
+        net = _char_rnn()
+        return (RnnDecodeModel(net, max_slots=3),
+                RnnDecodeModel(net, max_slots=3), 11)
+    kw = dict(seed=12, max_len=64, max_slots=3, page=8,
+              max_pages_per_slot=7, n_pages=12)
+    return _xf(**kw), _xf(**kw), 40
+
+
+def _serial_answer(model, prompt, max_new, eos_id):
+    """What a plain serial loop over ``model.step`` gives the request
+    alone: the greedy tokens up to ``max_new`` or the first ``eos``."""
+    out = offline_decode(model, prompt, max_new)
+    if eos_id in out:
+        out = out[:out.index(eos_id) + 1]
+    return out
+
+
+@pytest.fixture(scope="module", params=["transformer", "rnn"])
+def overlapped_run(request):
+    """Eleven requests on three slots through a plain engine (prompts
+    1-40, answers 1-12, every second with an ``eos_id`` taken from its
+    own greedy stream): what each was given, what the serial loop
+    gives it, and what the engine left behind."""
+    from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
+
+    model, alone, vocab = _overlap_models(request.param)
+    rng = np.random.default_rng(31)
+    given = []
+    for i in range(11):
+        prompt = [int(t) for t in rng.integers(
+            1, vocab, size=int(rng.integers(1, 41)))]
+        max_new = int(rng.integers(1, 13))
+        eos_id = None
+        if i % 2 and max_new > 1:
+            # a token the stream reaches before its last
+            stream = offline_decode(alone, prompt, max_new)
+            eos_id = stream[(max_new - 1) // 2]
+        given.append((prompt, max_new, eos_id))
+    want = [_serial_answer(alone, *g) for g in given]
+    reg = MetricsRegistry()
+    prev = telemetry.set_registry(reg)
+    telemetry.enable()
+    name = f"overlap-{request.param}"
+    eng = DecodeEngine(
+        model, name=name,
+        instruments=telemetry.serving_instruments(name)).warmup()
+    try:
+        got = [r.result(timeout=180.0)
+               for r in _submit_at_once(eng, given)]
+        left = {"free_slots": sorted(eng._free_slots),
+                "active": dict(eng._active), "flight": eng._flight,
+                "free_pages": (None if eng._kv is None
+                               else (eng._kv.free_pages,
+                                     eng._kv.n_pages))}
+    finally:
+        eng.close()
+        telemetry.set_registry(prev)
+    return {"given": given, "want": want, "got": got, "left": left,
+            "snap": reg.snapshot(), "name": name}
+
+
+def _submit_at_once(eng, given):
+    """Every request of ``given`` pending before the engine admits the
+    first, so that what overlaps does not depend on how fast this
+    thread submits."""
+    import threading
+
+    gate, admit = threading.Event(), eng._admit
+
+    def gated(inst):
+        gate.wait(30.0)
+        return admit(inst)
+    eng._admit = gated
+    try:
+        return [eng.submit(p, m, eos_id=e) for p, m, e in given]
+    finally:
+        gate.set()
+
+
+def _sum(snap, family, **labels):
+    return sample_sum(snap, family, **labels) or 0.0
+
+
+def _row_of_position_0(model, token):
+    """The K rows, one a layer, that a step of ``token`` at position 0
+    writes into a fresh pool."""
+    kv = PagedKVCache(model.n_pages, model.page,
+                      model.max_pages_per_slot, model.max_slots)
+    kv.reserve(0, 1)
+    table = np.ascontiguousarray(kv.table)
+    toks = np.zeros((model.max_slots,), np.int32)
+    toks[0] = token
+    _, state = model.step(model.init_state(), toks,
+                          np.zeros((model.max_slots,), np.int32), table)
+    return np.asarray(state["k"])[:, table[0, 0], 0]
+
+
+_SCRIPT = [([5, 9, 2, 11, 3, 1, 4, 8, 6, 6, 2, 7, 1, 9, 3, 5, 8, 2, 4,
+             7, 3], 9), ([4, 4, 1], 5), ([7], 12)]
+
+
+class TestOneStepInFlight:
+    def test_tokens_are_the_serial_loops(self, overlapped_run):
+        """(a) Token for token what a plain serial loop over
+        ``model.step`` computes for each request alone, with slots and
+        pages reused while a launch is in flight."""
+        run = overlapped_run
+        cut_short = [len(w) < m for w, (_, m, e) in
+                     zip(run["want"], run["given"]) if e is not None]
+        assert cut_short and all(cut_short)     # ``eos`` really came
+        assert run["got"] == run["want"]
+
+    def test_nothing_leaks_and_a_discarded_row_counts_nowhere(
+            self, overlapped_run):
+        """(b) Every page and slot is free afterwards, nothing is in
+        flight, and the positions counted are those delivered: the row
+        a request rode after its ``eos`` is in no count."""
+        run, left = overlapped_run, overlapped_run["left"]
+        assert left["free_slots"] == [0, 1, 2]
+        assert not left["active"] and left["flight"] is None
+        if left["free_pages"] is not None:
+            assert left["free_pages"][0] == left["free_pages"][1]
+        delivered = sum(len(p) + len(a) - 1 for (p, _, _), a in
+                        zip(run["given"], run["got"]))
+        snap, name = run["snap"], run["name"]
+        assert _sum(snap, "dl4j_decode_positions_total",
+                    model=name) == delivered
+        assert _sum(snap, "dl4j_decode_positions_total", model=name,
+                    kind="prompt") == sum(len(p) for p, _, _ in
+                                          run["given"])
+        assert _sum(snap, "dl4j_serving_decode_tokens_total",
+                    model=name) == sum(len(a) for a in run["got"])
+        steps = _sum(snap, "dl4j_decode_boundaries_total", model=name,
+                     executable="step")
+        overlapped = _sum(snap,
+                          "dl4j_decode_overlapped_boundaries_total",
+                          model=name)
+        # eleven requests pending at once: the engine never idles, and
+        # delivers at once only where every slot ends on one boundary
+        assert steps - 3 <= overlapped < steps
+
+    @pytest.mark.parametrize("arm", ["plain", "chunk", "draft"])
+    def test_overlapped_boundaries_over_a_scripted_run(self, arm):
+        """(c) ``dl4j_decode_overlapped_boundaries_total``: every
+        boundary of one uninterrupted burst but the last on a plain
+        engine; none with a block executable or a draft, whose tokens
+        are those of the plain engine bit for bit."""
+        from deeplearning4j_tpu.telemetry.registry import MetricsRegistry
+
+        kw = dict(seed=14, max_len=64, max_slots=3, page=8,
+                  max_pages_per_slot=5)
+        model = _xf(**kw)
+        opts = {"plain": {}, "chunk": {"chunk": 4},
+                "draft": {"speculative": SpeculativeConfig(
+                    draft=_draft_of(model), k=2)}}[arm]
+        want = [offline_decode(_xf(**kw), p, m) for p, m in _SCRIPT]
+        reg = MetricsRegistry()
+        prev = telemetry.set_registry(reg)
+        telemetry.enable()
+        name = f"burst-{arm}"
+        eng = DecodeEngine(
+            model, name=name,
+            instruments=telemetry.serving_instruments(name),
+            **opts).warmup()
+        try:
+            reqs = _submit_at_once(eng, [(p, m, None)
+                                         for p, m in _SCRIPT])
+            assert [r.result(timeout=180.0) for r in reqs] == want
+        finally:
+            eng.close()
+            telemetry.set_registry(prev)
+        snap = reg.snapshot()
+        steps = _sum(snap, "dl4j_decode_boundaries_total", model=name,
+                     executable="step")
+        overlapped = _sum(snap,
+                          "dl4j_decode_overlapped_boundaries_total",
+                          model=name)
+        if arm == "plain":
+            # the longest request alone sets the burst's length
+            assert steps == 21 + 9 - 1 and overlapped == steps - 1
+        else:
+            assert steps > 0 and overlapped == 0
+
+    def test_unfed_slot_leaves_shared_prefix_pages_alone(self):
+        """A plain engine with a prefix cache overlaps too. A request
+        whose last launch is out holds its pages and is not fed: the
+        next launch must write that slot's row to the scratch page, not
+        to row 0 of its first page, which is a published prefix page
+        that concurrent and later requests share. Tokens are the serial
+        loop's, for the publishers, for those that adopt beside them
+        and for a rerun that adopts after every publisher is gone."""
+        kw = dict(seed=16, max_len=64, max_slots=2, page=8,
+                  max_pages_per_slot=5)
+        rng = np.random.default_rng(16)
+        shared = [int(t) for t in rng.integers(1, 40, size=16)]
+        given = [(shared + [int(t) for t in rng.integers(1, 40, size=n)],
+                  m, None)
+                 for n, m in ((2, 2), (5, 9), (1, 3), (3, 7), (2, 1),
+                              (4, 6))]
+        alone = _xf(**kw)
+        want = [offline_decode(alone, p, m) for p, m, _ in given]
+        eng = DecodeEngine(_xf(**kw), name="pfx-fl",
+                           prefix_cache=True).warmup()
+        try:
+            assert eng._pick is not None        # it overlaps
+            got = [r.result(timeout=180.0)
+                   for r in _submit_at_once(eng, given)]
+            assert got == want
+            assert eng._pcache.hits >= 3
+            again = [eng.submit(p, m) for p, m, _ in given[:3]]
+            assert [r.result(timeout=180.0) for r in again] == want[:3]
+            # and the first cached page still holds, at row 0, what
+            # position 0 of the shared prefix wrote there (the tokens
+            # of a toy model can survive one foreign row; this cannot)
+            pages, _ = eng._pcache.match(shared + [1])
+            assert len(pages) == 2
+            np.testing.assert_array_equal(
+                np.asarray(eng._state["k"])[:, pages[0], 0],
+                _row_of_position_0(alone, shared[0]))
+            eng.clear_prefix_cache()
+            assert eng._kv.free_pages == eng._kv.n_pages
+        finally:
+            eng.close()
+
+    def test_close_with_a_launch_in_flight(self):
+        """(e) ``close()`` with a launch in flight returns within its
+        timeout, nothing is delivered afterwards, and every stream
+        ends in ``_END`` exactly once."""
+        eng = DecodeEngine(_xf(seed=15, max_len=576), name="close-fl"
+                           ).warmup()
+        rng = np.random.default_rng(6)
+        reqs = [eng.submit([int(t) for t in rng.integers(1, 40, size=n)],
+                           60) for n in (400, 300, 350)]
+        deadline = time.monotonic() + 30.0
+        while eng._flight is None and time.monotonic() < deadline:
+            time.sleep(0.0005)
+        assert eng._flight is not None
+        t0 = time.monotonic()
+        eng.close(timeout=5.0)
+        assert time.monotonic() - t0 < 5.0
+        assert not eng._thread.is_alive() and eng._flight is None
+        time.sleep(0.1)
+        for r in reqs:
+            items = []
+            while not r.stream.empty():
+                items.append(r.stream.get_nowait())
+            assert items.count(_DecodeRequest._END) == 1
+            assert items[-1] is _DecodeRequest._END
+            assert r.future.done()
 
 
 # ---------------------------------------------------------------------------
