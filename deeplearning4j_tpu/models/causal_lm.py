@@ -2,20 +2,24 @@
 
 One pre-norm block, `h = x + Attn_l(RMSNorm(x))`, `y = h + MLP_l(RMSNorm(h))`,
 where each layer says for itself which attention it has (`full` or
-`sliding`: causal, a sliding layer also masks `i - j >= sliding_window`),
-how many query heads (grouped over `kv_heads` K and V heads), which rotary
-parameters (partial rotary, YaRN or plain) and which MLP (`dense`, a gated
-MLP; `sparse`, a sigmoid-routed expert layer plus one shared expert). The
-configuration is built from a published `config.json`'s own keys
-(`layer_types`, `num_attention_heads_per_layer`, `mlp_layer_types`,
-`rope_parameters`, ...), cut to the chip's share of a deployment:
+`sliding`: causal, a sliding layer also masks `i - j >= sliding_window`;
+`latent`: causal, queries and keys and values through low-rank latents, one
+rotary key shared by all heads), how many query heads (grouped over
+`kv_heads` K and V heads), which rotary parameters (partial rotary, YaRN or
+plain) and which MLP (`dense`, a gated MLP; `sparse`, a sigmoid-routed
+expert layer plus one shared expert, chosen over all experts or inside the
+best groups on biased scores). The configuration is built from a published
+`config.json`'s own keys (`layer_types`, `num_attention_heads_per_layer`,
+`mlp_layer_types`, `rope_parameters`, ...; `from_latent_published` reads the
+keys of a latent-attention config), cut to the chip's share of a deployment:
 `experts_held = (first, count)` of each sparse layer's experts and
 `vocab_held` rows of embedding and head (`parallel/moe.py:moe_share_apply`).
 
 bfloat16 activations and matmul operands with float32 accumulation; norms,
 rotary tables, router scores and the loss in float32; float32 parameters;
 each layer under `jax.checkpoint`. `CausalLMTrainer` trains it through the
-step engine that `BertTrainer` uses."""
+step engine that `BertTrainer` uses; `serving/latent.py` decodes the same
+block description, token by token, over a paged pool of latents."""
 
 from __future__ import annotations
 
@@ -41,7 +45,7 @@ INIT_STD = 0.02
 
 @dataclass(frozen=True)
 class LayerSpec:
-    attention: str          # "full" | "sliding"
+    attention: str          # "full" | "sliding" | "latent"
     heads: int              # query heads of this layer
     mlp: str                # "dense" | "sparse"
 
@@ -64,6 +68,74 @@ class CausalLMConfig:
     experts_held: tuple      # (first, count) of the experts that live here
     rms_eps: float = 1e-6
     compute_dtype: str = "bfloat16"
+    # the router's choice: inside the best `topk_group` of `n_group` groups,
+    # on scores plus a bias that the loss does not train
+    n_group: int = 1
+    topk_group: int = 1
+    router_bias: bool = False
+    # latent attention: the ranks of the query's and the cache's latents,
+    # each head's part without and with rotary, its value's width, and the
+    # factor on the scores
+    q_rank: int = 0
+    kv_rank: int = 0
+    nope_dim: int = 0
+    rope_dim: int = 0
+    v_dim: int = 0
+    latent_scale: float = 1.0
+
+    @classmethod
+    def from_latent_published(cls, published: dict, layer_ids=None,
+                              experts_held=None, vocab_held=None, **kw):
+        """From the keys of a published latent-attention config.json
+        (`q_lora_rank`, `kv_lora_rank`, `qk_nope_head_dim`,
+        `qk_rope_head_dim`, `v_head_dim`, `first_k_dense_replace`,
+        `n_routed_experts`, `n_group`, `topk_group`, `rope_scaling`, ...).
+        `layer_ids` are the published layers that run here, all by default;
+        the first `first_k_dense_replace` have a dense MLP. YaRN's two
+        magnitude factors are equal there, so the tables carry none and
+        the scores are scaled by (1 + 0.1 mscale ln factor)^2 beside
+        1 / sqrt(the query head's width)."""
+        ids = (range(published["num_hidden_layers"]) if layer_ids is None
+               else layer_ids)
+        heads = published["num_attention_heads"]
+        layers = tuple(
+            LayerSpec("latent", heads,
+                      "dense" if i < published["first_k_dense_replace"]
+                      else "sparse") for i in ids)
+        scaling = published["rope_scaling"]
+        if scaling["mscale"] != scaling["mscale_all_dim"]:
+            raise ValueError("rotary tables with a magnitude factor of "
+                             "their own are not written here")
+        rope = {"rope_theta": published["rope_theta"], "rope_type": "yarn",
+                "attention_factor": 1.0,
+                **{k: scaling[k] for k in (
+                    "factor", "original_max_position_embeddings",
+                    "beta_fast", "beta_slow")}}
+        nope, rot = (published["qk_nope_head_dim"],
+                     published["qk_rope_head_dim"])
+        mscale = 1.0 + 0.1 * scaling["mscale"] * math.log(scaling["factor"])
+        return cls(
+            layers=layers, rope={"latent": rope},
+            vocab_held=vocab_held or published["vocab_size"],
+            hidden=published["hidden_size"], head_dim=nope + rot,
+            kv_heads=published["num_key_value_heads"], sliding_window=0,
+            dense_ffn=published["intermediate_size"],
+            expert_ffn=published["moe_intermediate_size"],
+            shared_ffn=(published["n_shared_experts"]
+                        * published["moe_intermediate_size"]),
+            num_experts=published["n_routed_experts"],
+            top_k=published["num_experts_per_tok"],
+            routed_scale=published["routed_scaling_factor"],
+            experts_held=tuple(experts_held
+                               or (0, published["n_routed_experts"])),
+            rms_eps=published["rms_norm_eps"],
+            n_group=published["n_group"],
+            topk_group=published["topk_group"],
+            router_bias=published["topk_method"] == "noaux_tc",
+            q_rank=published["q_lora_rank"],
+            kv_rank=published["kv_lora_rank"], nope_dim=nope, rope_dim=rot,
+            v_dim=published["v_head_dim"],
+            latent_scale=mscale * mscale / math.sqrt(nope + rot), **kw)
 
     @classmethod
     def from_published(cls, published: dict, num_layers=None,
@@ -100,6 +172,11 @@ class CausalLMConfig:
     def sparse_layers(self):
         return [i for i, s in enumerate(self.layers) if s.mlp == "sparse"]
 
+    def rotary_width(self, kind):
+        """The width `rope_tables` is asked for: a latent layer rotates the
+        shared key's `rope_dim`, the others (part of) a head."""
+        return self.rope_dim if kind == "latent" else self.head_dim
+
 
 # -- parameters ---------------------------------------------------------------
 
@@ -123,20 +200,32 @@ def init_params(cfg: CausalLMConfig, key) -> dict:
               "final_norm": jnp.ones((d,)), "layers": []}
     for spec, lk in zip(cfg.layers, keys[2:]):
         k = jax.random.split(lk, 8)
-        layer = {
-            "attn_norm": jnp.ones((d,)), "mlp_norm": jnp.ones((d,)),
-            "wq": norm(k[0], (d, spec.heads * hd)),
-            "wk": norm(k[1], (d, cfg.kv_heads * hd)),
-            "wv": norm(k[2], (d, cfg.kv_heads * hd)),
-            "wg": norm(k[3], (d, spec.heads)),
-            "wo": norm(k[4], (spec.heads * hd, d)),
-        }
+        layer = {"attn_norm": jnp.ones((d,)), "mlp_norm": jnp.ones((d,))}
+        if spec.attention == "latent":
+            layer.update(
+                wq_a=norm(k[0], (d, cfg.q_rank)),
+                q_norm=jnp.ones((cfg.q_rank,)),
+                wq_b=norm(k[1], (cfg.q_rank, spec.heads * hd)),
+                wkv_a=norm(k[2], (d, cfg.kv_rank + cfg.rope_dim)),
+                kv_norm=jnp.ones((cfg.kv_rank,)),
+                wkv_b=norm(k[3], (cfg.kv_rank, spec.heads
+                                  * (cfg.nope_dim + cfg.v_dim))),
+                wo=norm(k[4], (spec.heads * cfg.v_dim, d)))
+        else:
+            layer.update(
+                wq=norm(k[0], (d, spec.heads * hd)),
+                wk=norm(k[1], (d, cfg.kv_heads * hd)),
+                wv=norm(k[2], (d, cfg.kv_heads * hd)),
+                wg=norm(k[3], (d, spec.heads)),
+                wo=norm(k[4], (spec.heads * hd, d)))
         if spec.mlp == "dense":
             layer["mlp"] = _gated_mlp_init(k[5], d, cfg.dense_ffn, std)
         else:
             layer["moe"] = moe_share_init(
                 k[5], d, cfg.expert_ffn, cfg.num_experts,
                 cfg.experts_held[1], std)
+            if cfg.router_bias:
+                layer["moe"]["bias"] = jnp.zeros((cfg.num_experts,))
             layer["shared"] = _gated_mlp_init(k[6], d, cfg.shared_ffn, std)
         params["layers"].append(layer)
     return params
@@ -196,6 +285,17 @@ def apply_rope(x, cos, sin):
     c, s = cos[None, :, None, :], sin[None, :, None, :]
     return jnp.concatenate(
         [x1 * c - x2 * s, x2 * c + x1 * s, rest], axis=-1).astype(x.dtype)
+
+
+def apply_rope_pairs(x, cos, sin):
+    """Rotate the neighbouring pairs (x[2i], x[2i+1]) of the last axis, in
+    float32: the layout of the published latent-attention code, where
+    `apply_rope` pairs x[i] with x[i + half]. cos, sin: [..., last / 2],
+    broadcast against x's leading axes."""
+    xf = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    a, b = xf[..., 0], xf[..., 1]
+    return jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                     axis=-1).reshape(x.shape).astype(x.dtype)
 
 
 # -- attention ----------------------------------------------------------------
@@ -272,8 +372,54 @@ def gated_mlp(p, u):
                .astype(u.dtype), p["down"])
 
 
+def latent_project(lp, u, cfg: CausalLMConfig, heads: int, cos, sin):
+    """What a latent layer makes of its normed input u [..., d] at the
+    positions whose rotary rows are cos, sin [..., rope_dim / 2]: each
+    head's query without rotary [..., H, nope_dim] and with it [..., H,
+    rope_dim] (rotated), and what the cache holds of a position, the
+    normed latent c [..., kv_rank] and the rotated key k_r [..., rope_dim]
+    that all heads share. The expanded attention (`latent_attention`) and
+    the absorbed token step (`serving/latent.py`) both start here."""
+    dtype = u.dtype
+    c_q = rms_norm(_mm(u, lp["wq_a"]), lp["q_norm"], cfg.rms_eps)
+    q = _mm(c_q.astype(dtype), lp["wq_b"]).astype(dtype).reshape(
+        *u.shape[:-1], heads, cfg.nope_dim + cfg.rope_dim)
+    kv = _mm(u, lp["wkv_a"])
+    c = rms_norm(kv[..., :cfg.kv_rank], lp["kv_norm"],
+                 cfg.rms_eps).astype(dtype)
+    k_r = apply_rope_pairs(kv[..., cfg.kv_rank:], cos, sin).astype(dtype)
+    q_r = apply_rope_pairs(q[..., cfg.nope_dim:], cos[..., None, :],
+                           sin[..., None, :])
+    return q[..., :cfg.nope_dim], q_r, c, k_r
+
+
+def latent_attention(lp, u, cfg: CausalLMConfig, spec: LayerSpec, tables):
+    """Causal latent attention over a whole sequence, every position
+    expanded into heads: [k_nope_h | v_h] = c W_kvb,h, the score of head h
+    is (q_nope_h . k_nope_h + q_rope_h . k_r) x `latent_scale`, softmax in
+    float32. u [B, T, d] -> [B, T, d] float32. A plain masked product (no
+    kernel takes heads whose keys are wider than their values)."""
+    b, t, _ = u.shape
+    cos, sin = tables["latent"]
+    q_n, q_r, c, k_r = latent_project(lp, u, cfg, spec.heads, cos, sin)
+    kv = _mm(c, lp["wkv_b"]).astype(u.dtype).reshape(
+        b, t, spec.heads, cfg.nope_dim + cfg.v_dim)
+    k_n, v = kv[..., :cfg.nope_dim], kv[..., cfg.nope_dim:]
+    s = (jnp.einsum("bqhd,bkhd->bhqk", q_n, k_n,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bqhd,bkd->bhqk", q_r, k_r,
+                      preferred_element_type=jnp.float32)) * cfg.latent_scale
+    seen = jnp.arange(t)[None, :] <= jnp.arange(t)[:, None]
+    p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(u.dtype), v,
+                   preferred_element_type=jnp.float32).astype(u.dtype)
+    return _mm(o.reshape(b, t, spec.heads * cfg.v_dim), lp["wo"])
+
+
 def attention_block(lp, u, cfg: CausalLMConfig, spec: LayerSpec, tables):
     """u: the layer's normed input [B, T, d] -> [B, T, d] float32."""
+    if spec.attention == "latent":
+        return latent_attention(lp, u, cfg, spec, tables)
     b, t, _ = u.shape
     hd = cfg.head_dim
     heads = lambda w, n: _mm(u, w).astype(u.dtype).reshape(  # noqa: E731
@@ -308,7 +454,8 @@ def layer_forward(lp, x, cfg: CausalLMConfig, spec: LayerSpec, tables):
     else:
         routed, choices, dropped = moe_share_apply(
             lp["moe"], u.reshape(b * t, d), top_k=cfg.top_k,
-            experts_held=cfg.experts_held, routed_scale=cfg.routed_scale)
+            experts_held=cfg.experts_held, routed_scale=cfg.routed_scale,
+            n_group=cfg.n_group, topk_group=cfg.topk_group)
         with jax.named_scope("moe.shared"):
             out = routed.reshape(b, t, d) + gated_mlp(lp["shared"], u)
     return (h + out).astype(dtype), choices, dropped
@@ -320,7 +467,7 @@ def forward(params, cfg: CausalLMConfig, tokens):
     int32 [sparse layers])."""
     dtype = jnp.dtype(cfg.compute_dtype)
     t = tokens.shape[1]
-    tables = {kind: rope_tables(cfg.rope[kind], cfg.head_dim, t)
+    tables = {kind: rope_tables(cfg.rope[kind], cfg.rotary_width(kind), t)
               for kind in {s.attention for s in cfg.layers}}
     x = params["embed"][tokens].astype(dtype)
     choices, dropped = [], []
@@ -376,19 +523,6 @@ def lm_loss(params, cfg: CausalLMConfig, tokens, labels):
 
 # -- the trainer --------------------------------------------------------------
 
-MOE_CHOICES_HELP = ("Expert choices the sparse layer's router made "
-                    "(tokens x experts per token), by layer")
-MOE_HELD_HELP = ("Expert choices that fell on an expert this program "
-                 "holds, by layer")
-MOE_DROPPED_HELP = ("Held expert choices that did not fit the expert "
-                    "layer's buffer and were left out, by layer: 0 while "
-                    "the layer is dropless")
-MOE_LOAD_HELP = ("Sum over steps of the fullest held expert's choices over "
-                 "the held experts' mean; over dl4j_moe_steps_total it is "
-                 "the mean imbalance, by layer")
-MOE_STEPS_HELP = "Train steps whose router counts have been published"
-
-
 class CausalLMTrainer:
     """`train_step(tokens, labels)` over the shared step engine: fwd + bwd
     + Adam in one donated executable, rows over `data`. Beside the loss the
@@ -396,11 +530,12 @@ class CausalLMTrainer:
     publishes those counts one step behind, so that reading them never
     holds up a dispatch. `params` starts from weights of the caller's (a
     tree shaped as `init_params`'s) where the seed's are not wanted; with
-    `warmup_steps` the rate climbs linearly to `lr` over that many steps."""
+    `warmup_steps` the rate climbs linearly to `lr` over that many steps.
+    `name` is the `model` label of the `dl4j_moe_*` series."""
 
     def __init__(self, cfg: CausalLMConfig, mesh: Mesh, lr=1e-4, seed=0,
-                 params=None, warmup_steps=0):
-        self.cfg, self.mesh, self.lr = cfg, mesh, lr
+                 params=None, warmup_steps=0, name="causal_lm"):
+        self.cfg, self.mesh, self.lr, self.name = cfg, mesh, lr, name
         self.warmup_steps = warmup_steps
         rows = NamedSharding(mesh, spec_for(mesh, DATA_AXIS))
         repl = NamedSharding(mesh, P())
@@ -416,7 +551,7 @@ class CausalLMTrainer:
         # [sparse layers]) on the device, and what waits to be published
         self.router_counts = None
         self._unpublished = None
-        self._series = None      # the dl4j_moe_* families, once bound
+        self._series = None      # the dl4j_moe_* bundle, once bound
 
     params = property(lambda self: self._engine.params)
     opt = property(lambda self: self._engine.opt)
@@ -455,21 +590,8 @@ class CausalLMTrainer:
             return
         choices, dropped = (np.asarray(a) for a in waiting[0])
         if self._series is None:
-            reg = telemetry.get_registry()
-            self._series = [
-                reg.counter(name, text, ("layer",)) for name, text in (
-                    ("dl4j_moe_choices_total", MOE_CHOICES_HELP),
-                    ("dl4j_moe_held_choices_total", MOE_HELD_HELP),
-                    ("dl4j_moe_dropped_total", MOE_DROPPED_HELP),
-                    ("dl4j_moe_load_max_over_mean_sum", MOE_LOAD_HELP))
-            ] + [reg.counter("dl4j_moe_steps_total", MOE_STEPS_HELP)]
-        n_all, held, lost, load, steps = self._series
+            self._series = telemetry.moe_instruments(self.name)
         every = waiting[1] * self.cfg.top_k
-        for i, layer in enumerate(self.cfg.sparse_layers):
-            label = {"layer": str(layer)}
-            n_all.labels(**label).inc(every)
-            held.labels(**label).inc(int(choices[i].sum()))
-            lost.labels(**label).inc(int(dropped[i]))
-            load.labels(**label).inc(
-                float(choices[i].max() / max(choices[i].mean(), 1e-9)))
-        steps.inc()
+        self._series.step(self.cfg.sparse_layers, [
+            (every, c.sum(), d, c.max() / max(c.mean(), 1e-9), (c > 0).sum())
+            for c, d in zip(choices, dropped)])

@@ -132,8 +132,24 @@ def moe_share_rows(n_tokens: int, top_k: int, n_experts: int,
     return min(worst, int(math.ceil(SHARE_BUFFER * even / 8.0)) * 8)
 
 
+def _keep_groups(select, n_group: int, topk_group: int):
+    """`select` [N, E] with the experts outside each token's best
+    `topk_group` of `n_group` equal groups at -inf. A group's mark is the
+    sum of its two largest entries (node-limited routing: a token's
+    experts lie on at most `topk_group` nodes)."""
+    n, e = select.shape
+    grouped = select.reshape(n, n_group, e // n_group)
+    mark = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)       # [N, G]
+    _, best = jax.lax.top_k(mark, topk_group)
+    kept = jnp.any(best[:, :, None] == jnp.arange(n_group)[None, None, :],
+                   axis=1)                                       # [N, G]
+    return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
+
+
 def moe_share_apply(params, x, *, top_k: int, experts_held,
-                    routed_scale: float = 1.0):
+                    routed_scale: float = 1.0, n_group: int = 1,
+                    topk_group: int = 1, rows: int | None = None,
+                    live=None):
     """The part of a sigmoid-routed expert layer that the experts held here
     give. x: [N, H] -> (y [N, H] float32, choices int32 [held], dropped
     int32 scalar).
@@ -142,7 +158,12 @@ def moe_share_apply(params, x, *, top_k: int, experts_held,
     `params` holds (`gate`, `up` [count, H, F], `down` [count, F, H]). The
     router scores every token over ALL experts (`router` [H, E], float32
     sigmoid), takes the `top_k` largest and weighs each chosen expert by
-    `routed_scale * s_e / sum of the chosen s`. The (token, choice) pairs
+    `routed_scale * s_e / sum of the chosen s`. Where `params` holds a
+    `bias` [E] (a buffer, not trained by the loss) the choice is made on
+    `s + bias` and the weights stay the unbiased `s`; with `n_group > 1`
+    it is made inside each token's best `topk_group` groups
+    (`_keep_groups`, their marks from `s + bias` too). One group and no
+    bias is the plain top-k over all experts. The (token, choice) pairs
     whose expert lives here are sorted by expert into one buffer of static
     size, pass through three grouped products (`jax.lax.ragged_dot`, which
     on a TPU is a kernel that skips the tiles no group fills) and are
@@ -150,24 +171,39 @@ def moe_share_apply(params, x, *, top_k: int, experts_held,
     `moe_share_rows` pairs, twice the even share; `dropped` counts the held
     pairs that did not fit and were left out, and a caller that wants the
     layer dropless holds that count to nought (the whole layer's buffer is
-    the worst case and drops nothing). What the absent experts would add is
-    left out: on one chip the layer runs without its exchange, and the
-    shares of all chips add up to the whole layer. `choices[e]` counts the
-    pairs routed to held expert `e`."""
+    the worst case and drops nothing; `rows` sets another size, as a token
+    step does, whose worst case is small). `live` [N] bool names the rows
+    of x that carry a token: the pairs of the others (a decode batch's idle
+    slots) are neither worked on nor counted. What the absent experts would
+    add is left out: on one chip the layer runs without its exchange, and
+    the shares of all chips add up to the whole layer. `choices[e]` counts
+    the pairs routed to held expert `e`."""
     n, _ = x.shape
     first, count = experts_held
     n_experts = params["router"].shape[1]
-    rows = moe_share_rows(n, top_k, n_experts, count)
+    if rows is None:
+        rows = moe_share_rows(n, top_k, n_experts, count)
     dtype = x.dtype
     with jax.named_scope("moe.route"):
         scores = jax.nn.sigmoid(jnp.matmul(
             x.astype(jnp.float32), params["router"].astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-        top_s, top_i = jax.lax.top_k(scores, top_k)            # [N, k]
+        select = scores
+        if "bias" in params:
+            select = scores + params["bias"].astype(jnp.float32)
+        if n_group > 1:
+            select = _keep_groups(select, n_group, topk_group)
+        if select is scores:
+            top_s, top_i = jax.lax.top_k(scores, top_k)        # [N, k]
+        else:
+            _, top_i = jax.lax.top_k(select, top_k)
+            top_s = jnp.take_along_axis(scores, top_i, axis=-1)
         weight = (routed_scale * top_s
                   / jnp.sum(top_s, -1, keepdims=True)).reshape(-1)
         local = top_i - first
         here = (local >= 0) & (local < count)
+        if live is not None:
+            here &= live[:, None]
         # pairs of absent experts sort behind every held one
         group = jnp.where(here, local, count).reshape(-1)       # [N*k]
         pair = jnp.argsort(group, stable=True)[:rows]

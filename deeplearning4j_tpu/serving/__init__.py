@@ -24,6 +24,9 @@ gives the INFERENCE side the same contract under concurrent traffic:
   autoregressive decode over a preallocated paged KV cache — new
   sequences join the in-flight batch at token boundaries, finished
   ones free their slot immediately, zero steady-state recompiles;
+  `LatentDecodeModel` (ISSUE 32) serves a latent-attention causal LM
+  over a paged pool of latents, its experts through
+  `parallel/moe.py:moe_share_apply`;
 - `InferenceSession`: the sync/async facade, instrumented through the
   PR-1 telemetry registry (`dl4j_serving_*`);
 - HTTP: `UIServer.serveModels(session)` exposes
@@ -48,6 +51,7 @@ from deeplearning4j_tpu.serving.buckets import (
     unpad)
 from deeplearning4j_tpu.serving.decode import (
     DecodeEngine, PagedKVCache, RnnDecodeModel, TransformerDecodeModel)
+from deeplearning4j_tpu.serving.latent import LatentDecodeModel
 from deeplearning4j_tpu.serving.prefill import ChunkedPrefill
 from deeplearning4j_tpu.serving.prefix_cache import PrefixCache
 from deeplearning4j_tpu.serving.registry import ModelNotFound, ModelRegistry
@@ -67,7 +71,8 @@ __all__ = [
     "AdmissionController", "BucketLadder", "ChunkedPrefill",
     "DEFAULT_BATCH_BUCKETS",
     "DecodeEngine", "DynamicBatcher", "FnServable", "GraphServable",
-    "InferenceSession", "ModelNotFound", "ModelRegistry",
+    "InferenceSession", "LatentDecodeModel", "ModelNotFound",
+    "ModelRegistry",
     "NetworkServable", "PagedKVCache", "PrefixCache", "QueueFullError",
     "Replica",
     "ReplicaDeath", "ReplicaSet", "RnnDecodeModel", "SameDiffServable",

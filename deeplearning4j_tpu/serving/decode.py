@@ -434,6 +434,51 @@ def live_pages(pos, table, page):
         "dead": dead}
 
 
+def live_page_attention(live, partial, spread, n_heads, o_shape):
+    """Each slot's attention over its own context, from the step's list of
+    live pages (``live_pages``): a loop over chunks of ``LIVE_CHUNK``
+    entries, ``ceil(n_live / LIVE_CHUNK)`` of them, hands ``partial`` a
+    chunk's ``slot``, pool ``page`` and ``last`` seen row (each ``[C]``)
+    and gets back every entry's page reduced ON ITS OWN: its scores'
+    maximum ``m [C, H]`` (-inf where no row is seen), their sum ``l [C,
+    H]`` and the weighted values ``o [C, *o_shape]``, which are written at
+    the entry's place. A slot then combines its entries in its page table's
+    order, dead ones as exact zeros (split-K over pages); ``spread`` lays
+    a per-head ``[..., H]`` out against ``o_shape``. An entry's partial is
+    row-wise math on its own page and the combination runs over a fixed
+    range, so a slot's output depends neither on what its neighbours hold
+    nor on where a chunk's edge falls. What a model brings is the
+    partial: how a row of its pool is scored and what of it is a value.
+    -> ``[S, *o_shape]`` float32."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    N = live["slot"].shape[0]       # whole chunks: ``live_pages``
+    C = min(LIVE_CHUNK, N)
+
+    def body(c, bufs):
+        at = c * C
+        slot, pg, last = (lax.dynamic_slice_in_dim(live[k], at, C)
+                          for k in ("slot", "page", "last"))
+        return tuple(
+            lax.dynamic_update_slice_in_dim(buf, x, at, axis=0)
+            for buf, x in zip(bufs, partial(slot, pg, last)))
+
+    bufs = (jnp.full((N, n_heads), -jnp.inf, jnp.float32),
+            jnp.zeros((N, n_heads), jnp.float32),
+            jnp.zeros((N, *o_shape), jnp.float32))
+    ms, ls, os_ = lax.fori_loop(0, (live["n_live"] + C - 1) // C,
+                                body, bufs)
+    # slot s's entries are offset[s] + i, i = 0..P-1; those past its
+    # last live page weigh exp(-inf) = 0
+    own, dead = live["own"], live["dead"]
+    m_i = jnp.where(dead[:, :, None], -jnp.inf, ms[own])   # [S, P, H]
+    w = jnp.exp(m_i - jnp.max(m_i, axis=1, keepdims=True))
+    l = jnp.sum(ls[own] * w, axis=1)                       # [S, H]
+    o = jnp.sum(os_[own] * spread(w), axis=1)              # [S, *o_shape]
+    return o / spread(l)
+
+
 class TransformerDecodeModel:
     """Causal single-token decode over a paged KV pool.
 
@@ -530,18 +575,10 @@ class TransformerDecodeModel:
 
     def _paged_attention(self, q, kpool, vpool, li, live):
         """q [S, H*D] against each slot's own context in layer ``li``,
-        over the step's list of live pages (``live_pages``): a loop over
-        chunks of ``LIVE_CHUNK`` entries, ``ceil(n_live / LIVE_CHUNK)``
-        of them, gathers each entry's K and V page out of the whole
-        pools (no layer of a pool is ever a value of its own) and
-        reduces the page ON ITS OWN to its scores' maximum ``m [H]``,
-        their sum ``l [H]`` and the weighted values ``o [H*D]``, written
-        at the entry's place. A slot then combines its entries in its
-        page table's order, dead ones as exact zeros (split-K over
-        pages). An entry's partial is row-wise math on its own page and
-        the combination runs over a fixed range, so a slot's output
-        depends neither on what its neighbours hold nor on where a
-        chunk's edge falls.
+        over the step's list of live pages (``live_page_attention``,
+        which holds the loop and the combination). The partial of a
+        chunk gathers each entry's K and V page out of the whole pools
+        (no layer of a pool is ever a value of its own).
 
         A row is scored as it lies, H*D wide: the product with q summed
         over each head's D lanes by a constant 0/1 ``[H*D, H]`` matrix,
@@ -550,22 +587,16 @@ class TransformerDecodeModel:
         block into heads costs the device a relayout: PERF.md, PR 27's
         trace)."""
         import jax.numpy as jnp
-        from jax import lax
 
         hd = q.shape[1]
-        H, page = self.n_heads, self.page
-        N = live["slot"].shape[0]       # whole chunks: ``live_pages``
-        C = min(LIVE_CHUNK, N)
+        H = self.n_heads
         heads = jnp.asarray(np.repeat(np.eye(H), hd // H, axis=0),
                             jnp.bfloat16)                    # [H*D, H]
         lanes = heads.T
         scale = 1.0 / math.sqrt(self.head_dim)
-        rows = jnp.arange(page)
+        rows = jnp.arange(self.page)
 
-        def body(c, bufs):
-            at = c * C
-            slot, pg, last = (lax.dynamic_slice_in_dim(live[k], at, C)
-                              for k in ("slot", "page", "last"))
+        def partial(slot, pg, last):
             kb, vb = kpool[li, pg], vpool[li, pg]       # [C, page, H*D]
             s = _dot_01(kb * q[slot][:, None, :], heads) * scale
             seen = rows[None, :] <= last[:, None]       # causal + length
@@ -573,23 +604,10 @@ class TransformerDecodeModel:
             m = jnp.max(s, axis=1)                      # [C, H]
             p = jnp.exp(s - jnp.where(jnp.isfinite(m), m, 0.0)[:, None])
             o = jnp.sum(_dot_01(p, lanes) * vb, axis=1)  # [C, H*D]
-            return tuple(
-                lax.dynamic_update_slice_in_dim(buf, x, at, axis=0)
-                for buf, x in zip(bufs, (m, jnp.sum(p, axis=1), o)))
+            return m, jnp.sum(p, axis=1), o
 
-        bufs = (jnp.full((N, H), -jnp.inf, jnp.float32),
-                jnp.zeros((N, H), jnp.float32),
-                jnp.zeros((N, hd), jnp.float32))
-        ms, ls, os_ = lax.fori_loop(0, (live["n_live"] + C - 1) // C,
-                                    body, bufs)
-        # slot s's entries are offset[s] + i, i = 0..P-1; those past its
-        # last live page weigh exp(-inf) = 0
-        own, dead = live["own"], live["dead"]
-        m_i = jnp.where(dead[:, :, None], -jnp.inf, ms[own])   # [S, P, H]
-        w = jnp.exp(m_i - jnp.max(m_i, axis=1, keepdims=True))
-        l = jnp.sum(ls[own] * w, axis=1)                       # [S, H]
-        o = jnp.sum(os_[own] * _dot_01(w, lanes), axis=1)      # [S, H*D]
-        return o / _dot_01(l, lanes)
+        return live_page_attention(
+            live, partial, lambda a: _dot_01(a, lanes), H, (hd,))
 
     def _fn(self, params, state, tokens, pos, table):
         import jax.numpy as jnp
@@ -951,8 +969,9 @@ class DecodeEngine:
                                                        PrefixCache)
                             else PrefixCache(self._kv.page))
         # one token step in flight (class docstring): the launch whose
-        # tokens are still on the device, as (nxt, fed, t_b0) with fed =
-        # [(slot, request, position fed)]. The choice between the host's
+        # tokens are still on the device, as (nxt, fed, t_b0, counts) with
+        # fed = [(slot, request, position fed)] and counts what the step
+        # returned beside its tokens, or None. The choice between the host's
         # feed and that launch's tokens exists only where the engine
         # overlaps: with a block executable or a draft it is serial. It
         # goes through the executable store under a key of its own (it
@@ -1098,8 +1117,8 @@ class DecodeEngine:
         # already-contiguous table): admission mutates the table
         # between boundaries, and jax may zero-copy numpy inputs
         table = self._table.copy()
-        nxt, self._state = self._model_step(self._state, tokens, pos,
-                                            table)
+        nxt, self._state, _ = self._model_step(self._state, tokens, pos,
+                                               table)
         if self._pick is not None:
             # the step as a boundary with one in flight calls it: its
             # tokens a device array, chosen where the last ones live
@@ -1108,8 +1127,8 @@ class DecodeEngine:
             compile_ledger.note_step(
                 f"decode:{self.name}:pick", self._pick, (feed, nxt),
                 donation=())
-            _, self._state = self._model_step(self._state, tokens, pos,
-                                              table)
+            _, self._state, _ = self._model_step(self._state, tokens,
+                                                 pos, table)
         if self._block is not None:
             self._state = self._block.warmup(
                 self._state, table, site=f"decode:{self.name}:prefill")
@@ -1389,10 +1408,18 @@ class DecodeEngine:
                 self._finish(req, error=err)
 
     def _model_step(self, state, tokens, pos, table):
-        if self._step_takes_site:
-            return self.model.step(state, tokens, pos, table,
-                                   site=f"decode:{self.name}:step")
-        return self.model.step(state, tokens, pos, table)
+        """(tokens, state, counts) of one launch of the token step. A
+        model whose step routes tokens to experts returns its router's
+        counts beside the tokens: float32 ``[len(model.moe_layers), 5]``,
+        a row (every choice, held choices, dropped, the fullest held
+        expert over the mean, held experts touched: what
+        ``MoeInstruments.step`` takes) a sparse layer over the rows the
+        launch fed, which ``_deliver`` reads with the tokens and
+        publishes; the others return two values and ``counts`` is None."""
+        kw = ({"site": f"decode:{self.name}:step"}
+              if self._step_takes_site else {})
+        out = self.model.step(state, tokens, pos, table, **kw)
+        return out if len(out) == 3 else (*out, None)
 
     def clear_prefix_cache(self):
         """Drop every cached prefix chain (both lanes), releasing the
@@ -1572,8 +1599,8 @@ class DecodeEngine:
                 tokens = feed
                 if (feed < 0).any():
                     tokens = self._pick(feed, prev[0])
-                nxt, self._state = self._model_step(self._state, tokens,
-                                                    pos, table)
+                nxt, self._state, counts = self._model_step(
+                    self._state, tokens, pos, table)
                 if self._spec is not None:
                     # fallback boundaries keep the draft pool in sync so
                     # a later speculation probe proposes from real
@@ -1585,7 +1612,7 @@ class DecodeEngine:
             return
         for _, req, _ in fed:
             req.ptr += 1
-        launch = (nxt, fed, t_b0)
+        launch = (nxt, fed, t_b0, counts)
         if prev is not None and not self._deliver(inst, prev, True):
             return
         if self._pick is not None and any(
@@ -1602,12 +1629,21 @@ class DecodeEngine:
         successor was dispatched before this read. A row whose request
         has ended since the dispatch (its ``eos`` came with the
         boundary before, or it was failed or closed) is discarded:
-        nothing of it is emitted or counted. Returns False when the
-        read raised (every request was failed)."""
-        nxt, fed, t_b0 = launch
+        nothing of it is emitted or counted. The router's counts, where
+        the step returned any, come to the host in the same read as the
+        tokens and go to the ``dl4j_moe_*`` series at the end of `emit`:
+        nothing is read a second time, and nothing on the dispatch side
+        waits for them. Returns False when the read raised (every request
+        was failed)."""
+        nxt, fed, t_b0, counts = launch
         try:
             with _phase(inst, "readback"):
-                nxt = np.asarray(nxt)
+                if counts is None:
+                    nxt = np.asarray(nxt)
+                else:
+                    import jax
+
+                    nxt, counts = jax.device_get((nxt, counts))
         except Exception as e:
             self._fail_boundary(_boundary_error(
                 e, f"decode:{self.name}:step", "decode step failed"))
@@ -1659,6 +1695,8 @@ class DecodeEngine:
                                     np.asarray(positions, np.int32))
                 if overlapped:
                     inst.overlapped.inc()
+                if counts is not None:
+                    inst.moe_step(self.model.moe_layers, counts)
         return True
 
     def _speculative_boundary(self, inst):

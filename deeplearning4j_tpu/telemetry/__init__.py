@@ -81,11 +81,11 @@ from deeplearning4j_tpu.telemetry.health import (
 from deeplearning4j_tpu.telemetry.listener import MetricsListener
 from deeplearning4j_tpu.telemetry.registry import (
     BYTES_BUCKETS, Counter, ETL_HELP, EtlInstruments, FleetInstruments,
-    Gauge, Histogram, LoopInstruments, MetricsRegistry, SECONDS_BUCKETS,
-    STEP_HELP, ServingInstruments, Timer, collect_device_memory, disable,
-    enable, enabled, etl_instruments, fleet_instruments, get_registry,
-    log_buckets, loop_instruments, serving_instruments, set_registry,
-    span)
+    Gauge, Histogram, LoopInstruments, MetricsRegistry, MoeInstruments,
+    SECONDS_BUCKETS, STEP_HELP, ServingInstruments, Timer,
+    collect_device_memory, disable, enable, enabled, etl_instruments,
+    fleet_instruments, get_registry, log_buckets, loop_instruments,
+    moe_instruments, serving_instruments, set_registry, span)
 
 __all__ = [
     "BYTES_BUCKETS", "CapacityError", "Counter", "DeviceOomError",
@@ -93,12 +93,13 @@ __all__ = [
     "EtlInstruments", "FleetInstruments", "FlightRecorder", "Gauge",
     "HealthConfig",
     "HealthMonitor", "Histogram", "LoopInstruments", "MetricsListener",
-    "MetricsRegistry", "SECONDS_BUCKETS", "STEP_HELP",
+    "MetricsRegistry", "MoeInstruments", "SECONDS_BUCKETS", "STEP_HELP",
     "ServingInstruments", "Timer", "aggregate", "aggregate_snapshot",
     "collect_device_memory", "compile_ledger", "costmodel", "disable",
     "enable", "enabled", "etl_instruments", "fleet_instruments",
     "flight", "get_registry",
     "health", "hlo_audit", "log_buckets", "loop_instruments",
-    "memledger", "profiler", "prometheus", "serving_instruments",
+    "memledger", "moe_instruments", "profiler", "prometheus",
+    "serving_instruments",
     "set_registry", "slo", "span", "timeseries", "tracing",
 ]

@@ -569,6 +569,60 @@ DECODE_QUEUE_WAIT_HELP = ("Seconds from decode submit to the boundary "
                           "at which the request took a slot")
 
 
+MOE_CHOICES_HELP = ("Expert choices the sparse layer's router made "
+                    "(tokens x experts per token), by model and layer")
+MOE_HELD_HELP = ("Expert choices that fell on an expert this program "
+                 "holds, by model and layer")
+MOE_DROPPED_HELP = ("Held expert choices that did not fit the expert "
+                    "layer's buffer and were left out, by model and layer: "
+                    "0 while the layer is dropless")
+MOE_LOAD_HELP = ("Sum over steps of the fullest held expert's choices over "
+                 "the held experts' mean; over dl4j_moe_steps_total it is "
+                 "the mean imbalance, by model and layer")
+MOE_TOUCHED_HELP = ("Sum over steps of the held experts at least one "
+                    "choice fell on (the experts whose weights the step's "
+                    "grouped products read), by model and layer")
+MOE_STEPS_HELP = ("Steps whose router counts have been published, by model "
+                  "(a trainer's train steps, a decode engine's token steps)")
+
+
+class MoeInstruments:
+    """The `dl4j_moe_*` series of one publisher, labelled `model`: a
+    trainer's name or a decode engine's. `step(layers, counts)` adds one
+    step's counts, a row (every choice, held choices, dropped, the fullest
+    held expert over the mean, held experts touched) a sparse layer."""
+
+    __slots__ = ("model", "_families", "_steps")
+
+    def __init__(self, registry, model):
+        self.model = model
+        self._families = [
+            registry.counter(name, text, ("model", "layer"))
+            for name, text in (
+                ("dl4j_moe_choices_total", MOE_CHOICES_HELP),
+                ("dl4j_moe_held_choices_total", MOE_HELD_HELP),
+                ("dl4j_moe_dropped_total", MOE_DROPPED_HELP),
+                ("dl4j_moe_load_max_over_mean_sum", MOE_LOAD_HELP),
+                ("dl4j_moe_touched_experts_total", MOE_TOUCHED_HELP))]
+        self._steps = registry.counter(
+            "dl4j_moe_steps_total", MOE_STEPS_HELP,
+            ("model",)).labels(model=model)
+
+    def step(self, layers, counts):
+        for layer, row in zip(layers, counts):
+            for family, value in zip(self._families, row):
+                family.labels(model=self.model,
+                              layer=str(layer)).inc(float(value))
+        self._steps.inc()
+
+
+def moe_instruments(model):
+    """The router-count bundle of one publisher, or None when disabled."""
+    if not _state["enabled"]:
+        return None
+    return MoeInstruments(get_registry(), model)
+
+
 class ServingInstruments:
     """Bound per-model serving instruments (mirrors LoopInstruments:
     obtained once per batcher, None when telemetry is disabled, so a
@@ -580,10 +634,12 @@ class ServingInstruments:
                  "prefix_hits", "prefix_misses", "ttft", "_accepted",
                  "kv_occupancy", "_phases", "_boundaries", "_positions",
                  "kv_fill_sum", "live_pages_sum", "overlapped",
-                 "decode_queue_wait")
+                 "decode_queue_wait", "_registry", "_moe")
 
     def __init__(self, registry, model):
         self.model = model
+        self._registry = registry
+        self._moe = None    # bound by the first token step that routes
         self._requests = registry.counter(
             "dl4j_serving_requests_total", SERVING_REQUESTS_HELP,
             ("model", "outcome"))
@@ -674,6 +730,13 @@ class ServingInstruments:
         and, on the profiler's clock, the span `dl4j.decode.<phase>`."""
         histogram, annotation = self._phases[phase]
         return histogram.time(annotation)
+
+    def moe_step(self, layers, counts):
+        """One token step's router counts (`MoeInstruments.step`), under
+        this model's label."""
+        if self._moe is None:
+            self._moe = MoeInstruments(self._registry, self.model)
+        self._moe.step(layers, counts)
 
     def boundary(self, executable, prompt=0, answer=0):
         """One decode boundary through `executable` that fed `prompt`

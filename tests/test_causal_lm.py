@@ -329,14 +329,18 @@ def test_router_counts_are_published_one_step_behind():
         first = np.asarray(trainer.router_counts[0])
         trainer.train_step(*batch(2))
         snap = telemetry.get_registry().snapshot()
-        assert snap["dl4j_moe_steps_total"] == 1
-        assert snap['dl4j_moe_choices_total{layer="1"}'] == 2 * SEQ * 2
-        assert snap['dl4j_moe_held_choices_total{layer="4"}'] == first[3].sum()
-        assert snap['dl4j_moe_load_max_over_mean_sum{layer="2"}'] == \
+        # the series carry the publisher's name since the decode engine
+        # publishes them too (`model`, the trainer's `name`)
+        at = lambda name, layer: snap[  # noqa: E731
+            f'{name}{{model="causal_lm",layer="{layer}"}}']
+        assert snap['dl4j_moe_steps_total{model="causal_lm"}'] == 1
+        assert at("dl4j_moe_choices_total", 1) == 2 * SEQ * 2
+        assert at("dl4j_moe_held_choices_total", 4) == first[3].sum()
+        assert at("dl4j_moe_load_max_over_mean_sum", 2) == \
             pytest.approx(first[1].max() / first[1].mean())
         trainer.publish_router_counts()
         snap = telemetry.get_registry().snapshot()
-        assert snap["dl4j_moe_steps_total"] == 2
+        assert snap['dl4j_moe_steps_total{model="causal_lm"}'] == 2
         assert sum(v for k, v in snap.items()
                    if k.startswith("dl4j_moe_dropped_total")) == 0
     finally:

@@ -1,0 +1,324 @@
+"""ISSUE 32 tests: a latent-attention causal LM served token by token over a
+paged pool of latents (`serving/latent.py`), its node-limited, biased expert
+selection (`parallel/moe.py:moe_share_apply`), and the router counts the
+decode engine publishes. The program is compared with the plain reference
+`benchmark/reference/deepseek_v3_plain.py` (which imports nothing from the
+program) at a small size on seeded weights: hidden 64, 4 heads, ranks 24 and
+16, rotary 8, 16 experts in 4 groups (2 kept, 4 chosen), a dense and a sparse
+layer."""
+
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmark.drivers import serve_closed_lm as driver  # noqa: E402
+from benchmark.reference import deepseek_v3_plain as plain  # noqa: E402
+from deeplearning4j_tpu import telemetry  # noqa: E402
+from deeplearning4j_tpu.models import causal_lm as lm  # noqa: E402
+from deeplearning4j_tpu.parallel import moe  # noqa: E402
+from deeplearning4j_tpu.serving import (  # noqa: E402
+    DecodeEngine, LatentDecodeModel, PagedKVCache)
+
+PUBLISHED = {
+    "first_k_dense_replace": 1, "hidden_size": 64, "intermediate_size": 96,
+    "kv_lora_rank": 16, "moe_intermediate_size": 32, "n_group": 4,
+    "n_routed_experts": 16, "n_shared_experts": 1, "num_attention_heads": 4,
+    "num_experts_per_tok": 4, "num_hidden_layers": 2,
+    "num_key_value_heads": 4, "q_lora_rank": 24, "qk_nope_head_dim": 16,
+    "qk_rope_head_dim": 8, "rms_norm_eps": 1e-6,
+    "rope_scaling": {"beta_fast": 32, "beta_slow": 1, "factor": 40,
+                     "mscale": 1, "mscale_all_dim": 1,
+                     "original_max_position_embeddings": 16, "type": "yarn"},
+    "rope_theta": 10000, "routed_scaling_factor": 2.5, "topk_group": 2,
+    "topk_method": "noaux_tc", "v_head_dim": 16, "vocab_size": 96}
+WEIGHTS = {"matrix_std": 0.1, "embedding_std": 1.0, "router_bias_std": 0.1}
+# float32 program against the float32 reference: the two sum in other orders
+# and read 9e-7 apart at these sizes. The same program in bfloat16 reads
+# 1.3e-2 and fails it by three orders.
+TOL = 2e-5
+
+
+def config(experts_held=(0, 16), layer_ids=(0, 1)):
+    return {"published": PUBLISHED, "weights": WEIGHTS,
+            "model": {"layer_ids": list(layer_ids),
+                      "layer_kinds": ["dense" if i < 1 else "sparse"
+                                      for i in layer_ids],
+                      "experts_held": list(experts_held),
+                      "vocab_size": PUBLISHED["vocab_size"]}}
+
+
+def model(dtype="float32", seed=1, experts_held=(0, 16), **kw):
+    """(the decode model, the reference's weights and sizes); the values are
+    the reference's, bfloat16-rounded, in both."""
+    cfg = config(experts_held)
+    sizes = driver.reference_sizes(cfg)
+    weights = plain.draw_params(seed, sizes)
+    geometry = dict(max_slots=3, page=4, max_pages_per_slot=6)
+    geometry.update(kw)
+    return LatentDecodeModel(
+        driver.to_program(weights),
+        driver.program_config(cfg, compute_dtype=dtype), dtype=dtype,
+        **geometry), weights, sizes
+
+
+def stepwise_logits(m, tokens):
+    """The token step's logits at every position of one sequence, in slot 1
+    of the pool, a position a launch: prompt, then decode, through the
+    cache."""
+    kv = PagedKVCache(m.n_pages, m.page, m.max_pages_per_slot, m.max_slots)
+    kv.reserve(1, len(tokens))
+    apply = jax.jit(m._apply)
+    state, out = m.init_state(), []
+    for p, tok in enumerate(tokens):
+        feed = np.zeros(m.max_slots, np.int32)
+        pos = np.zeros(m.max_slots, np.int32)
+        table = np.zeros_like(kv.table)
+        feed[1], pos[1], table[1] = tok, p, kv.table[1]
+        pidx = table[np.arange(m.max_slots), pos // m.page]
+        logits, state, _ = apply(m.params, state, feed, pos, table, pidx)
+        out.append(np.asarray(logits[1]))
+    return np.stack(out)
+
+
+TOKENS = [int(t) for t in np.random.default_rng(0).integers(3, 96, 19)]
+
+
+def test_a_prompt_then_decode_through_the_pool_gives_the_references_logits():
+    m, weights, sizes = model()
+    ref = np.asarray(plain.forward_logits(weights, sizes, TOKENS))
+    got = stepwise_logits(m, TOKENS)
+    assert np.abs(got - ref).max() < TOL
+    low = stepwise_logits(model("bfloat16")[0], TOKENS)
+    assert np.abs(low - ref).max() > 100 * TOL
+
+
+def test_the_engine_serves_the_references_best_token():
+    m, weights, sizes = model()
+    eng = DecodeEngine(m, name="latent-ref").warmup()
+    try:
+        prompts = [TOKENS[:7], TOKENS[3:5], TOKENS[6:17]]
+        answers = [r.result(timeout=120.0) for r in
+                   [eng.submit(p, 6) for p in prompts]]
+    finally:
+        eng.close()
+    for prompt, answer in zip(prompts, answers):
+        ref = np.asarray(plain.forward_logits(weights, sizes,
+                                              prompt + answer[:-1]))
+        at = ref[len(prompt) - 1:]
+        gaps = at.max(-1) - at[np.arange(len(answer)), answer]
+        assert gaps.max() < TOL
+
+
+def test_the_absorbed_step_equals_the_expanded_block():
+    m, weights, _ = model()
+    program = jax.tree_util.tree_map(
+        lambda a: a.astype(jnp.float32), driver.to_program(weights))
+    expanded = lm.logits(program, m.cfg, jnp.asarray([TOKENS]))[0]
+    assert np.abs(stepwise_logits(m, TOKENS) - np.asarray(expanded)).max() \
+        < TOL
+
+
+def sparse_layer(seed=3, rows=24):
+    """(the reference's sparse layer holding every expert, its sizes, a
+    batch of normed rows)."""
+    sizes = driver.reference_sizes(config())
+    lp = plain.draw_params(seed, sizes)["layers"][1]
+    u = jax.random.normal(jax.random.key(seed), (rows, 64), jnp.float32)
+    return lp, sizes, u
+
+
+def share_of(lp, first, count):
+    """The program's parameters of the experts first..first+count-1."""
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    return {"router": f32(lp["gate"]),
+            "bias": f32(lp["e_score_correction_bias"]),
+            **{k: f32(lp["experts"][v][first:first + count])
+               for k, v in driver.MLP_NAMES.items()}}
+
+
+def test_the_shares_of_all_chips_add_up_to_the_whole_layer():
+    lp, sizes, u = sparse_layer()
+    whole = plain.sparse_mlp(lp, u, sizes, "f32")
+    shared = plain.gated_mlp(lp["shared_experts"], u, "f32")
+    total, chosen = shared, 0
+    for first in range(0, 16, 4):
+        y, choices, dropped = moe.moe_share_apply(
+            share_of(lp, first, 4), u, top_k=4, experts_held=(first, 4),
+            routed_scale=2.5, n_group=4, topk_group=2, rows=24 * 4)
+        assert int(dropped) == 0
+        total, chosen = total + y, chosen + int(choices.sum())
+    assert chosen == 24 * 4
+    assert np.abs(np.asarray(total - whole)).max() < TOL
+    # node-limited: a row's four experts lie in two of the four groups
+    w = np.asarray(plain.route(lp, u, sizes))
+    groups = (w.reshape(24, 4, 4) > 0).any(-1).sum(-1)
+    assert (groups <= 2).all() and ((w > 0).sum(-1) == 4).all()
+
+
+def test_selection_is_by_biased_scores_and_the_weights_are_unbiased():
+    """sigma = (.6, .5, .4, .1) and b = (0, 0, .15, 0): by sigma + b the two
+    chosen are experts 0 and 2, by sigma alone 0 and 1; the weights are
+    .6 and .4 over their sum, not .6 and .55 over theirs."""
+    logit = lambda p: np.log(p / (1 - p))  # noqa: E731
+    x = jnp.zeros((1, 4)).at[0, 0].set(1.0)
+    router = jnp.zeros((4, 4)).at[0].set(
+        jnp.asarray(logit(np.array([.6, .5, .4, .1]))))
+    # expert e answers e + 1 in every column: silu(g) * up, down = identity
+    rows = jnp.arange(1.0, 5.0)[:, None, None]
+    params = {"router": router, "bias": jnp.asarray([0, 0, .15, 0.]),
+              "gate": jnp.zeros((4, 4, 4)).at[:, 0, :].set(30.0),
+              "up": jnp.zeros((4, 4, 4)).at[:, 0, :].set(1.0) * rows / 30.0,
+              "down": jnp.broadcast_to(jnp.eye(4), (4, 4, 4))}
+    y, choices, _ = moe.moe_share_apply(params, x, top_k=2,
+                                        experts_held=(0, 4))
+    assert choices.tolist() == [1, 0, 1, 0]
+    right = (.6 * 1 + .4 * 3) / (.6 + .4)
+    by_sigma_alone = (.6 * 1 + .5 * 2) / (.6 + .5)
+    biased_weights = (.6 * 1 + .55 * 3) / (.6 + .55)
+    assert float(y[0, 0]) == pytest.approx(right, abs=1e-5)
+    assert abs(float(y[0, 0]) - by_sigma_alone) > 0.1
+    assert abs(float(y[0, 0]) - biased_weights) > 0.1
+
+
+def test_one_group_and_no_bias_is_the_plain_top_k_bit_for_bit():
+    lp, _, u = sparse_layer()
+    share = share_of(lp, 4, 8)
+    plain_share = {k: v for k, v in share.items() if k != "bias"}
+    kw = dict(top_k=4, experts_held=(4, 8), routed_scale=2.5)
+    old = moe.moe_share_apply(plain_share, u, **kw)
+    for params, more in (
+            (plain_share, dict(n_group=1, topk_group=1)),
+            # the new selection where it decides nothing
+            (dict(plain_share, bias=jnp.zeros(16)), {}),
+            (plain_share, dict(n_group=4, topk_group=4))):
+        new = moe.moe_share_apply(params, u, **kw, **more)
+        for a, b in zip(old, new):
+            assert (np.asarray(a) == np.asarray(b)).all()
+    # and the groups and the bias do decide something on these rows
+    other = moe.moe_share_apply(share, u, **kw, n_group=4, topk_group=2)
+    assert (np.asarray(old[1]) != np.asarray(other[1])).any()
+
+
+def test_idle_rows_are_neither_worked_on_nor_counted():
+    lp, _, u = sparse_layer()
+    share = share_of(lp, 0, 16)
+    kw = dict(top_k=4, experts_held=(0, 16), n_group=4, topk_group=2,
+              rows=24 * 4)
+    live = jnp.arange(24) % 3 != 0
+    y, choices, _ = moe.moe_share_apply(share, u, live=live, **kw)
+    full, _, _ = moe.moe_share_apply(share, u, **kw)
+    assert int(choices.sum()) == 16 * 4
+    assert (np.asarray(y)[::3] == 0).all()
+    assert np.abs(np.asarray(y - full))[np.asarray(live)].max() < 1e-5
+
+
+@pytest.fixture(scope="module")
+def served():
+    """One engine over the bfloat16 model as it is served, on a registry of
+    its own: the answers of four requests that ran together, the registry's
+    snapshot, and how many programs were compiled after `warmup()`."""
+    reg = telemetry.MetricsRegistry()
+    prev = telemetry.set_registry(reg)
+    telemetry.enable()
+    m, _, _ = model("bfloat16", experts_held=(0, 8))
+    eng = DecodeEngine(
+        m, name="latent",
+        instruments=telemetry.serving_instruments("latent")).warmup()
+    compiles = reg.counter("dl4j_compile_total")
+    c0 = compiles.value
+    prompts = [TOKENS[:9], TOKENS[2:4], TOKENS[5:16], TOKENS[1:6]]
+    try:
+        together = [r.result(timeout=120.0) for r in
+                    [eng.submit(p, 7) for p in prompts]]
+        alone = eng.submit(prompts[2], 7).result(timeout=120.0)
+        after = compiles.value - c0
+    finally:
+        eng.close()
+        telemetry.set_registry(prev)
+    return {"together": together, "alone": alone, "compiles": after,
+            "snap": reg.snapshot(), "prompts": prompts}
+
+
+def test_a_sequence_decodes_the_same_alone_and_among_strangers(served):
+    assert served["together"][2] == served["alone"]
+    assert len(set(map(tuple, served["together"]))) > 1
+
+
+def test_nothing_compiles_after_warmup_and_nothing_is_dropped(served):
+    assert served["compiles"] == 0
+    assert served["snap"][
+        'dl4j_moe_dropped_total{model="latent",layer="1"}'] == 0
+
+
+def test_the_engine_publishes_the_router_counts_under_its_models_label(
+        served):
+    snap = served["snap"]
+    at = lambda name: snap[f'{name}{{model="latent",layer="1"}}']  # noqa: E731
+    steps = snap['dl4j_moe_steps_total{model="latent"}']
+    step_boundaries = snap[
+        'dl4j_decode_boundaries_total{model="latent",executable="step"}']
+    assert steps == step_boundaries > 0
+    # every position the token step fed chose four experts
+    fed = sum(v for k, v in snap.items()
+              if k.startswith("dl4j_decode_positions_total"))
+    assert at("dl4j_moe_choices_total") == 4 * fed
+    # half the experts are held: some choices fell on them, not all
+    assert 0 < at("dl4j_moe_held_choices_total") < at(
+        "dl4j_moe_choices_total")
+    assert at("dl4j_moe_load_max_over_mean_sum") >= steps
+    assert 0 < at("dl4j_moe_touched_experts_total") <= 8 * steps
+
+
+def test_the_model_answers_what_the_engine_asks_of_a_paged_model():
+    m, _, _ = model("bfloat16")
+    assert m.uses_pages and m.state_donation == (1,)
+    pool = jax.eval_shape(m.init_state)["latent"]
+    assert pool.shape == (2, 3 * 6 + 1, 16 + 8, 4) and pool.dtype == "bfloat16"
+    assert list(m.pool_device_bytes().values()) == [2 * 19 * 4 * 24 * 2]
+    assert m.max_len == 24 and m.vocab == 96 and m.moe_layers == (1,)
+    assert "LatentDecodeModel" in m._store_program()
+    # a model can be built on shapes (an ahead-of-time compile needs no
+    # weights): the step's layout comes out as shapes too
+    shapes = jax.eval_shape(lambda: m.params)
+    on_shapes = LatentDecodeModel(
+        jax.eval_shape(lambda: driver.to_program(plain.draw_params(
+            1, driver.reference_sizes(config())))),
+        m.cfg, max_slots=3, page=4, max_pages_per_slot=6)
+    assert on_shapes.params == shapes
+    with pytest.raises(Exception, match="latent"):
+        LatentDecodeModel({}, lm.CausalLMConfig.from_published(
+            LAGUNA_LIKE), max_slots=2)
+
+
+LAGUNA_LIKE = {
+    "num_hidden_layers": 1, "layer_types": ["full_attention"],
+    "num_attention_heads_per_layer": [2], "mlp_layer_types": ["dense"],
+    "rope_parameters": {
+        "full_attention": {"rope_theta": 10000, "rope_type": "default"},
+        "sliding_attention": {"rope_theta": 10000, "rope_type": "default"}},
+    "vocab_size": 32, "hidden_size": 16, "head_dim": 8,
+    "num_key_value_heads": 1, "sliding_window": 4, "intermediate_size": 32,
+    "moe_intermediate_size": 8, "shared_expert_intermediate_size": 8,
+    "num_experts": 4, "num_experts_per_tok": 2, "rms_norm_eps": 1e-6}
+
+
+def test_chunked_prefill_gives_the_token_steps_answers():
+    """The block executable is a loop of masked token steps: a model behind
+    the protocol serves `chunk` too, and the answers do not change."""
+    prompts = [TOKENS[:11], TOKENS[4:7]]
+    answers = []
+    for options in ({}, {"chunk": 4}):
+        eng = DecodeEngine(model("bfloat16")[0], name="latent-chunk",
+                           **options).warmup()
+        try:
+            answers.append([r.result(timeout=120.0) for r in
+                            [eng.submit(p, 5) for p in prompts]])
+        finally:
+            eng.close()
+    assert answers[0] == answers[1]
