@@ -32,7 +32,8 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, spec_for
-from deeplearning4j_tpu.parallel.moe import moe_share_apply, moe_share_init
+from deeplearning4j_tpu.parallel.moe import (
+    moe_share_apply, moe_share_dense, moe_share_init, moe_share_rows)
 from deeplearning4j_tpu.parallel.step_engine import StepEngine, loss_and_adam
 
 # the splash kernel's tiles: a sequence it runs on is a multiple of this
@@ -591,7 +592,11 @@ class CausalLMTrainer:
         choices, dropped = (np.asarray(a) for a in waiting[0])
         if self._series is None:
             self._series = telemetry.moe_instruments(self.name)
-        every = waiting[1] * self.cfg.top_k
-        self._series.step(self.cfg.sparse_layers, [
+        cfg, n = self.cfg, waiting[1]
+        every = n * cfg.top_k
+        # the form `layer_forward`'s call took at this many tokens
+        dense = moe_share_dense(n, cfg.top_k, moe_share_rows(
+            n, cfg.top_k, cfg.num_experts, cfg.experts_held[1]))
+        self._series.step(cfg.sparse_layers, [
             (every, c.sum(), d, c.max() / max(c.mean(), 1e-9), (c > 0).sum())
-            for c, d in zip(choices, dropped)])
+            for c, d in zip(choices, dropped)], dense)

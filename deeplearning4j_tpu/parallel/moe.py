@@ -9,9 +9,10 @@ tokens to their experts. No custom scheduler, no per-expert kernels —
 the MXU sees E parallel [C, H] x [H, F] matmuls.
 
 Beside it, `moe_share_apply`: the dropless layer of a program that holds a
-share of the experts (sigmoid router over all of them, sort by expert,
-grouped products over the held ones, weighted scatter-add), which
-`models/causal_lm.py` trains.
+share of the experts (sigmoid router over all of them, then the held
+experts' products: sorted by expert into a buffer and grouped, or, for the
+few rows of a token step, one batched product over the held experts), which
+`models/causal_lm.py` trains and `serving/latent.py` serves.
 """
 
 from __future__ import annotations
@@ -121,6 +122,14 @@ def moe_share_init(key, hidden: int, ffn: int, n_experts: int, held: int,
 # 256 experts) the step of PERF.md's cell took 879 ms against 743 (PR 28).
 SHARE_BUFFER = 2.0
 
+# Up to this many rows, a call whose buffer is the worst case runs its
+# products dense (`moe_share_dense`): every row through every held expert.
+# A v5e multiplies 240 FLOPs in the time it streams one byte (197e12 over
+# 819e9): under some 240 rows a bfloat16 expert's weights take longer to
+# arrive than every row takes to cross them, so the rows nobody chose are
+# free, and the sort, the gathers and the buffer's empty tiles are not.
+DENSE_ROWS = 256
+
 
 def moe_share_rows(n_tokens: int, top_k: int, n_experts: int,
                    held: int) -> int:
@@ -146,70 +155,65 @@ def _keep_groups(select, n_group: int, topk_group: int):
     return jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, e)
 
 
-def moe_share_apply(params, x, *, top_k: int, experts_held,
-                    routed_scale: float = 1.0, n_group: int = 1,
-                    topk_group: int = 1, rows: int | None = None,
-                    live=None):
-    """The part of a sigmoid-routed expert layer that the experts held here
-    give. x: [N, H] -> (y [N, H] float32, choices int32 [held], dropped
-    int32 scalar).
+def moe_share_dense(n_tokens: int, top_k: int, rows: int) -> bool:
+    """Whether `moe_share_apply` runs its three products as one batched
+    product over the held experts: the buffer asked for is the worst case
+    (`rows == n_tokens * top_k`: nothing can be dropped, so a dropless
+    product and the buffer give the same result) and the batch is at most
+    `DENSE_ROWS` rows. A function of the call's shapes alone; a publisher
+    of the `dl4j_moe_*` series asks it what its step ran."""
+    return rows == n_tokens * top_k and n_tokens <= DENSE_ROWS
 
-    `experts_held = (first, count)` names the experts whose weights
-    `params` holds (`gate`, `up` [count, H, F], `down` [count, F, H]). The
-    router scores every token over ALL experts (`router` [H, E], float32
-    sigmoid), takes the `top_k` largest and weighs each chosen expert by
-    `routed_scale * s_e / sum of the chosen s`. Where `params` holds a
-    `bias` [E] (a buffer, not trained by the loss) the choice is made on
-    `s + bias` and the weights stay the unbiased `s`; with `n_group > 1`
-    it is made inside each token's best `topk_group` groups
-    (`_keep_groups`, their marks from `s + bias` too). One group and no
-    bias is the plain top-k over all experts. The (token, choice) pairs
-    whose expert lives here are sorted by expert into one buffer of static
-    size, pass through three grouped products (`jax.lax.ragged_dot`, which
-    on a TPU is a kernel that skips the tiles no group fills) and are
-    added, weighted, into their tokens' rows. The buffer holds
-    `moe_share_rows` pairs, twice the even share; `dropped` counts the held
-    pairs that did not fit and were left out, and a caller that wants the
-    layer dropless holds that count to nought (the whole layer's buffer is
-    the worst case and drops nothing; `rows` sets another size, as a token
-    step does, whose worst case is small). `live` [N] bool names the rows
-    of x that carry a token: the pairs of the others (a decode batch's idle
-    slots) are neither worked on nor counted. What the absent experts would
-    add is left out: on one chip the layer runs without its exchange, and
-    the shares of all chips add up to the whole layer. `choices[e]` counts
-    the pairs routed to held expert `e`."""
-    n, _ = x.shape
+
+def _share_route(params, x, top_k, experts_held, routed_scale, n_group,
+                 topk_group, live):
+    """The router of `moe_share_apply`, float32: -> (weight float32 [N * k],
+    what each (token, choice) pair's expert gets of the token's result;
+    group int32 [N * k], the pair's held expert 0..count-1, or `count`
+    where the expert is not held here or the row is not `live`)."""
     first, count = experts_held
-    n_experts = params["router"].shape[1]
-    if rows is None:
-        rows = moe_share_rows(n, top_k, n_experts, count)
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), params["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    select = scores
+    if "bias" in params:
+        select = scores + params["bias"].astype(jnp.float32)
+    if n_group > 1:
+        select = _keep_groups(select, n_group, topk_group)
+    if select is scores:
+        top_s, top_i = jax.lax.top_k(scores, top_k)        # [N, k]
+    else:
+        _, top_i = jax.lax.top_k(select, top_k)
+        top_s = jnp.take_along_axis(scores, top_i, axis=-1)
+    weight = (routed_scale * top_s
+              / jnp.sum(top_s, -1, keepdims=True)).reshape(-1)
+    local = top_i - first
+    here = (local >= 0) & (local < count)
+    if live is not None:
+        here &= live[:, None]
+    # pairs of absent experts sort behind every held one
+    return weight, jnp.where(here, local, count).reshape(-1)
+
+
+def _held_choices(group, count: int):
+    """int32 [count]: the pairs of `group` that fell on each held expert."""
+    return jnp.sum(
+        group[:, None] == jnp.arange(count, dtype=group.dtype)[None],
+        axis=0, dtype=jnp.int32)
+
+
+def _grouped_products(params, x, weight, group, count: int, top_k: int,
+                      rows: int):
+    """The held pairs sorted by expert into one buffer of `rows` rows, three
+    grouped products (`jax.lax.ragged_dot`, which on a TPU is a kernel that
+    skips the tiles no group fills, and whose time follows the buffer's
+    length all the same) and a weighted scatter-add into the tokens' rows.
+    -> (y float32 [N, H], choices, dropped: the held pairs that did not
+    fit the buffer and were left out)."""
     dtype = x.dtype
     with jax.named_scope("moe.route"):
-        scores = jax.nn.sigmoid(jnp.matmul(
-            x.astype(jnp.float32), params["router"].astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST))
-        select = scores
-        if "bias" in params:
-            select = scores + params["bias"].astype(jnp.float32)
-        if n_group > 1:
-            select = _keep_groups(select, n_group, topk_group)
-        if select is scores:
-            top_s, top_i = jax.lax.top_k(scores, top_k)        # [N, k]
-        else:
-            _, top_i = jax.lax.top_k(select, top_k)
-            top_s = jnp.take_along_axis(scores, top_i, axis=-1)
-        weight = (routed_scale * top_s
-                  / jnp.sum(top_s, -1, keepdims=True)).reshape(-1)
-        local = top_i - first
-        here = (local >= 0) & (local < count)
-        if live is not None:
-            here &= live[:, None]
-        # pairs of absent experts sort behind every held one
-        group = jnp.where(here, local, count).reshape(-1)       # [N*k]
         pair = jnp.argsort(group, stable=True)[:rows]
-        choices = jnp.sum(
-            group[:, None] == jnp.arange(count, dtype=group.dtype)[None],
-            axis=0, dtype=jnp.int32)
+        choices = _held_choices(group, count)
         # where each held group ends in the buffer: cut at its end
         ends = jnp.minimum(jnp.cumsum(choices), rows)
         dropped = jnp.sum(choices) - ends[-1]
@@ -232,6 +236,95 @@ def moe_share_apply(params, x, *, top_k: int, experts_held,
         out = dot(mid, params["down"]) * w_rows[:, None]        # f32
         y = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
     return y, choices, dropped
+
+
+def _dense_products(params, x, weight, group, count: int, top_k: int):
+    """Every row through every held expert in one batched product a matrix
+    (no sort, no gather, no scatter-add: a held expert's weights stream no
+    faster than these few rows multiply them), each row's result weighed by
+    the router's weight where the row chose the expert. A row that did not
+    choose an expert, or is not live, is selected to nought, never
+    multiplied: whatever an idle row holds stays in that row's products and
+    reaches no other. Nothing can be dropped.
+    -> (y float32 [N, H], choices, dropped = 0)."""
+    dtype = x.dtype
+    with jax.named_scope("moe.route"):
+        choices = _held_choices(group, count)
+        # [count, N]: a row's k choices are k different experts, so at
+        # most one term of each sum is not nought
+        hit = (group.reshape(-1, top_k)[None]
+               == jnp.arange(count, dtype=group.dtype)[:, None, None])
+        w = jnp.sum(jnp.where(hit, weight.reshape(-1, top_k)[None], 0.0),
+                    axis=-1)
+        chose = jnp.any(hit, axis=-1)
+    with jax.named_scope("moe.experts"):
+        dot = lambda spec, a, b: jnp.einsum(  # noqa: E731
+            spec, a, b.astype(dtype), preferred_element_type=jnp.float32)
+        mid = (jax.nn.silu(dot("sd,edf->esf", x, params["gate"]))
+               * dot("sd,edf->esf", x, params["up"])).astype(dtype)
+        out = dot("esf,efd->esd", mid, params["down"])          # f32
+        y = jnp.sum(jnp.where(chose[:, :, None], out * w[:, :, None], 0.0),
+                    axis=0)
+    return y, choices, jnp.zeros((), jnp.int32)
+
+
+def moe_share_apply(params, x, *, top_k: int, experts_held,
+                    routed_scale: float = 1.0, n_group: int = 1,
+                    topk_group: int = 1, rows: int | None = None,
+                    live=None):
+    """The part of a sigmoid-routed expert layer that the experts held here
+    give. x: [N, H] -> (y [N, H] float32, choices int32 [held], dropped
+    int32 scalar).
+
+    `experts_held = (first, count)` names the experts whose weights
+    `params` holds (`gate`, `up` [count, H, F], `down` [count, F, H]). The
+    router scores every token over ALL experts (`router` [H, E], float32
+    sigmoid), takes the `top_k` largest and weighs each chosen expert by
+    `routed_scale * s_e / sum of the chosen s`. Where `params` holds a
+    `bias` [E] (a buffer, not trained by the loss) the choice is made on
+    `s + bias` and the weights stay the unbiased `s`; with `n_group > 1`
+    it is made inside each token's best `topk_group` groups
+    (`_keep_groups`, their marks from `s + bias` too). One group and no
+    bias is the plain top-k over all experts. `live` [N] bool names the
+    rows of x that carry a token: the pairs of the others (a decode batch's
+    idle slots) are neither counted nor added to any row. `choices[e]`
+    counts the (token, choice) pairs routed to held expert `e`.
+
+    One routing, then the three products of the gated MLP in one of two
+    forms, chosen from the call's shapes (`moe_share_dense`):
+
+    - *grouped* (`_grouped_products`): the held pairs are sorted by expert
+      into one buffer of `rows` rows, pass through three
+      `jax.lax.ragged_dot`s and are added, weighted, into their tokens'
+      rows. `rows` is `moe_share_rows` where the caller names none: twice
+      the even share, as a training step's tens of thousands of tokens
+      want it; `dropped` counts the held pairs that did not fit and were
+      left out, and a caller that wants the layer dropless holds that
+      count to nought.
+    - *dense* (`_dense_products`), where `rows` is the worst case (every
+      choice of every row, `N * top_k`, as a token step asks for so that
+      nothing is ever dropped) and `N <= DENSE_ROWS`: every row goes
+      through every held expert in one batched product a matrix, and a
+      dense `[count, N]` matrix of the router's weights picks what each
+      row keeps. `dropped` is a constant 0. The grouped product's time
+      follows its buffer's length, not its fill, and the worst case is
+      `E / count` times the even fill.
+
+    Both give a chosen (row, expert) pair the same three products of the
+    same operands with float32 accumulation and float32 weighing; the
+    order of the float32 sum over a row's experts differs. What the absent
+    experts would add is left out: on one chip the layer runs without its
+    exchange, and the shares of all chips add up to the whole layer."""
+    n, _ = x.shape
+    _, count = experts_held
+    if rows is None:
+        rows = moe_share_rows(n, top_k, params["router"].shape[1], count)
+    with jax.named_scope("moe.route"):
+        weight, group = _share_route(params, x, top_k, experts_held,
+                                     routed_scale, n_group, topk_group, live)
+    if moe_share_dense(n, top_k, rows):
+        return _dense_products(params, x, weight, group, count, top_k)
+    return _grouped_products(params, x, weight, group, count, top_k, rows)
 
 
 class MoELayerTrainer:

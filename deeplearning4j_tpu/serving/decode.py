@@ -1696,7 +1696,8 @@ class DecodeEngine:
                 if overlapped:
                     inst.overlapped.inc()
                 if counts is not None:
-                    inst.moe_step(self.model.moe_layers, counts)
+                    inst.moe_step(self.model.moe_layers, counts,
+                                  getattr(self.model, "moe_dense", False))
         return True
 
     def _speculative_boundary(self, inst):

@@ -21,10 +21,13 @@ this model brings the page's partial. Greedy argmax over the held rows of
 the vocabulary.
 
 Per-sequence determinism: attention, norms and the dense products are
-row-wise; the expert share is row-wise but for its buffer, where a row's
-place depends on what the other rows chose (on the CPU the grouped product
-gives a row the same bits wherever it lies; tests assert that a sequence
-decodes bit-identically alone and among strangers)."""
+row-wise, and so is the expert share while its products run dense
+(`moe_dense`: every row through every held expert, as at up to
+`parallel/moe.py:DENSE_ROWS` slots). With more slots the share sorts its
+pairs into a buffer, where a row's place depends on what the other rows
+chose (on the CPU the grouped product gives a row the same bits wherever it
+lies). Tests assert that a sequence decodes bit-identically alone and among
+strangers."""
 
 from __future__ import annotations
 
@@ -83,8 +86,10 @@ class LatentDecodeModel:
     activations' and pool's (bfloat16 as served; products accumulate in
     float32; norms, rotary tables, softmax and router scores are float32).
     The step returns, beside the tokens, what each sparse layer's router
-    did with the rows the launch fed (`DecodeEngine._model_step`), and
-    `moe_layers` names those layers."""
+    did with the rows the launch fed (`DecodeEngine._model_step`),
+    `moe_layers` names those layers, and `moe_dense` says whether their
+    expert products run dense at this many slots (`moe_share_dense`: the
+    engine counts such steps in `dl4j_moe_dense_steps_total`)."""
 
     uses_pages = True
     # the pool is donated to every executable over it and written in
@@ -97,6 +102,7 @@ class LatentDecodeModel:
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.models.causal_lm import rope_tables
+        from deeplearning4j_tpu.parallel.moe import moe_share_dense
 
         if any(s.attention != "latent" for s in cfg.layers):
             raise DecodeError("LatentDecodeModel serves latent-attention "
@@ -115,6 +121,9 @@ class LatentDecodeModel:
         self.n_pages = (int(n_pages) if n_pages is not None
                         else max_slots * max_pages_per_slot)
         self.moe_layers = tuple(cfg.sparse_layers)
+        # the step asks its expert share for `max_slots * top_k` rows
+        self.moe_dense = bool(self.moe_layers) and moe_share_dense(
+            self.max_slots, cfg.top_k, self.max_slots * cfg.top_k)
         # rotary rows of every position a slot can reach, float32
         self._cos, self._sin = rope_tables(cfg.rope["latent"], cfg.rope_dim,
                                            self.max_len)
@@ -271,8 +280,10 @@ class LatentDecodeModel:
                 with jax.named_scope("mlp.dense"):
                     out = gated_mlp(lp["mlp"], u)
             else:
-                # the buffer holds every choice of every row: a token
-                # step's worst case is small, and nothing is dropped
+                # `rows` is every choice of every row, so nothing is ever
+                # dropped; at up to `moe.DENSE_ROWS` slots that makes the
+                # products one batched product over the held experts, no
+                # buffer at all (`self.moe_dense`)
                 routed, choices, dropped = moe_share_apply(
                     lp["moe"], u, top_k=cfg.top_k,
                     experts_held=cfg.experts_held,

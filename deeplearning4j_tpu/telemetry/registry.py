@@ -580,19 +580,26 @@ MOE_LOAD_HELP = ("Sum over steps of the fullest held expert's choices over "
                  "the held experts' mean; over dl4j_moe_steps_total it is "
                  "the mean imbalance, by model and layer")
 MOE_TOUCHED_HELP = ("Sum over steps of the held experts at least one "
-                    "choice fell on (the experts whose weights the step's "
-                    "grouped products read), by model and layer")
+                    "choice fell on (the experts whose weights a grouped "
+                    "step's products read; a dense step reads every held "
+                    "expert's), by model and layer")
 MOE_STEPS_HELP = ("Steps whose router counts have been published, by model "
                   "(a trainer's train steps, a decode engine's token steps)")
+MOE_DENSE_HELP = ("Of dl4j_moe_steps_total, the steps whose expert products "
+                  "ran as one batched product over the held experts (the "
+                  "worst-case buffer of a small batch: nothing can be "
+                  "dropped), by model")
 
 
 class MoeInstruments:
     """The `dl4j_moe_*` series of one publisher, labelled `model`: a
     trainer's name or a decode engine's. `step(layers, counts)` adds one
     step's counts, a row (every choice, held choices, dropped, the fullest
-    held expert over the mean, held experts touched) a sparse layer."""
+    held expert over the mean, held experts touched) a sparse layer;
+    `dense` says that the step's expert products ran dense
+    (`parallel/moe.py:moe_share_dense`, which the publisher asks)."""
 
-    __slots__ = ("model", "_families", "_steps")
+    __slots__ = ("model", "_families", "_steps", "_dense")
 
     def __init__(self, registry, model):
         self.model = model
@@ -607,13 +614,18 @@ class MoeInstruments:
         self._steps = registry.counter(
             "dl4j_moe_steps_total", MOE_STEPS_HELP,
             ("model",)).labels(model=model)
+        self._dense = registry.counter(
+            "dl4j_moe_dense_steps_total", MOE_DENSE_HELP,
+            ("model",)).labels(model=model)
 
-    def step(self, layers, counts):
+    def step(self, layers, counts, dense=False):
         for layer, row in zip(layers, counts):
             for family, value in zip(self._families, row):
                 family.labels(model=self.model,
                               layer=str(layer)).inc(float(value))
         self._steps.inc()
+        if dense:
+            self._dense.inc()
 
 
 def moe_instruments(model):
@@ -731,12 +743,12 @@ class ServingInstruments:
         histogram, annotation = self._phases[phase]
         return histogram.time(annotation)
 
-    def moe_step(self, layers, counts):
+    def moe_step(self, layers, counts, dense=False):
         """One token step's router counts (`MoeInstruments.step`), under
         this model's label."""
         if self._moe is None:
             self._moe = MoeInstruments(self._registry, self.model)
-        self._moe.step(layers, counts)
+        self._moe.step(layers, counts, dense)
 
     def boundary(self, executable, prompt=0, answer=0):
         """One decode boundary through `executable` that fed `prompt`

@@ -128,19 +128,36 @@ def test_the_rate_warms_up():
         assert np.median(step) == pytest.approx(moved, rel=1e-3)
 
 
-@pytest.mark.parametrize("dtype, tol, change_tol, warmup", [
-    ("float32", 2e-4, 0.05, 0), ("bfloat16", 0.1, 0.5, 0),
-    ("float32", 2e-4, 0.05, 4)])
+def share_is_dense(held, n=2 * SEQ):
+    """Whether a sparse layer over `n` tokens (top-2 of 8 experts, `held`
+    here) runs its products dense: half the experts held make the default
+    buffer, twice the even share, the worst case."""
+    return moe.moe_share_dense(n, 2, moe.moe_share_rows(n, 2, 8, held[1]))
+
+
+# (0, 4): the 64 tokens' share is dense; (0, 2): sorted into a buffer of 64
+# rows and grouped, as every step of a real size is. `draw` seeds the
+# weights: with two small experts held, one choice that bfloat16's rounding
+# flips (draw 3 flips one of 141 in the first step) moves a sixteenth of an
+# expert's tokens and its gradient by 0.2 to 0.5 of its largest entry, in
+# either form; draw 4 flips none in three steps and reads 0.014.
+@pytest.mark.parametrize("dtype, tol, change_tol, warmup, held, draw", [
+    ("float32", 2e-4, 0.05, 0, (0, 4), 3),
+    ("bfloat16", 0.1, 0.5, 0, (0, 4), 3),
+    ("float32", 2e-4, 0.05, 4, (0, 4), 3),
+    ("float32", 2e-4, 0.05, 0, (0, 2), 3),
+    ("bfloat16", 0.1, 0.5, 0, (0, 2), 4)])
 def test_first_gradient_and_three_adam_steps_match(dtype, tol, change_tol,
-                                                   warmup):
+                                                   warmup, held, draw):
     """From the reference's own weights, loaded into the trainer."""
-    cfg = config(dtype=dtype)
+    assert share_is_dense(held) == (held == (0, 4))
+    cfg = config(held, dtype=dtype)
     mesh = MeshConfig(data=1, devices=jax.devices()[:1]).build()
-    p0 = plain.draw_params(sizes(), 3)
+    p0 = plain.draw_params(sizes(held), draw)
     trainer = lm.CausalLMTrainer(cfg, mesh, lr=1e-3,
                                  params=train_lm.to_program(p0),
                                  warmup_steps=warmup)
-    ref = plain.LmReference(sizes(), p0, 1e-3, block_q=8,
+    ref = plain.LmReference(sizes(held), p0, 1e-3, block_q=8,
                             warmup_steps=warmup)
     batches = train_lm.traffic_lm.lm_batches({"rows": 2, "seq": SEQ}, 64, 5)
     for i in range(3):
@@ -204,11 +221,16 @@ def test_the_shares_add_up_to_the_uncut_layer(count):
     assert close(total, want, 2e-5)
 
 
-def test_nothing_is_dropped_when_every_token_chooses_one_expert():
+@pytest.mark.parametrize("n", [2 * SEQ, 320])
+def test_nothing_is_dropped_when_every_token_chooses_one_expert(n):
+    """Four of eight experts held: the buffer is the worst case. 64 tokens
+    go through every held expert; 320 are past `DENSE_ROWS` and are sorted
+    into the buffer, which takes the two full groups."""
+    assert share_is_dense((0, 4), n) == (n == 2 * SEQ)
     whole, _, ref_lp = _whole_layer()
     # a router that sends every token to expert 1 first (and 0 second)
     router = jnp.zeros((64, 8)).at[:, 1].set(1.0).at[:, 0].set(0.5)
-    u = jnp.abs(_layer_input())
+    u = jnp.abs(_layer_input(n=n))
     share = {"router": router, **{k: whole[k][:4]
                                   for k in ("gate", "up", "down")}}
     y, choices, dropped = moe.moe_share_apply(
@@ -243,6 +265,42 @@ def test_a_buffer_that_falls_short_counts_what_it_left_out():
     want, _, none = moe.moe_share_apply(
         quiet, u, top_k=2, experts_held=(0, 4), routed_scale=2.5)
     assert int(none) == 0 and close(y, want, 1e-6)
+
+
+@pytest.mark.parametrize("n, top_k, rows, dense", [
+    (128, 8, 1024, True),           # PR 32's token step: 128 slots
+    (256, 8, 2048, True),           # the last batch that is dense
+    (257, 8, 2056, False),          # the worst case of a larger batch
+    (16384, 8, 32768, False),       # Laguna's step: a quarter of its worst
+    (128, 8, 1016, False), (1, 8, 7, False),    # short of the worst case
+    (128, 8, 1032, False)])
+def test_the_products_form_follows_the_calls_shapes(n, top_k, rows, dense):
+    assert moe.moe_share_dense(n, top_k, rows) == dense
+
+
+def test_every_real_training_call_is_grouped():
+    assert moe.moe_share_rows(16384, 8, 256, 32) == 32768 < 16384 * 8
+    assert not moe.moe_share_dense(16384, 8, 32768)
+    # DeepSeek-V3's share of 16 in 256 trained at any size: an eighth
+    assert moe.moe_share_rows(4096, 8, 256, 16) == 4096
+
+
+@pytest.mark.parametrize("held", [(0, 2), (0, 4)])
+def test_a_train_step_keeps_the_grouped_products_short_of_the_worst_case(
+        held):
+    """The trainer's jitted step, lowered for the TPU (where a grouped
+    product is an operation of its own; nothing is compiled): a share that
+    asks for less than every choice of every token still sorts and groups,
+    forward and backward; half the experts held at 64 tokens is the worst
+    case and lowers to none."""
+    mesh = MeshConfig(data=1, devices=jax.devices()[:1]).build()
+    trainer = lm.CausalLMTrainer(config(held), mesh, seed=0)
+    tok, lab = batch()
+    text = jax.jit(trainer._step_math).trace(
+        trainer.params, trainer.opt, tok, lab, jnp.int32(0)).lower(
+            lowering_platforms=("tpu",)).as_text()
+    assert ("ragged_dot" in text) == (not share_is_dense(held))
+    assert share_is_dense(held) == (held == (0, 4))
 
 
 def test_rotary_tables_against_the_closed_form():
@@ -314,13 +372,14 @@ def test_grouped_heads_read_their_own_kv_head():
         assert close(out[:, :, h], one[:, :, 0], 1e-5)
 
 
-def test_router_counts_are_published_one_step_behind():
+@pytest.mark.parametrize("held", [(0, 4), (0, 2)])
+def test_router_counts_are_published_one_step_behind(held):
     from deeplearning4j_tpu import telemetry
 
     old = telemetry.get_registry()
     telemetry.set_registry(telemetry.MetricsRegistry())
     try:
-        cfg = config()
+        cfg = config(held)
         mesh = MeshConfig(data=1, devices=jax.devices()[:1]).build()
         trainer = lm.CausalLMTrainer(cfg, mesh, seed=0)
         trainer.train_step(*batch(1))
@@ -343,6 +402,10 @@ def test_router_counts_are_published_one_step_behind():
         assert snap['dl4j_moe_steps_total{model="causal_lm"}'] == 2
         assert sum(v for k, v in snap.items()
                    if k.startswith("dl4j_moe_dropped_total")) == 0
+        # a trainer's steps are grouped (0) but at a toy size whose buffer
+        # is the worst case: the series says which form the steps took
+        assert snap['dl4j_moe_dense_steps_total{model="causal_lm"}'] == (
+            2 if share_is_dense(held) else 0)
     finally:
         telemetry.set_registry(old)
 

@@ -142,7 +142,17 @@ def share_of(lp, first, count):
                for k, v in driver.MLP_NAMES.items()}}
 
 
-def test_the_shares_of_all_chips_add_up_to_the_whole_layer():
+# `rows` of the calls below on 24 rows that choose 4 experts each: the worst
+# case (`moe.moe_share_dense`: one batched product over the held experts), or
+# a shorter buffer that still takes every held choice of these rows (sorted,
+# three `ragged_dot`s)
+BOTH_FORMS = pytest.mark.parametrize("rows", [
+    pytest.param(96, id="dense"), pytest.param(80, id="grouped")])
+
+
+@BOTH_FORMS
+def test_the_shares_of_all_chips_add_up_to_the_whole_layer(rows):
+    assert moe.moe_share_dense(24, 4, rows) == (rows == 96)
     lp, sizes, u = sparse_layer()
     whole = plain.sparse_mlp(lp, u, sizes, "f32")
     shared = plain.gated_mlp(lp["shared_experts"], u, "f32")
@@ -150,7 +160,7 @@ def test_the_shares_of_all_chips_add_up_to_the_whole_layer():
     for first in range(0, 16, 4):
         y, choices, dropped = moe.moe_share_apply(
             share_of(lp, first, 4), u, top_k=4, experts_held=(first, 4),
-            routed_scale=2.5, n_group=4, topk_group=2, rows=24 * 4)
+            routed_scale=2.5, n_group=4, topk_group=2, rows=rows)
         assert int(dropped) == 0
         total, chosen = total + y, chosen + int(choices.sum())
     assert chosen == 24 * 4
@@ -186,11 +196,12 @@ def test_selection_is_by_biased_scores_and_the_weights_are_unbiased():
     assert abs(float(y[0, 0]) - biased_weights) > 0.1
 
 
-def test_one_group_and_no_bias_is_the_plain_top_k_bit_for_bit():
+@BOTH_FORMS
+def test_one_group_and_no_bias_is_the_plain_top_k_bit_for_bit(rows):
     lp, _, u = sparse_layer()
     share = share_of(lp, 4, 8)
     plain_share = {k: v for k, v in share.items() if k != "bias"}
-    kw = dict(top_k=4, experts_held=(4, 8), routed_scale=2.5)
+    kw = dict(top_k=4, experts_held=(4, 8), routed_scale=2.5, rows=rows)
     old = moe.moe_share_apply(plain_share, u, **kw)
     for params, more in (
             (plain_share, dict(n_group=1, topk_group=1)),
@@ -205,17 +216,68 @@ def test_one_group_and_no_bias_is_the_plain_top_k_bit_for_bit():
     assert (np.asarray(old[1]) != np.asarray(other[1])).any()
 
 
-def test_idle_rows_are_neither_worked_on_nor_counted():
+@BOTH_FORMS
+def test_idle_rows_are_neither_worked_on_nor_counted(rows):
+    lp, _, u = sparse_layer()
+    share = share_of(lp, 0, 16)
+    kw = dict(top_k=4, experts_held=(0, 16), n_group=4, topk_group=2)
+    live = jnp.arange(24) % 3 != 0
+    y, choices, dropped = moe.moe_share_apply(share, u, live=live, rows=rows,
+                                              **kw)
+    full, _, _ = moe.moe_share_apply(share, u, rows=96, **kw)
+    assert int(choices.sum()) == 16 * 4 and int(dropped) == 0
+    assert (np.asarray(y)[::3] == 0).all()
+    assert np.abs(np.asarray(y - full))[np.asarray(live)].max() < 1e-5
+
+
+@BOTH_FORMS
+def test_what_an_idle_row_holds_reaches_no_live_row(rows):
+    """A slot that is not fed keeps whatever its last request left in its
+    row: NaN and inf there leave every live row's result bit for bit what
+    it is beside idle rows of nought, and the idle rows' own result nought."""
     lp, _, u = sparse_layer()
     share = share_of(lp, 0, 16)
     kw = dict(top_k=4, experts_held=(0, 16), n_group=4, topk_group=2,
-              rows=24 * 4)
+              rows=rows)
     live = jnp.arange(24) % 3 != 0
-    y, choices, _ = moe.moe_share_apply(share, u, live=live, **kw)
-    full, _, _ = moe.moe_share_apply(share, u, **kw)
-    assert int(choices.sum()) == 16 * 4
-    assert (np.asarray(y)[::3] == 0).all()
-    assert np.abs(np.asarray(y - full))[np.asarray(live)].max() < 1e-5
+    stale = jnp.where(jnp.arange(24) % 2 == 0, jnp.nan, jnp.inf)[:, None]
+    for dtype in ("float32", "bfloat16"):
+        x = u.astype(dtype)
+        clean = moe.moe_share_apply(
+            share, jnp.where(live[:, None], x, 0), live=live, **kw)
+        dirty = moe.moe_share_apply(
+            share, jnp.where(live[:, None], x, stale.astype(dtype)),
+            live=live, **kw)
+        for a, b in zip(clean, dirty):
+            assert (np.asarray(a) == np.asarray(b)).all()
+        assert (np.asarray(dirty[0])[::3] == 0).all()
+        assert np.abs(np.asarray(dirty[0])).max() > 0.1
+
+
+# Dense against grouped: the same products of the same operands, summed in
+# another order. float32: the two read 2.5e-7 to 3.6e-7 of the largest
+# entry apart (eight seeds, with and without idle rows); 2e-6 is five times
+# that. bfloat16 operands: 1.1e-7 on the CPU, whose products of bfloat16
+# are exact in float32; where an accumulation's order flips the rounding of
+# one of a row's 32 `mid` entries to bfloat16 (2^-9 of it), the row moves by
+# some 2^-9 / sqrt(32) = 3.5e-4 of itself: 1e-3.
+@pytest.mark.parametrize("with_idle", [False, True])
+@pytest.mark.parametrize("dtype, tol", [("float32", 2e-6),
+                                        ("bfloat16", 1e-3)])
+def test_dense_and_grouped_products_of_one_routing_agree(dtype, tol,
+                                                         with_idle):
+    lp, _, u = sparse_layer(seed=5)
+    share, x = share_of(lp, 0, 16), u.astype(dtype)
+    live = (jnp.arange(24) % 3 != 0) if with_idle else None
+    weight, group = moe._share_route(share, x, 4, (0, 16), 2.5, 4, 2, live)
+    dense = moe._dense_products(share, x, weight, group, 16, 4)
+    grouped = moe._grouped_products(share, x, weight, group, 16, 4, 96)
+    assert dense[0].dtype == grouped[0].dtype == jnp.float32
+    gap = np.abs(np.asarray(dense[0] - grouped[0])).max()
+    assert gap <= tol * np.abs(np.asarray(grouped[0])).max()
+    assert (np.asarray(dense[1]) == np.asarray(grouped[1])).all()
+    assert int(dense[1].sum()) == (16 if with_idle else 24) * 4
+    assert int(dense[2]) == 0 == int(grouped[2])
 
 
 @pytest.fixture(scope="module")
@@ -273,6 +335,33 @@ def test_the_engine_publishes_the_router_counts_under_its_models_label(
         "dl4j_moe_choices_total")
     assert at("dl4j_moe_load_max_over_mean_sum") >= steps
     assert 0 < at("dl4j_moe_touched_experts_total") <= 8 * steps
+    # three slots: every step's expert products ran dense
+    assert snap['dl4j_moe_dense_steps_total{model="latent"}'] == steps
+
+
+@pytest.mark.parametrize("slots, dense", [(3, True), (256, True),
+                                          (257, False)])
+def test_the_token_step_sorts_nothing_up_to_dense_rows_slots(slots, dense):
+    """The step asks its share for every choice of every slot: up to
+    `moe.DENSE_ROWS` slots neither the token step nor the masked step of a
+    block prefill lowers to a grouped product, past them both do."""
+    assert moe.DENSE_ROWS == 256
+    m, _, _ = model("bfloat16", max_slots=slots, page=4,
+                    max_pages_per_slot=1)
+    assert m.moe_dense == dense
+    state = jax.eval_shape(m.init_state)
+    ints = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    table = jax.ShapeDtypeStruct((slots, 1), jnp.int32)
+    args = (jax.eval_shape(lambda: m.params), state, ints, ints, table)
+    # lowered for the TPU (no chip needed: nothing is compiled), where a
+    # grouped product is an operation of its own; the CPU's lowering
+    # spells it out in plain products
+    text = lambda fn, *more: jax.jit(fn).trace(*args, *more).lower(  # noqa: E731
+        lowering_platforms=("tpu",)).as_text()
+    step = text(m._fn)
+    masked = text(m.masked_fn, jax.ShapeDtypeStruct((slots,), jnp.bool_))
+    assert ("ragged_dot" in step) == (not dense)
+    assert ("ragged_dot" in masked) == (not dense)
 
 
 def test_the_model_answers_what_the_engine_asks_of_a_paged_model():
