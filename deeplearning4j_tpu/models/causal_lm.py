@@ -4,14 +4,20 @@ One pre-norm block, `h = x + Attn_l(RMSNorm(x))`, `y = h + MLP_l(RMSNorm(h))`,
 where each layer says for itself which attention it has (`full` or
 `sliding`: causal, a sliding layer also masks `i - j >= sliding_window`;
 `latent`: causal, queries and keys and values through low-rank latents, one
-rotary key shared by all heads), how many query heads (grouped over
-`kv_heads` K and V heads), which rotary parameters (partial rotary, YaRN or
-plain) and which MLP (`dense`, a gated MLP; `sparse`, a sigmoid-routed
-expert layer plus one shared expert, chosen over all experts or inside the
-best groups on biased scores). The configuration is built from a published
-`config.json`'s own keys (`layer_types`, `num_attention_heads_per_layer`,
-`mlp_layer_types`, `rope_parameters`, ...; `from_latent_published` reads the
-keys of a latent-attention config), cut to the chip's share of a deployment:
+rotary key shared by all heads; `mamba`: a Mamba-2 selective state-space
+mixer, a convolution tail and a float32 state a sequence in place of keys
+and values), how many query heads (grouped over `kv_heads` K and V heads),
+which rotary parameters (partial rotary, YaRN, plain, or none) and which MLP
+(`dense`, a gated MLP; `sparse`, a sigmoid-routed expert layer plus one
+shared expert, chosen over all experts or inside the best groups on biased
+scores; the experts gated silu MLPs on the model's width or ungated relu²
+MLPs in a latent space). Either half may be `none`: a hybrid model's layer
+is one mixer alone, `x + mixer(RMSNorm(x))`. The configuration is built from
+a published `config.json`'s own keys (`layer_types`,
+`num_attention_heads_per_layer`, `mlp_layer_types`, `rope_parameters`, ...;
+`from_latent_published` reads the keys of a latent-attention config,
+`from_hybrid_published` a `hybrid_override_pattern`), cut to the chip's
+share of a deployment:
 `experts_held = (first, count)` of each sparse layer's experts and
 `vocab_held` rows of embedding and head (`parallel/moe.py:moe_share_apply`).
 
@@ -19,7 +25,8 @@ bfloat16 activations and matmul operands with float32 accumulation; norms,
 rotary tables, router scores and the loss in float32; float32 parameters;
 each layer under `jax.checkpoint`. `CausalLMTrainer` trains it through the
 step engine that `BertTrainer` uses; `serving/latent.py` decodes the same
-block description, token by token, over a paged pool of latents."""
+block description, token by token, over a paged pool of latents, and
+`serving/hybrid.py` a hybrid one over K/V pages and a state a slot."""
 
 from __future__ import annotations
 
@@ -33,7 +40,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, spec_for
 from deeplearning4j_tpu.parallel.moe import (
-    moe_share_apply, moe_share_dense, moe_share_init, moe_share_rows)
+    ACTIVATIONS, expert_mid, moe_share_apply, moe_share_dense,
+    moe_share_init, moe_share_rows)
 from deeplearning4j_tpu.parallel.step_engine import StepEngine, loss_and_adam
 
 # the splash kernel's tiles: a sequence it runs on is a multiple of this
@@ -46,9 +54,9 @@ INIT_STD = 0.02
 
 @dataclass(frozen=True)
 class LayerSpec:
-    attention: str          # "full" | "sliding" | "latent"
-    heads: int              # query heads of this layer
-    mlp: str                # "dense" | "sparse"
+    attention: str          # "full" | "sliding" | "latent" | "mamba" | "none"
+    heads: int              # query (or state-space) heads of this layer
+    mlp: str                # "dense" | "sparse" | "none"
 
 
 @dataclass(frozen=True)
@@ -83,6 +91,69 @@ class CausalLMConfig:
     rope_dim: int = 0
     v_dim: int = 0
     latent_scale: float = 1.0
+    # a `full` or `sliding` layer's sigmoid output gate, one value a head
+    attn_gate: bool = True
+    # the experts' form: gated or not, their activation, and the width of
+    # the latent space they work in (0: the model's own width)
+    expert_gated: bool = True
+    expert_act: str = "silu"
+    expert_latent: int = 0
+    # a `mamba` layer: each head's width, the groups that share B and C, the
+    # state's width a head and group, the causal convolution's taps, and
+    # (min, max, floor) of the time step the initial `dt_bias` is drawn for
+    mamba_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    conv_kernel: int = 0
+    time_step: tuple = (0.001, 0.1, 1e-4)
+
+    @classmethod
+    def from_hybrid_published(cls, published: dict, layer_ids=None,
+                              experts_held=None, vocab_held=None, **kw):
+        """From the keys of a published hybrid state-space config.json
+        (`hybrid_override_pattern`, `mamba_num_heads`, `mamba_head_dim`,
+        `n_groups`, `ssm_state_size`, `conv_kernel`, `moe_latent_size`,
+        `mlp_hidden_act`, ...). A letter of the pattern a layer, each ONE
+        mixer: `M` Mamba-2, `*` attention (grouped heads, no rotary
+        embedding: the state-space layers carry position), `E` latent
+        experts with one shared expert on the full width. `layer_ids` are
+        the published layers that run here, all by default."""
+        pattern = published["hybrid_override_pattern"]
+        ids = range(len(pattern)) if layer_ids is None else layer_ids
+        kinds = {
+            "M": LayerSpec("mamba", published["mamba_num_heads"], "none"),
+            "*": LayerSpec("full", published["num_attention_heads"], "none"),
+            "E": LayerSpec("none", 0, "sparse")}
+        if any(pattern[i] not in kinds for i in ids):
+            raise ValueError(
+                f"layers {sorted(set(pattern) - set(kinds))} of the pattern "
+                f"are not written here (M, * and E are)")
+        return cls(
+            layers=tuple(kinds[pattern[i]] for i in ids), rope={},
+            vocab_held=vocab_held or published["vocab_size"],
+            hidden=published["hidden_size"], head_dim=published["head_dim"],
+            kv_heads=published["num_key_value_heads"], sliding_window=0,
+            dense_ffn=published["intermediate_size"],
+            expert_ffn=published["moe_intermediate_size"],
+            shared_ffn=(published["n_shared_experts"]
+                        * published["moe_shared_expert_intermediate_size"]),
+            num_experts=published["n_routed_experts"],
+            top_k=published["num_experts_per_tok"],
+            routed_scale=published["routed_scaling_factor"],
+            experts_held=tuple(experts_held
+                               or (0, published["n_routed_experts"])),
+            rms_eps=published["norm_eps"], n_group=published["n_group"],
+            topk_group=published["topk_group"], router_bias=True,
+            attn_gate=False, expert_gated=False,
+            expert_act=published["mlp_hidden_act"],
+            expert_latent=published["moe_latent_size"],
+            mamba_head_dim=published["mamba_head_dim"],
+            ssm_groups=published["n_groups"],
+            ssm_state=published["ssm_state_size"],
+            conv_kernel=published["conv_kernel"],
+            time_step=(published["time_step_min"],
+                       published["time_step_max"],
+                       published["time_step_floor"]), **kw)
 
     @classmethod
     def from_latent_published(cls, published: dict, layer_ids=None,
@@ -173,6 +244,17 @@ class CausalLMConfig:
     def sparse_layers(self):
         return [i for i, s in enumerate(self.layers) if s.mlp == "sparse"]
 
+    @property
+    def mamba_inner(self):
+        """Width of a Mamba layer's inner stream: heads x their width."""
+        heads = next(s.heads for s in self.layers if s.attention == "mamba")
+        return heads * self.mamba_head_dim
+
+    @property
+    def conv_width(self):
+        """Channels of a Mamba layer's convolution: x beside B and C."""
+        return self.mamba_inner + 2 * self.ssm_groups * self.ssm_state
+
     def rotary_width(self, kind):
         """The width `rope_tables` is asked for: a latent layer rotates the
         shared key's `rope_dim`, the others (part of) a head."""
@@ -185,11 +267,38 @@ def _normal(key, shape, std):
     return jax.random.normal(key, shape, jnp.float32) * std
 
 
-def _gated_mlp_init(key, hidden, ffn, std):
+def _mlp_init(key, hidden, ffn, std, gated=True):
     k = jax.random.split(key, 3)
-    return {"gate": _normal(k[0], (hidden, ffn), std),
-            "up": _normal(k[1], (hidden, ffn), std),
-            "down": _normal(k[2], (ffn, hidden), std)}
+    out = {"up": _normal(k[1], (hidden, ffn), std),
+           "down": _normal(k[2], (ffn, hidden), std)}
+    if gated:
+        out["gate"] = _normal(k[0], (hidden, ffn), std)
+    return out
+
+
+def _mamba_init(cfg, heads, key, std):
+    """A Mamba-2 mixer's leaves: `A_log = ln U(1, 16)`, `dt_bias` the
+    inverse softplus of a time step drawn log-uniformly in `cfg.time_step`'s
+    range and floored, `D` and the gains 1, the convolution's taps and bias
+    uniform in +-conv_kernel^-0.5 (a depthwise convolution's usual
+    initialiser: at `std` B and C would be so small that the state's part
+    of the output vanished beside `D x`)."""
+    k = jax.random.split(key, 6)
+    inner, width = heads * cfg.mamba_head_dim, cfg.conv_width
+    lo, hi, floor = cfg.time_step
+    taps = cfg.conv_kernel ** -0.5
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(k[3], (heads,)) * (
+        math.log(hi) - math.log(lo)) + math.log(lo)), floor)
+    return {"w_in": _normal(k[0], (cfg.hidden, inner + width + heads), std),
+            "conv_w": jax.random.uniform(k[1], (cfg.conv_kernel, width),
+                                         jnp.float32, -taps, taps),
+            "conv_b": jax.random.uniform(k[5], (width,), jnp.float32,
+                                         -taps, taps),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(jax.random.uniform(
+                k[4], (heads,), minval=1.0, maxval=16.0)),
+            "D": jnp.ones((heads,)), "gate_norm": jnp.ones((inner,)),
+            "w_out": _normal(k[2], (inner, cfg.hidden), std)}
 
 
 def init_params(cfg: CausalLMConfig, key) -> dict:
@@ -201,8 +310,14 @@ def init_params(cfg: CausalLMConfig, key) -> dict:
               "final_norm": jnp.ones((d,)), "layers": []}
     for spec, lk in zip(cfg.layers, keys[2:]):
         k = jax.random.split(lk, 8)
-        layer = {"attn_norm": jnp.ones((d,)), "mlp_norm": jnp.ones((d,))}
-        if spec.attention == "latent":
+        layer = {}
+        if spec.attention != "none":
+            layer["attn_norm"] = jnp.ones((d,))
+        if spec.mlp != "none":
+            layer["mlp_norm"] = jnp.ones((d,))
+        if spec.attention == "mamba":
+            layer.update(_mamba_init(cfg, spec.heads, k[0], std))
+        elif spec.attention == "latent":
             layer.update(
                 wq_a=norm(k[0], (d, cfg.q_rank)),
                 q_norm=jnp.ones((cfg.q_rank,)),
@@ -212,22 +327,25 @@ def init_params(cfg: CausalLMConfig, key) -> dict:
                 wkv_b=norm(k[3], (cfg.kv_rank, spec.heads
                                   * (cfg.nope_dim + cfg.v_dim))),
                 wo=norm(k[4], (spec.heads * cfg.v_dim, d)))
-        else:
+        elif spec.attention != "none":
             layer.update(
                 wq=norm(k[0], (d, spec.heads * hd)),
                 wk=norm(k[1], (d, cfg.kv_heads * hd)),
                 wv=norm(k[2], (d, cfg.kv_heads * hd)),
-                wg=norm(k[3], (d, spec.heads)),
                 wo=norm(k[4], (spec.heads * hd, d)))
+            if cfg.attn_gate:
+                layer["wg"] = norm(k[3], (d, spec.heads))
         if spec.mlp == "dense":
-            layer["mlp"] = _gated_mlp_init(k[5], d, cfg.dense_ffn, std)
-        else:
+            layer["mlp"] = _mlp_init(k[5], d, cfg.dense_ffn, std)
+        elif spec.mlp == "sparse":
             layer["moe"] = moe_share_init(
                 k[5], d, cfg.expert_ffn, cfg.num_experts,
-                cfg.experts_held[1], std)
+                cfg.experts_held[1], std, gated=cfg.expert_gated,
+                latent=cfg.expert_latent)
             if cfg.router_bias:
                 layer["moe"]["bias"] = jnp.zeros((cfg.num_experts,))
-            layer["shared"] = _gated_mlp_init(k[6], d, cfg.shared_ffn, std)
+            layer["shared"] = _mlp_init(k[6], d, cfg.shared_ffn, std,
+                                        gated=cfg.expert_gated)
         params["layers"].append(layer)
     return params
 
@@ -368,9 +486,11 @@ def _mm(a, w):
                       preferred_element_type=jnp.float32)
 
 
-def gated_mlp(p, u):
-    return _mm((jax.nn.silu(_mm(u, p["gate"])) * _mm(u, p["up"]))
-               .astype(u.dtype), p["down"])
+def mlp_apply(p, u, activation="silu"):
+    """An MLP in the form its parameters give (`parallel/moe.py:expert_mid`):
+    gated where `p` holds `gate`, else `down(act(up u))`."""
+    mid = expert_mid(p, lambda w: _mm(u, w), ACTIVATIONS[activation])
+    return _mm(mid.astype(u.dtype), p["down"])
 
 
 def latent_project(lp, u, cfg: CausalLMConfig, heads: int, cos, sin):
@@ -417,24 +537,98 @@ def latent_attention(lp, u, cfg: CausalLMConfig, spec: LayerSpec, tables):
     return _mm(o.reshape(b, t, spec.heads * cfg.v_dim), lp["wo"])
 
 
+def mamba_step(lp, u, tail, state, cfg: CausalLMConfig, heads: int):
+    """One position of a Mamba-2 mixer for a batch of sequences, each with
+    what it carries: u [S, d] the normed input; `tail` [S, conv_kernel - 1,
+    conv_width], the rows of `xBC` that the causal convolution still sees,
+    oldest first, zeros before a sequence; `state` [S, heads, head_dim,
+    ssm_state] float32. -> (out [S, d] float32, the new tail, the new state).
+
+        [z | xBC | dt] = u W_in;  xBC <- silu(conv_b + sum_j conv_w[j] * row_j)
+        [x | B | C] = xBC;  dt = softplus(dt + dt_bias);  A = -exp(A_log)
+        S <- exp(dt A) S + dt x (outer) B;   y = S C + D x
+        out = rms_norm_g(y * silu(z)) W_out
+
+    head h reads B and C of group `h // (heads / ssm_groups)`; the norm runs
+    inside each of the `ssm_groups` groups of the inner stream, one gain over
+    all of it. Products take operands in u's dtype and accumulate in float32;
+    the convolution, the time step, the state and its update are float32.
+    The sequence form (`mamba_mixer`) is a scan of this very function."""
+    dtype, S = u.dtype, u.shape[0]
+    P, G, N = cfg.mamba_head_dim, cfg.ssm_groups, cfg.ssm_state
+    inner, R = heads * P, heads // G
+    with jax.named_scope("ssm.project"):
+        zxd = _mm(u, lp["w_in"])
+        z = zxd[:, :inner]
+        xbc = zxd[:, inner:inner + cfg.conv_width].astype(dtype)
+        dt = jax.nn.softplus(zxd[:, inner + cfg.conv_width:]
+                             + lp["dt_bias"].astype(jnp.float32))    # [S, H]
+    with jax.named_scope("ssm.conv"):
+        rows = jnp.concatenate([tail, xbc[:, None, :]], axis=1)
+        conv = jax.nn.silu(
+            jnp.sum(rows.astype(jnp.float32)
+                    * lp["conv_w"].astype(jnp.float32)[None], axis=1)
+            + lp["conv_b"].astype(jnp.float32))
+        x = conv[:, :inner].reshape(S, G, R, P)
+        b = conv[:, inner:inner + G * N].reshape(S, G, 1, 1, N)
+        c = conv[:, inner + G * N:].reshape(S, G, 1, 1, N)
+    with jax.named_scope("ssm.update"):
+        # heads by group: B and C broadcast over a group's heads
+        dt = dt.reshape(S, G, R)
+        decay = jnp.exp(-dt * jnp.exp(lp["A_log"].astype(jnp.float32))
+                        .reshape(G, R))
+        new = decay[..., None, None] * state.reshape(S, G, R, P, N) \
+            + (dt[..., None] * x)[..., None] * b
+        y = jnp.sum(new * c, axis=-1) \
+            + lp["D"].astype(jnp.float32).reshape(G, R, 1) * x
+    with jax.named_scope("ssm.gate"):
+        y = y.reshape(S, G, R * P) * jax.nn.silu(z).reshape(S, G, R * P)
+        y = y * jax.lax.rsqrt(jnp.mean(y * y, -1, keepdims=True)
+                              + cfg.rms_eps)
+        y = (y.reshape(S, inner) * lp["gate_norm"]).astype(dtype)
+        out = _mm(y, lp["w_out"])
+    return out, rows[:, 1:], new.reshape(state.shape)
+
+
+def mamba_mixer(lp, u, cfg: CausalLMConfig, heads: int):
+    """A Mamba-2 mixer over whole sequences from an empty tail and a zero
+    state, one position after another (`lax.scan` of `mamba_step`; no
+    chunked scan is written here). u [B, T, d] -> [B, T, d] float32."""
+    b = u.shape[0]
+    carry = (jnp.zeros((b, cfg.conv_kernel - 1, cfg.conv_width), u.dtype),
+             jnp.zeros((b, heads, cfg.mamba_head_dim, cfg.ssm_state),
+                       jnp.float32))
+
+    def one(carry, u_t):
+        out, tail, state = mamba_step(lp, u_t, *carry, cfg, heads)
+        return (tail, state), out
+
+    _, out = jax.lax.scan(one, carry, jnp.swapaxes(u, 0, 1))
+    return jnp.swapaxes(out, 0, 1)
+
+
 def attention_block(lp, u, cfg: CausalLMConfig, spec: LayerSpec, tables):
     """u: the layer's normed input [B, T, d] -> [B, T, d] float32."""
     if spec.attention == "latent":
         return latent_attention(lp, u, cfg, spec, tables)
+    if spec.attention == "mamba":
+        return mamba_mixer(lp, u, cfg, spec.heads)
     b, t, _ = u.shape
     hd = cfg.head_dim
     heads = lambda w, n: _mm(u, w).astype(u.dtype).reshape(  # noqa: E731
         b, t, n, hd)
     q, k, v = (heads(lp["wq"], spec.heads), heads(lp["wk"], cfg.kv_heads),
                heads(lp["wv"], cfg.kv_heads))
-    cos, sin = tables[spec.attention]
-    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    if spec.attention in tables:
+        cos, sin = tables[spec.attention]
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
     o = causal_attention(
         q, k, v, cfg.sliding_window if spec.attention == "sliding" else None)
-    # the output gate, one value a head, from the same normed input
-    gate = jax.nn.sigmoid(_mm(u, lp["wg"]))
-    o = (o * gate[..., None].astype(o.dtype)).reshape(b, t, spec.heads * hd)
-    return _mm(o, lp["wo"])
+    if "wg" in lp:
+        # the output gate, one value a head, from the same normed input
+        gate = jax.nn.sigmoid(_mm(u, lp["wg"]))
+        o = o * gate[..., None].astype(o.dtype)
+    return _mm(o.reshape(b, t, spec.heads * hd), lp["wo"])
 
 
 def layer_forward(lp, x, cfg: CausalLMConfig, spec: LayerSpec, tables):
@@ -442,23 +636,30 @@ def layer_forward(lp, x, cfg: CausalLMConfig, spec: LayerSpec, tables):
     dropped int32); the two counts are nought on a dense layer."""
     dtype = x.dtype
     b, t, d = x.shape
-    with jax.named_scope("attention." + spec.attention):
-        u = rms_norm(x, lp["attn_norm"], cfg.rms_eps).astype(dtype)
-        h = (x + attention_block(lp, u, cfg, spec, tables)).astype(dtype)
-    u = rms_norm(h, lp["mlp_norm"], cfg.rms_eps).astype(dtype)
+    h = x
+    if spec.attention != "none":
+        with jax.named_scope("attention." + spec.attention):
+            u = rms_norm(x, lp["attn_norm"], cfg.rms_eps).astype(dtype)
+            h = (x + attention_block(lp, u, cfg, spec, tables)).astype(dtype)
     count = cfg.experts_held[1]
-    if spec.mlp == "dense":
-        with jax.named_scope("mlp.dense"):
-            out = gated_mlp(lp["mlp"], u)
+    if spec.mlp != "sparse":
         choices = jnp.zeros((count,), jnp.int32)
         dropped = jnp.zeros((), jnp.int32)
+        if spec.mlp == "none":
+            return h, choices, dropped
+    u = rms_norm(h, lp["mlp_norm"], cfg.rms_eps).astype(dtype)
+    if spec.mlp == "dense":
+        with jax.named_scope("mlp.dense"):
+            out = mlp_apply(lp["mlp"], u)
     else:
         routed, choices, dropped = moe_share_apply(
             lp["moe"], u.reshape(b * t, d), top_k=cfg.top_k,
             experts_held=cfg.experts_held, routed_scale=cfg.routed_scale,
-            n_group=cfg.n_group, topk_group=cfg.topk_group)
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            activation=cfg.expert_act)
         with jax.named_scope("moe.shared"):
-            out = routed.reshape(b, t, d) + gated_mlp(lp["shared"], u)
+            out = routed.reshape(b, t, d) + mlp_apply(lp["shared"], u,
+                                                      cfg.expert_act)
     return (h + out).astype(dtype), choices, dropped
 
 
@@ -469,7 +670,8 @@ def forward(params, cfg: CausalLMConfig, tokens):
     dtype = jnp.dtype(cfg.compute_dtype)
     t = tokens.shape[1]
     tables = {kind: rope_tables(cfg.rope[kind], cfg.rotary_width(kind), t)
-              for kind in {s.attention for s in cfg.layers}}
+              for kind in {s.attention for s in cfg.layers}
+              if kind in cfg.rope}
     x = params["embed"][tokens].astype(dtype)
     choices, dropped = [], []
     for lp, spec in zip(params["layers"], cfg.layers):

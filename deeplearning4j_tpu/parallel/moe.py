@@ -11,8 +11,10 @@ the MXU sees E parallel [C, H] x [H, F] matmuls.
 Beside it, `moe_share_apply`: the dropless layer of a program that holds a
 share of the experts (sigmoid router over all of them, then the held
 experts' products: sorted by expert into a buffer and grouped, or, for the
-few rows of a token step, one batched product over the held experts), which
-`models/causal_lm.py` trains and `serving/latent.py` serves.
+few rows of a token step, one batched product over the held experts; the
+expert a gated silu MLP on the model's width or an ungated relu² MLP in a
+latent space), which `models/causal_lm.py` trains and `serving/latent.py`
+and `serving/hybrid.py` serve.
 """
 
 from __future__ import annotations
@@ -105,16 +107,41 @@ def moe_apply(params, x, k: int = 2, capacity_factor: float = 1.5):
 
 
 def moe_share_init(key, hidden: int, ffn: int, n_experts: int, held: int,
-                   std: float = 0.02) -> dict:
-    """A router over all `n_experts` and the gated MLPs of the `held`
-    experts that live here, float32."""
+                   std: float = 0.02, gated: bool = True,
+                   latent: int = 0) -> dict:
+    """A router over all `n_experts` and the MLPs of the `held` experts
+    that live here, float32: gated (`gate`, `up`, `down`) or, with
+    `gated=False`, `up` and `down` alone. With `latent` the experts work on
+    rows of that width, between `latent_in` [hidden, latent] and
+    `latent_out` [latent, hidden]."""
     k = jax.random.split(key, 4)
     norm = lambda kk, shape: jax.random.normal(  # noqa: E731
         kk, shape, jnp.float32) * std
-    return {"router": norm(k[0], (hidden, n_experts)),
-            "gate": norm(k[1], (held, hidden, ffn)),
-            "up": norm(k[2], (held, hidden, ffn)),
-            "down": norm(k[3], (held, ffn, hidden))}
+    inner = latent or hidden
+    out = {"router": norm(k[0], (hidden, n_experts)),
+           "up": norm(k[2], (held, inner, ffn)),
+           "down": norm(k[3], (held, ffn, inner))}
+    if gated:
+        out["gate"] = norm(k[1], (held, inner, ffn))
+    if latent:
+        out["latent_in"] = norm(jax.random.fold_in(key, 4), (hidden, latent))
+        out["latent_out"] = norm(jax.random.fold_in(key, 5),
+                                 (latent, hidden))
+    return out
+
+
+# what an expert's first product goes through; a gated expert multiplies the
+# activated `gate` product by the `up` product, an ungated one activates `up`
+ACTIVATIONS = {"silu": jax.nn.silu,
+               "relu2": lambda a: jnp.square(jax.nn.relu(a))}
+
+
+def expert_mid(params, dot, act):
+    """What goes into an expert's `down` product, float32: `dot(w)` is the
+    rows' product with one of the expert matrices."""
+    if "gate" in params:
+        return act(dot(params["gate"])) * dot(params["up"])
+    return act(dot(params["up"]))
 
 
 # The buffer of a share's (token, choice) pairs holds this many times the even
@@ -203,7 +230,7 @@ def _held_choices(group, count: int):
 
 
 def _grouped_products(params, x, weight, group, count: int, top_k: int,
-                      rows: int):
+                      rows: int, act=jax.nn.silu):
     """The held pairs sorted by expert into one buffer of `rows` rows, three
     grouped products (`jax.lax.ragged_dot`, which on a TPU is a kernel that
     skips the tiles no group fills, and whose time follows the buffer's
@@ -231,14 +258,14 @@ def _grouped_products(params, x, weight, group, count: int, top_k: int,
         xs = live(x[token])                                     # [rows, H]
         dot = lambda a, b: live(jax.lax.ragged_dot(  # noqa: E731
             a, b.astype(dtype), sizes, preferred_element_type=jnp.float32))
-        mid = (jax.nn.silu(dot(xs, params["gate"]))
-               * dot(xs, params["up"])).astype(dtype)
+        mid = expert_mid(params, lambda w: dot(xs, w), act).astype(dtype)
         out = dot(mid, params["down"]) * w_rows[:, None]        # f32
         y = jnp.zeros(x.shape, jnp.float32).at[token].add(out)
     return y, choices, dropped
 
 
-def _dense_products(params, x, weight, group, count: int, top_k: int):
+def _dense_products(params, x, weight, group, count: int, top_k: int,
+                    act=jax.nn.silu):
     """Every row through every held expert in one batched product a matrix
     (no sort, no gather, no scatter-add: a held expert's weights stream no
     faster than these few rows multiply them), each row's result weighed by
@@ -260,8 +287,17 @@ def _dense_products(params, x, weight, group, count: int, top_k: int):
     with jax.named_scope("moe.experts"):
         dot = lambda spec, a, b: jnp.einsum(  # noqa: E731
             spec, a, b.astype(dtype), preferred_element_type=jnp.float32)
-        mid = (jax.nn.silu(dot("sd,edf->esf", x, params["gate"]))
-               * dot("sd,edf->esf", x, params["up"])).astype(dtype)
+        if "gate" in params:
+            first = lambda w: dot("sd,edf->esf", x, w)  # noqa: E731
+        else:
+            # the same product with the rows laid out an expert each: the
+            # CPU backend folds the transposition of a lone `sd,edf->esf`
+            # into the product after it and then has no bfloat16 product
+            # for it (on the chip the two forms are one program: PERF.md,
+            # PR 33)
+            xe = jnp.broadcast_to(x[None], (count, *x.shape))
+            first = lambda w: dot("esd,edf->esf", xe, w)  # noqa: E731
+        mid = expert_mid(params, first, act).astype(dtype)
         out = dot("esf,efd->esd", mid, params["down"])          # f32
         y = jnp.sum(jnp.where(chose[:, :, None], out * w[:, :, None], 0.0),
                     axis=0)
@@ -271,14 +307,24 @@ def _dense_products(params, x, weight, group, count: int, top_k: int):
 def moe_share_apply(params, x, *, top_k: int, experts_held,
                     routed_scale: float = 1.0, n_group: int = 1,
                     topk_group: int = 1, rows: int | None = None,
-                    live=None):
+                    live=None, activation: str = "silu"):
     """The part of a sigmoid-routed expert layer that the experts held here
     give. x: [N, H] -> (y [N, H] float32, choices int32 [held], dropped
     int32 scalar).
 
     `experts_held = (first, count)` names the experts whose weights
-    `params` holds (`gate`, `up` [count, H, F], `down` [count, F, H]). The
-    router scores every token over ALL experts (`router` [H, E], float32
+    `params` holds. The expert's form is read from them: `gate`, `up`
+    [count, H, F] and `down` [count, F, H] are a gated MLP, `down(act(gate
+    x) * up x)`; without `gate` it is ungated, `down(act(up x))`;
+    `activation` names `act` (`ACTIVATIONS`: `silu`, `relu2`). Where
+    `params` holds `latent_in` [H, R] and `latent_out` [R, H] the experts
+    work in a latent space of width R: the rows go down through `latent_in`
+    once before the products (the router still scores the full width), and
+    the weighted sum over the held experts comes up through `latent_out`
+    once after. That is linear, so the shares of all chips still add up to
+    the whole layer.
+
+    The router scores every token over ALL experts (`router` [H, E], float32
     sigmoid), takes the `top_k` largest and weighs each chosen expert by
     `routed_scale * s_e / sum of the chosen s`. Where `params` holds a
     `bias` [E] (a buffer, not trained by the loss) the choice is made on
@@ -290,8 +336,8 @@ def moe_share_apply(params, x, *, top_k: int, experts_held,
     idle slots) are neither counted nor added to any row. `choices[e]`
     counts the (token, choice) pairs routed to held expert `e`.
 
-    One routing, then the three products of the gated MLP in one of two
-    forms, chosen from the call's shapes (`moe_share_dense`):
+    One routing, then the expert's products in one of two forms, chosen
+    from the call's shapes (`moe_share_dense`):
 
     - *grouped* (`_grouped_products`): the held pairs are sorted by expert
       into one buffer of `rows` rows, pass through three
@@ -322,9 +368,24 @@ def moe_share_apply(params, x, *, top_k: int, experts_held,
     with jax.named_scope("moe.route"):
         weight, group = _share_route(params, x, top_k, experts_held,
                                      routed_scale, n_group, topk_group, live)
+    act = ACTIVATIONS[activation]
+    latent = "latent_in" in params
+    if latent:
+        with jax.named_scope("moe.latent"):
+            x = jnp.matmul(x, params["latent_in"].astype(x.dtype),
+                           preferred_element_type=jnp.float32).astype(x.dtype)
     if moe_share_dense(n, top_k, rows):
-        return _dense_products(params, x, weight, group, count, top_k)
-    return _grouped_products(params, x, weight, group, count, top_k, rows)
+        y, choices, dropped = _dense_products(params, x, weight, group,
+                                              count, top_k, act)
+    else:
+        y, choices, dropped = _grouped_products(params, x, weight, group,
+                                                count, top_k, rows, act)
+    if latent:
+        with jax.named_scope("moe.latent"):
+            y = jnp.matmul(y.astype(x.dtype),
+                           params["latent_out"].astype(x.dtype),
+                           preferred_element_type=jnp.float32)
+    return y, choices, dropped
 
 
 class MoELayerTrainer:

@@ -26,7 +26,10 @@ gives the INFERENCE side the same contract under concurrent traffic:
   ones free their slot immediately, zero steady-state recompiles;
   `LatentDecodeModel` (ISSUE 32) serves a latent-attention causal LM
   over a paged pool of latents, its experts through
-  `parallel/moe.py:moe_share_apply`;
+  `parallel/moe.py:moe_share_apply`; `HybridDecodeModel` (ISSUE 34) a
+  hybrid state-space LM: a Mamba-2 state and convolution tail a slot
+  beside paged grouped-head K and V, latent experts through the same
+  share;
 - `InferenceSession`: the sync/async facade, instrumented through the
   PR-1 telemetry registry (`dl4j_serving_*`);
 - HTTP: `UIServer.serveModels(session)` exposes
@@ -51,6 +54,7 @@ from deeplearning4j_tpu.serving.buckets import (
     unpad)
 from deeplearning4j_tpu.serving.decode import (
     DecodeEngine, PagedKVCache, RnnDecodeModel, TransformerDecodeModel)
+from deeplearning4j_tpu.serving.hybrid import HybridDecodeModel
 from deeplearning4j_tpu.serving.latent import LatentDecodeModel
 from deeplearning4j_tpu.serving.prefill import ChunkedPrefill
 from deeplearning4j_tpu.serving.prefix_cache import PrefixCache
@@ -71,8 +75,8 @@ __all__ = [
     "AdmissionController", "BucketLadder", "ChunkedPrefill",
     "DEFAULT_BATCH_BUCKETS",
     "DecodeEngine", "DynamicBatcher", "FnServable", "GraphServable",
-    "InferenceSession", "LatentDecodeModel", "ModelNotFound",
-    "ModelRegistry",
+    "HybridDecodeModel", "InferenceSession", "LatentDecodeModel",
+    "ModelNotFound", "ModelRegistry",
     "NetworkServable", "PagedKVCache", "PrefixCache", "QueueFullError",
     "Replica",
     "ReplicaDeath", "ReplicaSet", "RnnDecodeModel", "SameDiffServable",

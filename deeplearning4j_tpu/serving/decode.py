@@ -832,6 +832,20 @@ class DecodeEngine:
         self.max_new_limit = int(max_new_limit)
         self._instruments_fn = (instruments if callable(instruments)
                                 else lambda: instruments)
+        # a model that holds state by slot beside its pages (a recurrent
+        # state no page table names: serving/hybrid.py) starts a request's
+        # state at position 0 and cannot take a position back, so what
+        # treats a position as a row of a page is refused, not served wrongly
+        self._slot_state_bytes = 0
+        if getattr(model, "slot_state", False):
+            if prefix_cache or speculative is not None:
+                raise DecodeError(
+                    "a model that holds state by slot serves neither "
+                    "prefix_cache (a hit starts a request past position 0, "
+                    "on a state that no page holds) nor speculative (a "
+                    "rejected draft's positions cannot be taken out of the "
+                    "state)")
+            self._slot_state_bytes = int(model.slot_state_bytes())
         self._pending: _queue.Queue = _queue.Queue(maxsize=pending_size)
         self._waiting: list = []   # engine-side FIFO (page head-block)
         self._active: dict[int, _DecodeRequest] = {}
@@ -1201,7 +1215,8 @@ class DecodeEngine:
             # the pool in BYTES beside page occupancy (ISSUE 14
             # satellite): the device pool holds n_pages + 1 pages
             # (page 0 = scratch), so per-page bytes divide by that
-            per_page = self._pool_bytes // (self._kv.n_pages + 1)
+            per_page = (self._pool_bytes - self._slot_state_bytes) \
+                // (self._kv.n_pages + 1)
             out["kv_pages"] = {"total": self._kv.n_pages,
                                "free": self._kv.free_pages,
                                "occupancy": round(
@@ -1218,6 +1233,8 @@ class DecodeEngine:
         if self._sharded_mesh is not None and \
                 callable(getattr(self.model, "sharded_health", None)):
             out["sharded"] = self.model.sharded_health()
+        if self._slot_state_bytes:
+            out["slot_state_bytes"] = self._slot_state_bytes
         if self._pcache is not None:
             out["prefix_cache"] = self._pcache.stats()
         if self._spec is not None:
@@ -1344,6 +1361,8 @@ class DecodeEngine:
             self._state = self.model.reset_slot(self._state, slot)
             self._active[slot] = req
             admitted += 1
+            if self._slot_state_bytes and inst is not None:
+                inst.state_start(self._slot_state_bytes)
             # submit -> slot join: the decode analog of queue-wait
             t_join = time.perf_counter()
             if inst is not None:

@@ -45,6 +45,22 @@ _FLOAT32 = ("attn_norm", "mlp_norm", "q_norm", "kv_norm", "final_norm",
             "router", "bias")
 
 
+def cast_leaves(params, dtype, float32):
+    """`params` with every leaf in `dtype` but those named in `float32`. A
+    leaf that already has its dtype is taken as it is (no second copy of a
+    model that fills the chip)."""
+    import jax
+    import jax.numpy as jnp
+
+    def cast(path, a):
+        name = str(getattr(path[-1], "key", path[-1]))
+        want = jnp.float32 if name in float32 else dtype
+        a = jnp.asarray(a)
+        return a if a.dtype == want else a.astype(want)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 def decode_layout(params, cfg, dtype):
     """`causal_lm.init_params`'s tree as the token step reads it: matrices
     in `dtype`, norm gains, router and bias in float32, and each layer's
@@ -61,13 +77,7 @@ def decode_layout(params, cfg, dtype):
            for a in jax.tree_util.tree_leaves(params)):
         return jax.eval_shape(lambda p: decode_layout(p, cfg, dtype), params)
 
-    def cast(path, a):
-        name = str(getattr(path[-1], "key", path[-1]))
-        want = jnp.float32 if name in _FLOAT32 else dtype
-        a = jnp.asarray(a)
-        return a if a.dtype == want else a.astype(want)
-
-    out = jax.tree_util.tree_map_with_path(cast, params)
+    out = cast_leaves(params, dtype, _FLOAT32)
     layers = []
     for lp, spec in zip(out["layers"], cfg.layers):
         lp = dict(lp)
@@ -238,7 +248,7 @@ class LatentDecodeModel:
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.models.causal_lm import (
-            _mm, gated_mlp, latent_project, rms_norm)
+            _mm, latent_project, mlp_apply, rms_norm)
         from deeplearning4j_tpu.parallel.moe import moe_share_apply
 
         cfg, dt, S, H = self.cfg, self.dtype, self.max_slots, self.n_heads
@@ -278,7 +288,7 @@ class LatentDecodeModel:
             u = rms_norm(h, lp["mlp_norm"], cfg.rms_eps).astype(dt)
             if spec.mlp == "dense":
                 with jax.named_scope("mlp.dense"):
-                    out = gated_mlp(lp["mlp"], u)
+                    out = mlp_apply(lp["mlp"], u)
             else:
                 # `rows` is every choice of every row, so nothing is ever
                 # dropped; at up to `moe.DENSE_ROWS` slots that makes the
@@ -290,7 +300,7 @@ class LatentDecodeModel:
                     routed_scale=cfg.routed_scale, n_group=cfg.n_group,
                     topk_group=cfg.topk_group, rows=S * cfg.top_k, live=fed)
                 with jax.named_scope("moe.shared"):
-                    out = routed + gated_mlp(lp["shared"], u)
+                    out = routed + mlp_apply(lp["shared"], u)
                 held = choices.astype(jnp.float32)
                 counts.append(jnp.stack([
                     n_fed * cfg.top_k, jnp.sum(held),

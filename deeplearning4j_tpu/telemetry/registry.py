@@ -565,6 +565,15 @@ DECODE_OVERLAPPED_HELP = ("Token-step boundaries whose successor was "
                           "token step in flight); over "
                           "dl4j_decode_boundaries_total{executable="
                           "\"step\"} it is the overlapped share")
+DECODE_SLOT_STATE_HELP = ("Bytes of state the decode model holds by slot "
+                          "beside its paged pool (a hybrid model's "
+                          "recurrent states and convolution tails), by "
+                          "model; the pool's pages are not in it")
+DECODE_STATE_STARTS_HELP = ("Requests whose state by slot started from "
+                            "nought at their position 0, by model: every "
+                            "admission of a model that holds such state "
+                            "(a prefix hit would not be one, and the engine "
+                            "refuses the prefix cache for such a model)")
 DECODE_QUEUE_WAIT_HELP = ("Seconds from decode submit to the boundary "
                           "at which the request took a slot")
 
@@ -646,12 +655,13 @@ class ServingInstruments:
                  "prefix_hits", "prefix_misses", "ttft", "_accepted",
                  "kv_occupancy", "_phases", "_boundaries", "_positions",
                  "kv_fill_sum", "live_pages_sum", "overlapped",
-                 "decode_queue_wait", "_registry", "_moe")
+                 "decode_queue_wait", "_registry", "_moe", "_state_starts")
 
     def __init__(self, registry, model):
         self.model = model
         self._registry = registry
         self._moe = None    # bound by the first token step that routes
+        self._state_starts = None   # and by a model's first state start
         self._requests = registry.counter(
             "dl4j_serving_requests_total", SERVING_REQUESTS_HELP,
             ("model", "outcome"))
@@ -742,6 +752,18 @@ class ServingInstruments:
         and, on the profiler's clock, the span `dl4j.decode.<phase>`."""
         histogram, annotation = self._phases[phase]
         return histogram.time(annotation)
+
+    def state_start(self, nbytes):
+        """One request of a model that holds `nbytes` of state by slot
+        started its state from nought."""
+        if self._state_starts is None:
+            self._state_starts = self._registry.counter(
+                "dl4j_decode_state_starts_total", DECODE_STATE_STARTS_HELP,
+                ("model",)).labels(model=self.model)
+            self._registry.gauge(
+                "dl4j_decode_slot_state_bytes", DECODE_SLOT_STATE_HELP,
+                ("model",)).labels(model=self.model).set(nbytes)
+        self._state_starts.inc()
 
     def moe_step(self, layers, counts, dense=False):
         """One token step's router counts (`MoeInstruments.step`), under

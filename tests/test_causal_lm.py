@@ -191,7 +191,7 @@ def _layer_input(seed=0, n=2 * SEQ):
 def _whole_layer(seed=2):
     """An uncut sparse layer's weights in the reference's layout."""
     whole = moe.moe_share_init(jax.random.key(seed), 64, 32, 8, 8)
-    shared = lm._gated_mlp_init(jax.random.key(seed + 1), 64, 32, 0.02)
+    shared = lm._mlp_init(jax.random.key(seed + 1), 64, 32, 0.02)
     names = train_lm.MLP_NAMES
     ref_lp = {"router": whole["router"],
               "experts": {names[k]: whole[k] for k in names},
@@ -206,7 +206,7 @@ def test_the_shares_add_up_to_the_uncut_layer(count):
     whole), plus the shared expert once, equal the uncut reference's layer."""
     whole, shared, ref_lp = _whole_layer()
     u = _layer_input()
-    total, chosen = lm.gated_mlp(shared, u), 0
+    total, chosen = lm.mlp_apply(shared, u), 0
     for first in range(0, 8, count):
         share = {"router": whole["router"],
                  **{k: whole[k][first:first + count]
