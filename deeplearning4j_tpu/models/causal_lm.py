@@ -23,7 +23,8 @@ share of a deployment:
 
 bfloat16 activations and matmul operands with float32 accumulation; norms,
 rotary tables, router scores and the loss in float32; float32 parameters;
-each layer under `jax.checkpoint`. `CausalLMTrainer` trains it through the
+each layer under `jax.checkpoint`, which keeps the layer's input and its
+attention's output. `CausalLMTrainer` trains it through the
 step engine that `BertTrainer` uses; `serving/latent.py` decodes the same
 block description, token by token, over a paged pool of latents, and
 `serving/hybrid.py` a hybrid one over K/V pages and a state a slot."""
@@ -36,6 +37,7 @@ from dataclasses import dataclass
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import DATA_AXIS, spec_for
@@ -44,8 +46,10 @@ from deeplearning4j_tpu.parallel.moe import (
     moe_share_init, moe_share_rows)
 from deeplearning4j_tpu.parallel.step_engine import StepEngine, loss_and_adam
 
-# the splash kernel's tiles: a sequence it runs on is a multiple of this
+# the splash kernel's least tile: a sequence it runs on is a multiple of this
 ATTENTION_BLOCK = 512
+# what `forward`'s checkpoint keeps of a layer: the attention's result
+ATTENTION_SAVED = "attention.out"
 # positions of each row whose logits the loss holds at a time
 LOSS_CHUNK = 4096
 # weights from a seed: normal(0, INIT_STD) for every matrix and the embedding
@@ -431,7 +435,13 @@ def causal_attention(q, k, v, window=None):
     On a TPU, for sequences the kernel's tiles divide, the splash kernel
     (blocked online softmax) visits only the blocks the mask leaves:
     a sliding layer does the work of its window, not of the sequence.
-    Elsewhere a plain masked product."""
+    Elsewhere a plain masked product.
+
+    Both branches name their result `ATTENTION_SAVED` (the kernel its
+    float32 log-sum-exp [B, H, T] too), for a `jax.checkpoint` whose policy
+    keeps that name: `B x T x H x D` of the activations' dtype a call, and
+    the backward pass does not run the forward kernel again. The plain
+    branch names the output alone and makes its softmax again."""
     b, t, h, d = q.shape
     kv = k.shape[2]
     g = h // kv
@@ -451,26 +461,54 @@ def causal_attention(q, k, v, window=None):
         p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
         out = jnp.einsum("bkgqs,bksd->bkgqd", p.astype(vt.dtype), vt,
                          preferred_element_type=jnp.float32).astype(q.dtype)
+        out = checkpoint_name(out, ATTENTION_SAVED)
     return jnp.transpose(out, (0, 3, 1, 2, 4)).reshape(b, t, h, d)
 
 
 def _splash(qg, kt, vt, window):
     """One multi-query kernel (G query heads on one K and V head) mapped
-    over the KV heads and the batch."""
+    over the KV heads and the batch; its output and log-sum-exp named
+    `ATTENTION_SAVED`."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as kernel, splash_attention_mask as mask)
 
     g, t = qg.shape[2], qg.shape[3]
     one = (mask.CausalMask((t, t)) if window is None else
            mask.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
+    # tiles from the mask: under a window a query tile of 512 visits two
+    # key tiles of 512 and more columns at any other size; over the whole
+    # triangle tiles twice as long read K and V half as often
     blk = ATTENTION_BLOCK
+    if window is None and t % (2 * blk) == 0:
+        blk *= 2
     sizes = kernel.BlockSizes(
-        block_q=blk, block_kv=blk, block_kv_compute=blk, block_q_dkv=blk,
-        block_kv_dkv=blk, block_kv_dkv_compute=blk, block_q_dq=blk,
+        block_q=blk, block_kv=blk, block_kv_compute=ATTENTION_BLOCK,
+        block_q_dkv=blk, block_kv_dkv=blk,
+        block_kv_dkv_compute=ATTENTION_BLOCK, block_q_dq=blk,
         block_kv_dq=blk)
-    fn = kernel.make_splash_mqa_single_device(
-        mask.MultiHeadMask([one] * g), block_sizes=sizes)
-    return jax.vmap(jax.vmap(fn))(qg, kt, vt)
+
+    def fn():
+        # made where it is called: under a trace the kernel holds its mask's
+        # tables as values of that trace
+        return jax.vmap(jax.vmap(kernel.make_splash_mqa_single_device(
+            mask.MultiHeadMask([one] * g), block_sizes=sizes,
+            residual_checkpoint_name=ATTENTION_SAVED)))
+
+    @jax.custom_vjp
+    def attend(q, k, v):
+        return fn()(q, k, v)
+
+    def attend_fwd(q, k, v):
+        # The kernel writes its log-sum-exp 128 lanes wide, [B, H, T, 128]
+        # float32 (537 MB for 64 heads at 2 x 8,192), and keeps lane 0.
+        # Left alone the compiler puts that slice off until the backward
+        # pass and holds the wide array meanwhile; behind the barrier all
+        # the backward pass is handed is made by the time the output is.
+        out, pull = jax.vjp(fn(), q, k, v)
+        return jax.lax.optimization_barrier((out, pull))
+
+    attend.defvjp(attend_fwd, lambda pull, d_out: pull(d_out))
+    return attend(qg, kt, vt)
 
 
 # -- the block ----------------------------------------------------------------
@@ -663,10 +701,26 @@ def layer_forward(lp, x, cfg: CausalLMConfig, spec: LayerSpec, tables):
     return (h + out).astype(dtype), choices, dropped
 
 
+def checkpointed_layer(cfg: CausalLMConfig, spec: LayerSpec, tables):
+    """`layer_forward` of (lp, x) as `forward` runs it, recomputed going
+    backward: the backward pass holds one layer's activations at a time,
+    and of every layer its input and what `causal_attention` names."""
+    return jax.checkpoint(
+        lambda lp, x: layer_forward(lp, x, cfg, spec, tables),
+        policy=jax.checkpoint_policies.save_only_these_names(ATTENTION_SAVED))
+
+
 def forward(params, cfg: CausalLMConfig, tokens):
     """tokens [B, T] int32 -> (final hidden states [B, T, d], normed, in
     the compute dtype; choices int32 [sparse layers, held experts]; dropped
-    int32 [sparse layers])."""
+    int32 [sparse layers]).
+
+    Every layer runs under `checkpointed_layer`. What a backward pass pays
+    for not running attention twice: each `full` or `sliding` layer's
+    attention output, `B x T x heads x head_dim` in the compute dtype (268
+    MB at 2 x 8,192 x 64 x 128 in bfloat16), held from the layer's forward
+    pass to its backward pass. A `latent`, `mamba` or `none` layer names
+    nothing and keeps its input alone."""
     dtype = jnp.dtype(cfg.compute_dtype)
     t = tokens.shape[1]
     tables = {kind: rope_tables(cfg.rope[kind], cfg.rotary_width(kind), t)
@@ -675,11 +729,7 @@ def forward(params, cfg: CausalLMConfig, tokens):
     x = params["embed"][tokens].astype(dtype)
     choices, dropped = [], []
     for lp, spec in zip(params["layers"], cfg.layers):
-        # recomputation a layer: the backward pass keeps one layer's
-        # activations and every layer's input
-        x, c, dr = jax.checkpoint(
-            lambda lp_, x_, spec=spec: layer_forward(
-                lp_, x_, cfg, spec, tables))(lp, x)
+        x, c, dr = checkpointed_layer(cfg, spec, tables)(lp, x)
         if spec.mlp == "sparse":
             choices.append(c)
             dropped.append(dr)

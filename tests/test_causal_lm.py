@@ -5,6 +5,7 @@ program): widths 64, 8 experts top-2, window 8 at 32 positions, five layers
 in the published pattern (full dense, three sliding sparse, full sparse),
 seeded weights."""
 
+import dataclasses
 import json
 import math
 import os
@@ -422,3 +423,168 @@ def test_both_trainers_share_one_engine_and_one_adam():
     assert isinstance(causal._engine, StepEngine)
     assert bert._build().__wrapped__.__name__ == "step"
     assert causal._engine.build().__wrapped__.__name__ == "step"
+
+
+# -- what the layer's checkpoint keeps ----------------------------------------
+
+def cut_to(*specs, **widths):
+    """The toy configuration cut to these layers, and the rotary tables
+    `forward` would hand them at `t` positions."""
+    cfg = dataclasses.replace(config(), layers=specs, **widths)
+    tables = lambda t: {  # noqa: E731
+        kind: lm.rope_tables(cfg.rope[kind], cfg.rotary_width(kind), t)
+        for kind in cfg.rope if kind in {s.attention for s in specs}}
+    return cfg, tables
+
+
+@pytest.mark.parametrize("spec, kept", [
+    (lm.LayerSpec("full", 4, "dense"), (2, 2, 2, SEQ, 16)),
+    (lm.LayerSpec("sliding", 6, "sparse"), (2, 2, 3, SEQ, 16)),
+    (lm.LayerSpec("none", 0, "dense"), None)])
+def test_the_checkpoint_keeps_the_attention_and_computes_the_same(
+        spec, kept, capsys, monkeypatch):
+    """`forward`'s policy changes what a layer's checkpoint keeps, never
+    what is computed: beside its arguments the layer keeps one array, the
+    one `causal_attention` names, and none where there is no attention;
+    loss and gradient are the bare checkpoint's bit for bit."""
+    cfg, tables = cut_to(spec)
+    params = lm.init_params(cfg, jax.random.key(3))
+    tok, lab = map(jnp.asarray, batch(4))
+    x = params["embed"][tok]
+    lp = params["layers"][0]
+
+    def residuals(fn):
+        capsys.readouterr()
+        jax.ad_checkpoint.print_saved_residuals(fn, lp, x)
+        return [line for line in capsys.readouterr().out.splitlines()
+                if " from the argument " not in line
+                and " from a constant" not in line]
+
+    shape = kept and "f32[" + ",".join(map(str, kept)) + "]"
+    layer = lm.checkpointed_layer(cfg, spec, tables(SEQ))
+    held = residuals(layer)
+    # the checkpoint's own equation: what is named inside it
+    named = [line for line in str(jax.make_jaxpr(layer)(lp, x)).splitlines()
+             if f"name[name={lm.ATTENTION_SAVED}]" in line]
+    if kept is None:
+        assert held == [] and named == []
+    else:
+        assert len(held) == 1 and len(named) == 1, (held, named)
+        assert held[0].startswith(shape) and "(causal_attention)" in held[0]
+        assert f":{shape} = name[" in named[0]
+
+    # traced anew at each call, so the second sees the policy taken away
+    step = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+        lambda p: lm.lm_loss(p, cfg, tok, lab)[0]))(params)
+    loss, grad = step()
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    bare_loss, bare_grad = step()
+    assert float(loss) == float(bare_loss)
+    same = jax.tree_util.tree_map(
+        lambda a, b: bool(jnp.array_equal(a, b)), grad, bare_grad)
+    assert all(jax.tree_util.tree_leaves(same)), same
+    assert float(jnp.abs(grad["layers"][0]["mlp_norm"]).sum()) > 0
+
+
+def test_the_kernel_branch_runs_and_agrees_with_the_plain_one(monkeypatch):
+    """The TPU branch with its kernels interpreted on the CPU, a full and a
+    sliding layer at the kernel's widths: loss and gradient through
+    `forward`'s checkpoint are the plain branch's. A compile alone does not
+    show that a step can be called: the kernel's mask tables are values of
+    the trace they were made under, and one that escaped it raised only at
+    the first call."""
+    import functools
+
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel)
+
+    cfg, _ = cut_to(lm.LayerSpec("full", 4, "dense"),
+                    lm.LayerSpec("sliding", 6, "dense"), head_dim=128,
+                    sliding_window=512)
+    params = lm.init_params(cfg, jax.random.key(5))
+    tok = jax.random.randint(jax.random.key(6), (1, 2 * lm.ATTENTION_BLOCK),
+                             3, 64)
+    lab = jnp.roll(tok, -1, axis=1)
+    step = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+        lambda p: lm.lm_loss(p, cfg, tok, lab)[0]))(params)
+    plain_loss, plain_grad = step()
+    monkeypatch.setattr(lm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        kernel, "make_splash_mqa_single_device", functools.partial(
+            kernel.make_splash_mqa_single_device, interpret=True))
+    loss, grad = step()
+    assert abs(float(loss) - float(plain_loss)) < 1e-5
+    for got, want in zip(jax.tree_util.tree_leaves(grad),
+                         jax.tree_util.tree_leaves(plain_grad)):
+        assert close(got, want, 1e-5)
+
+
+# -- compiled for a described chip: nothing runs ------------------------------
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("spec", [lm.LayerSpec("full", 4, "dense"),
+                                  lm.LayerSpec("sliding", 6, "dense")])
+def test_a_layers_backward_pass_runs_the_forward_kernel_once(
+        spec, topo, no_cache, monkeypatch):
+    """One layer at the kernel's widths (heads of 128, window 512, 1,024
+    positions), forward and backward under `forward`'s checkpoint, compiled
+    for a v5e: one forward splash kernel where the bare checkpoint runs it
+    again going backward, and both backward kernels."""
+    import re
+
+    from jax.sharding import SingleDeviceSharding
+
+    t = 2 * lm.ATTENTION_BLOCK
+    cfg, tables = cut_to(spec, head_dim=128, sliding_window=512,
+                         compute_dtype="bfloat16")
+    monkeypatch.setattr(lm, "_on_tpu", lambda: True)
+    one = SingleDeviceSharding(topo.devices[0])
+    lp = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one),
+        jax.eval_shape(lambda: lm.init_params(
+            cfg, jax.random.key(0))["layers"][0]))
+    x = jax.ShapeDtypeStruct((1, t, cfg.hidden), jnp.bfloat16, sharding=one)
+
+    def launches():
+        def loss(lp_, x_):
+            y, _, _ = lm.checkpointed_layer(cfg, spec, tables(t))(lp_, x_)
+            return jnp.sum(y.astype(jnp.float32))
+
+        # tests/conftest.py asks for float32 matmuls everywhere, which a
+        # Mosaic kernel's bfloat16 product cannot be
+        with jax.default_matmul_precision("default"):
+            text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1))).lower(
+                lp, x).compile().as_text()
+        return {k: len(re.findall(
+            rf"%splash_mqa_{k}\S* = .*custom-call\(", text))
+            for k in ("fwd", "dkv", "dq")}
+
+    assert launches() == {"fwd": 1, "dkv": 1, "dq": 1}
+    monkeypatch.setattr(jax.checkpoint_policies, "save_only_these_names",
+                        lambda *names: None)
+    assert launches() == {"fwd": 2, "dkv": 1, "dq": 1}
