@@ -16,6 +16,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+# at import, not inside `run`: the process's compile listeners go in when
+# `telemetry` is first imported, and a trainer's weights are compiled before
+# its engine exists (telemetry.registry.watch_process)
+from deeplearning4j_tpu import telemetry
+
 B1, B2, EPS = 0.9, 0.999, 1e-8
 
 
@@ -92,14 +97,12 @@ class StepEngine:
         `place(gathered, steps)` makes the step's batch arguments, inside
         the dispatch span with the call. Returns what the step returns
         after the state: the loss, then any auxiliary outputs."""
-        from deeplearning4j_tpu import telemetry
-
         if self.fn is None:
             self.fn = self.build()
         # the step's two host phases as spans on the profiler's clock
         # (pure annotations; nothing is made when telemetry is off)
-        span = (telemetry.span if telemetry.enabled()
-                else contextlib.nullcontext)
+        on = telemetry.enabled()
+        span = telemetry.span if on else contextlib.nullcontext
         with span("dl4j.train.gather"):
             gathered = gather()
         with span("dl4j.train.dispatch"):
@@ -109,4 +112,8 @@ class StepEngine:
                 self.params, self.opt, *place(gathered, self.steps),
                 jnp.asarray(self.steps, jnp.int32))
         self.steps += 1
+        if on:
+            # the process's start-up account freezes once its first step is
+            # traced, compiled and queued (one flag read ever after)
+            telemetry.startup_done()
         return (loss, *aux)

@@ -58,7 +58,9 @@ from concurrent.futures import Future
 
 import numpy as np
 
-from deeplearning4j_tpu.telemetry import compile_ledger, flight, tracing
+from deeplearning4j_tpu.telemetry import compile_ledger, tracing
+from deeplearning4j_tpu.telemetry.registry import (DispatchAccount,
+                                                   startup_done)
 
 
 class DecodeError(RuntimeError):
@@ -69,7 +71,7 @@ class DecodeShutdown(RuntimeError):
     """Engine closed with this request still pending."""
 
 
-def _phase(inst, phase):
+def _phase(inst, phase, account):
     """The Timer over one phase of a boundary (histogram and
     `dl4j.decode.<phase>` span), or nothing when telemetry is off. The
     five phases are leaves, each observed once a delivered boundary, and
@@ -78,8 +80,13 @@ def _phase(inst, phase):
     An iteration of the engine's loop runs `admit`, `build` and
     `dispatch` of one boundary and then `readback` and `emit`: of the
     same boundary where the engine is serial, of the boundary before it
-    where one token step is in flight (`DecodeEngine._step_boundary`)."""
-    return contextlib.nullcontext() if inst is None else inst.phase(phase)
+    where one token step is in flight (`DecodeEngine._step_boundary`).
+    Each Timer also adds its seconds to the engine's ``account``, from
+    which the next token-step dispatch takes what the interval since the
+    last one spent under no span (`ServingInstruments.dispatched`)."""
+    if inst is None:
+        return contextlib.nullcontext()
+    return inst.phase(phase, account)
 
 
 # ---------------------------------------------------------------------------
@@ -993,6 +1000,9 @@ class DecodeEngine:
         # compiles nothing: tests/test_compilestore.py's
         # test_warm_decode_engine_zero_compiles counts this one too
         self._flight = None
+        # what the engine's thread spent under its phase spans since the
+        # last token-step dispatch (telemetry: the dispatch intervals)
+        self._account = DispatchAccount()
         self._pick = None
         if self._block is None and self._spec is None:
             import jax
@@ -1370,10 +1380,6 @@ class DecodeEngine:
             if req.trace is not None:
                 tracing.emit("decode.queue", req.trace, req.t_submit,
                              t_join, slot=slot, req_id=req.req_id)
-            flight.record("decode_join", model=self.name,
-                          req_id=req.req_id, slot=slot,
-                          prompt=len(req.prompt), max_new=req.max_new,
-                          adopted_pages=adopted)
         return admitted
 
     # per-request ceiling on per-boundary spans; the remainder folds
@@ -1399,11 +1405,6 @@ class DecodeEngine:
         elif not req.future.done():
             req.future.set_result(list(req.generated))
         req.stream.put(_DecodeRequest._END)
-        flight.record("decode_leave", model=self.name,
-                      req_id=req.req_id, slot=slot,
-                      generated=len(req.generated),
-                      seconds=round(time.perf_counter() - req.t_submit,
-                                    6))
 
     def _fail_boundary(self, err):
         """A launch raised: the engine goes on from fresh pools with
@@ -1416,6 +1417,7 @@ class DecodeEngine:
         dispatched on the consumed pool: that one's record goes with
         the state, nothing of it is delivered."""
         self._flight = None
+        self._account.clear()
         try:
             self._state = None      # let the old pool go before the new
             self._state = self.model.init_state()
@@ -1512,7 +1514,8 @@ class DecodeEngine:
             return True
         S = self.model.max_slots
         C = self._block.chunk
-        with _phase(inst, "build"):
+        self._account.clear()   # no interval between token steps across it
+        with _phase(inst, "build", self._account):
             blocks = np.zeros((S, C), np.int32)
             pos0 = np.zeros((S,), np.int32)
             counts = np.zeros((S,), np.int32)
@@ -1527,11 +1530,11 @@ class DecodeEngine:
             table = self._table.copy()
         t_b0 = time.perf_counter()
         try:
-            with _phase(inst, "dispatch"):
+            with _phase(inst, "dispatch", self._account):
                 outs, self._state = self._block.launch(
                     self._state, blocks, pos0, counts, table,
                     site=f"decode:{self.name}:prefill")
-            with _phase(inst, "readback"):
+            with _phase(inst, "readback", self._account):
                 np.asarray(outs)    # prefill wants none of it: the wait
                 if self._spec is not None:
                     self._spec.prefill(blocks, pos0, counts)
@@ -1542,7 +1545,7 @@ class DecodeEngine:
                 e, f"decode:{self.name}:prefill", "chunk prefill failed"))
             return False
         t_b1 = time.perf_counter()
-        with _phase(inst, "emit"):
+        with _phase(inst, "emit", self._account):
             self._last_boundary = time.monotonic()
             for slot, req in todo.items():
                 if self._active.get(slot) is not req:
@@ -1584,7 +1587,7 @@ class DecodeEngine:
         to overlap."""
         prev, self._flight = self._flight, None
         S = self.model.max_slots
-        with _phase(inst, "build"):
+        with _phase(inst, "build", self._account):
             feed = np.zeros((S,), np.int32)
             pos = np.zeros((S,), np.int32)
             active = np.zeros((S,), bool)
@@ -1613,8 +1616,10 @@ class DecodeEngine:
             # launch is out (a prefix page that others may share)
             table[~active] = 0
         t_b0 = time.perf_counter()
+        if inst is not None:
+            inst.dispatched(self._account, t_b0)
         try:
-            with _phase(inst, "dispatch"):
+            with _phase(inst, "dispatch", self._account):
                 tokens = feed
                 if (feed < 0).any():
                     tokens = self._pick(feed, prev[0])
@@ -1656,7 +1661,7 @@ class DecodeEngine:
         was failed)."""
         nxt, fed, t_b0, counts = launch
         try:
-            with _phase(inst, "readback"):
+            with _phase(inst, "readback", self._account):
                 if counts is None:
                     nxt = np.asarray(nxt)
                 else:
@@ -1668,7 +1673,7 @@ class DecodeEngine:
                 e, f"decode:{self.name}:step", "decode step failed"))
             return False
         t_b1 = time.perf_counter()
-        with _phase(inst, "emit"):
+        with _phase(inst, "emit", self._account):
             self._last_boundary = time.monotonic()
             n_decoded = n_prompt = n_answer = 0
             positions = []
@@ -1717,6 +1722,7 @@ class DecodeEngine:
                 if counts is not None:
                     inst.moe_step(self.model.moe_layers, counts,
                                   getattr(self.model, "moe_dense", False))
+                startup_done()      # one flag read after the process's first
         return True
 
     def _speculative_boundary(self, inst):
@@ -1733,7 +1739,8 @@ class DecodeEngine:
             self._step_boundary(inst)
             return
         V = self._spec.k + 1
-        with _phase(inst, "build"):
+        self._account.clear()   # no interval between token steps across it
+        with _phase(inst, "build", self._account):
             feed = np.zeros((S,), np.int32)
             pos = np.zeros((S,), np.int32)
             active = np.zeros((S,), bool)
@@ -1749,7 +1756,7 @@ class DecodeEngine:
             table = self._table.copy()
         t_b0 = time.perf_counter()
         try:
-            with _phase(inst, "dispatch"):
+            with _phase(inst, "dispatch", self._account):
                 drafts = self._spec.propose(feed, pos, active)
                 blocks = np.zeros((S, V), np.int32)
                 counts = np.zeros((S,), np.int32)
@@ -1762,7 +1769,7 @@ class DecodeEngine:
                 outs, self._state = self._block.launch(
                     self._state, blocks, pos, counts, table,
                     site=f"decode:{self.name}:verify")
-            with _phase(inst, "readback"):
+            with _phase(inst, "readback", self._account):
                 outs = np.asarray(outs)
         except Exception as e:
             self._fail_boundary(_boundary_error(
@@ -1770,7 +1777,7 @@ class DecodeEngine:
                 "speculative decode failed"))
             return
         t_b1 = time.perf_counter()
-        with _phase(inst, "emit"):
+        with _phase(inst, "emit", self._account):
             self._last_boundary = time.monotonic()
             n_decoded = n_prompt = n_answer = 0
             for slot, req in ready.items():
@@ -1830,7 +1837,7 @@ class DecodeEngine:
             # is timed only where there is a request to admit or advance
             busy = self._active or self._waiting or \
                 not self._pending.empty()
-            with _phase(inst if busy else None, "admit"):
+            with _phase(inst if busy else None, "admit", self._account):
                 self._admit(inst)
                 for req in list(self._active.values()):
                     # until the launch of its first token is dispatched
@@ -1838,6 +1845,7 @@ class DecodeEngine:
                         req.ttft_boundaries += 1
             if not self._active:
                 self._last_boundary = None   # idle: nothing to wedge
+                self._account.clear()        # nor an interval to observe
                 self._wake.wait(0.05)
                 self._wake.clear()
                 continue
@@ -1853,3 +1861,4 @@ class DecodeEngine:
             else:
                 self._step_boundary(inst)
         self._flight = None     # closed: what is in flight is dropped
+        self._account.clear()

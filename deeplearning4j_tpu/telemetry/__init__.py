@@ -85,7 +85,8 @@ from deeplearning4j_tpu.telemetry.registry import (
     SECONDS_BUCKETS, STEP_HELP, ServingInstruments, Timer,
     collect_device_memory, disable, enable, enabled, etl_instruments,
     fleet_instruments, get_registry, log_buckets, loop_instruments,
-    moe_instruments, serving_instruments, set_registry, span)
+    moe_instruments, serving_instruments, set_registry, span, startup_done,
+    watch_process)
 
 __all__ = [
     "BYTES_BUCKETS", "CapacityError", "Counter", "DeviceOomError",
@@ -101,5 +102,10 @@ __all__ = [
     "health", "hlo_audit", "log_buckets", "loop_instruments",
     "memledger", "moe_instruments", "profiler", "prometheus",
     "serving_instruments",
-    "set_registry", "slo", "span", "timeseries", "tracing",
+    "set_registry", "slo", "span", "startup_done", "timeseries", "tracing",
 ]
+
+# the compile and collection listeners go in now, before the importing
+# module can build its weights (registry.watch_process says why); they stay
+# silent under `telemetry.disable()`
+watch_process()

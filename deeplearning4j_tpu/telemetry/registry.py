@@ -17,9 +17,14 @@ Design constraints (ISSUE 1 tentpole):
 - Timer doubles as a `jax.profiler.TraceAnnotation` context so the
   host-side span shows up in XPlane device traces (TensorBoard) at the
   same wall-clock position as the device work it covers;
-- compile visibility comes from a `jax.monitoring` event listener (the
+- compile visibility comes from `jax.monitoring` listeners (the
   jit-cache-miss hook): every backend compile increments
-  `dl4j_compile_total` and adds to `dl4j_compile_seconds_total`;
+  `dl4j_compile_total` and adds to `dl4j_compile_seconds_total`, tracing,
+  lowering and the persistent cache's reads have running totals beside
+  them, and the process's start-up account is frozen from those at its
+  first train step or decode boundary (`startup_done`);
+- the process's own pauses come from a `gc.callbacks` entry installed with
+  those listeners (`dl4j_process_gc_*`);
 - nothing here touches a device on the record path (`memory_stats` is
   read only when an exporter asks for it).
 """
@@ -27,15 +32,23 @@ Design constraints (ISSUE 1 tentpole):
 from __future__ import annotations
 
 import math
+import os
 import threading
 import time
 from bisect import bisect_right
+from collections import defaultdict, deque
 
 # -- module state ------------------------------------------------------------
 
 _state = {"enabled": True, "registry": None}
 _lock = threading.Lock()
-_compile_hook_installed = False
+_process_watched = False
+# where /proc does not give the process's start, the start-up account runs
+# from here (`startup_done`)
+_IMPORTED_AT = time.perf_counter()
+# the process's collection seconds so far, in a cell the collector's
+# callback writes and a decode engine's thread reads (`_install_gc_hook`)
+_gc_seconds = [0.0]
 
 
 def enabled() -> bool:
@@ -44,8 +57,9 @@ def enabled() -> bool:
 
 def enable():
     _state["enabled"] = True
-    _install_compile_hook()
-    return get_registry()
+    reg = get_registry()
+    _process()      # a registry swapped in gets the process-wide series
+    return reg
 
 
 def disable():
@@ -53,8 +67,8 @@ def disable():
 
 
 def get_registry() -> "MetricsRegistry":
-    """The process-wide registry (created lazily; compile hook installed
-    on first use)."""
+    """The process-wide registry (created lazily; the process's listeners
+    installed on first use if `telemetry`'s import has not)."""
     reg = _state["registry"]
     if reg is None:
         with _lock:
@@ -62,7 +76,8 @@ def get_registry() -> "MetricsRegistry":
             if reg is None:
                 reg = MetricsRegistry()
                 _state["registry"] = reg
-    _install_compile_hook()
+                _bind_process(reg)
+    watch_process()
     return reg
 
 
@@ -179,8 +194,8 @@ def log_buckets(lo, hi, per_decade=4):
     if not (lo > 0 and hi > lo):
         raise ValueError(f"need 0 < lo < hi, got {lo}, {hi}")
     n = int(math.ceil(math.log10(hi / lo) * per_decade)) + 1
-    # 3 significant digits keep the exposition readable; per_decade <= 10
-    # keeps rounded bounds strictly increasing
+    # 3 significant digits keep the exposition readable; a step of 1% or
+    # more (per_decade <= 200) keeps rounded bounds strictly increasing
     return tuple(float(f"{lo * 10 ** (i / per_decade):.3g}")
                  for i in range(n))
 
@@ -246,12 +261,13 @@ class Timer:
     covers. Reusable (one observation per with-block); also usable
     standalone with histogram=None as a pure trace annotation."""
 
-    __slots__ = ("histogram", "name", "exemplar", "_t0", "_ann")
+    __slots__ = ("histogram", "name", "exemplar", "seconds", "_t0", "_ann")
 
     def __init__(self, histogram, name):
         self.histogram = histogram
         self.name = name
         self.exemplar = None   # trace id attached to the observation
+        self.seconds = 0.0     # the last with-block's duration
         self._t0 = 0.0
         self._ann = None
 
@@ -267,7 +283,7 @@ class Timer:
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
+        dt = self.seconds = time.perf_counter() - self._t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
             self._ann = None
@@ -576,6 +592,33 @@ DECODE_STATE_STARTS_HELP = ("Requests whose state by slot started from "
                             "refuses the prefix cache for such a model)")
 DECODE_QUEUE_WAIT_HELP = ("Seconds from decode submit to the boundary "
                           "at which the request took a slot")
+DECODE_INTERVAL_HELP = ("Seconds from one token-step dispatch of the decode "
+                        "engine to the next, where the engine did not idle "
+                        "in between (an idle poll, a failed boundary, a "
+                        "block or verify boundary and close() forget the "
+                        "last dispatch): every such interval of the "
+                        "engine's life")
+DECODE_INTERVAL_MAX_HELP = ("The longest interval that "
+                            "dl4j_decode_interval_seconds observed, exact")
+DECODE_BETWEEN_HELP = ("Of an observed dispatch interval, the seconds the "
+                       "engine's thread spent under none of its five phase "
+                       "spans: the wait for the interpreter lock between "
+                       "them and whatever has no span")
+DECODE_INTERVAL_GC_HELP = ("The process's collection seconds "
+                           "(dl4j_process_gc_pause_seconds_total) that fell "
+                           "inside observed dispatch intervals")
+DECODE_LONGEST_HELP = ("The longest dispatch interval split by what the "
+                       "engine's thread did in it (admit|build|dispatch|"
+                       "readback|emit: seconds under that phase's span, a "
+                       "collection inside it taken out; gc: the process's "
+                       "collection seconds inside the interval; between: "
+                       "the rest); rewritten whenever "
+                       "dl4j_decode_interval_max_seconds is, and the parts "
+                       "add up to it")
+# fine enough to take the time above a threshold from the buckets alone: a
+# bound every 15.5%, so a bucket's middle is within 7% of what fell in it
+INTERVAL_BUCKETS = log_buckets(1e-4, 1e2, per_decade=16)
+LONGEST_PARTS = DECODE_PHASES + ("between", "gc")
 
 
 MOE_CHOICES_HELP = ("Expert choices the sparse layer's router made "
@@ -644,6 +687,52 @@ def moe_instruments(model):
     return MoeInstruments(get_registry(), model)
 
 
+class DispatchAccount:
+    """What a decode engine keeps from one token-step dispatch to the
+    next, for `ServingInstruments.dispatched`: the dispatch's stamp (None
+    once the engine has idled or run something else, so that waiting for
+    traffic is never an interval), the seconds its thread has spent under
+    each phase Timer since, the collection seconds that fell inside those,
+    and the process's collection total at the stamp."""
+
+    __slots__ = ("stamp", "spent", "paused", "gc_mark")
+
+    def __init__(self):
+        self.stamp = None
+        self.spent = dict.fromkeys(DECODE_PHASES, 0.0)
+        self.paused = dict.fromkeys(DECODE_PHASES, 0.0)
+        self.gc_mark = 0.0
+
+    def clear(self):
+        """Forget the last dispatch: the time to the next is no interval."""
+        self.stamp = None
+
+
+class _PhaseTimer(Timer):
+    """A decode phase's Timer that also adds its seconds, and the
+    collection seconds inside them, to the engine's DispatchAccount."""
+
+    __slots__ = ("_account", "_phase", "_gc0")
+
+    def __init__(self, histogram, name, phase, account):
+        super().__init__(histogram, name)
+        self._account, self._phase = account, phase
+        self._gc0 = 0.0
+
+    def __enter__(self):
+        self._gc0 = _gc_seconds[0]
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        account = self._account
+        account.spent[self._phase] += self.seconds
+        paused = _gc_seconds[0] - self._gc0
+        if paused:
+            account.paused[self._phase] += paused
+        return False
+
+
 class ServingInstruments:
     """Bound per-model serving instruments (mirrors LoopInstruments:
     obtained once per batcher, None when telemetry is disabled, so a
@@ -655,7 +744,9 @@ class ServingInstruments:
                  "prefix_hits", "prefix_misses", "ttft", "_accepted",
                  "kv_occupancy", "_phases", "_boundaries", "_positions",
                  "kv_fill_sum", "live_pages_sum", "overlapped",
-                 "decode_queue_wait", "_registry", "_moe", "_state_starts")
+                 "decode_queue_wait", "_registry", "_moe", "_state_starts",
+                 "interval", "interval_max", "between", "interval_gc",
+                 "_longest")
 
     def __init__(self, registry, model):
         self.model = model
@@ -733,6 +824,23 @@ class ServingInstruments:
         self.decode_queue_wait = registry.histogram(
             "dl4j_decode_queue_wait_seconds", DECODE_QUEUE_WAIT_HELP,
             ("model",)).labels(model=model)
+        self.interval = registry.histogram(
+            "dl4j_decode_interval_seconds", DECODE_INTERVAL_HELP,
+            ("model",), buckets=INTERVAL_BUCKETS).labels(model=model)
+        self.interval_max = registry.gauge(
+            "dl4j_decode_interval_max_seconds", DECODE_INTERVAL_MAX_HELP,
+            ("model",)).labels(model=model)
+        self.between = registry.histogram(
+            "dl4j_decode_between_phases_seconds", DECODE_BETWEEN_HELP,
+            ("model",), buckets=INTERVAL_BUCKETS).labels(model=model)
+        self.interval_gc = registry.counter(
+            "dl4j_decode_interval_gc_seconds_total",
+            DECODE_INTERVAL_GC_HELP, ("model",)).labels(model=model)
+        longest = registry.gauge(
+            "dl4j_decode_longest_interval_seconds", DECODE_LONGEST_HELP,
+            ("model", "part"))
+        self._longest = {p: longest.labels(model=model, part=p)
+                         for p in LONGEST_PARTS}
 
     def request(self, outcome):
         self._requests.labels(model=self.model, outcome=outcome).inc()
@@ -747,11 +855,42 @@ class ServingInstruments:
     def accepted(self, outcome, n=1):
         self._accepted.labels(model=self.model, outcome=outcome).inc(n)
 
-    def phase(self, phase):
+    def phase(self, phase, account):
         """A Timer over one phase of a decode boundary: the histogram
-        and, on the profiler's clock, the span `dl4j.decode.<phase>`."""
+        and, on the profiler's clock, the span `dl4j.decode.<phase>`. Its
+        seconds also go to the engine's DispatchAccount."""
         histogram, annotation = self._phases[phase]
-        return histogram.time(annotation)
+        return _PhaseTimer(histogram, annotation, phase, account)
+
+    def dispatched(self, account, t_b0):
+        """A token step is dispatched at `t_b0`. Where the account holds
+        the dispatch before it, the time between the two is one interval:
+        observed, with what of it lay under no phase span and the
+        collections inside it, and split by part where it is the longest
+        yet. The account then starts again from this dispatch."""
+        gc_now = _gc_seconds[0]
+        prev, account.stamp = account.stamp, t_b0
+        spent, paused = account.spent, account.paused
+        if prev is not None:
+            seconds = t_b0 - prev
+            between = seconds - sum(spent.values())
+            collected = gc_now - account.gc_mark
+            self.interval.observe(seconds)
+            self.between.observe(between)
+            if collected > 0:
+                self.interval_gc.inc(collected)
+            if seconds > self.interval_max.value:
+                self.interval_max.set(seconds)
+                # a phase's part is net of the collections inside it, so
+                # that the seven parts add up to the interval
+                for p in DECODE_PHASES:
+                    self._longest[p].set(spent[p] - paused[p])
+                self._longest["gc"].set(collected)
+                self._longest["between"].set(
+                    between + sum(paused.values()) - collected)
+        account.gc_mark = gc_now
+        for p in DECODE_PHASES:
+            spent[p] = paused[p] = 0.0
 
     def state_start(self, nbytes):
         """One request of a model that holds `nbytes` of state by slot
@@ -887,41 +1026,153 @@ def fleet_instruments():
     return FleetInstruments(get_registry())
 
 
-# -- compile visibility (jit-cache-miss hook) --------------------------------
+# -- the process's own account: compiles, start-up, collections --------------
 
 COMPILE_HELP = "XLA backend compiles observed in this process"
+COMPILE_SECONDS_HELP = ("Seconds inside jax's backend-compile event. Since "
+                        "jax 0.9 that event wraps the persistent cache's "
+                        "read, so a hit fires it too, with the retrieval's "
+                        "time: the seconds that really compiled are this "
+                        "total minus dl4j_compile_stage_seconds_total"
+                        "{stage=\"cache_load\"}")
+COMPILE_STAGE_HELP = ("Seconds this process spent on the way to an "
+                      "executable beside the backend compile, by stage: "
+                      "trace (Python to jaxpr) and lower (jaxpr to an MLIR "
+                      "module), each span's own time with the spans nested "
+                      "in it taken out, and cache_load (reading a hit out "
+                      "of the persistent compilation cache)")
+CACHE_HITS_HELP = ("Executables this process took from jax's persistent "
+                   "compilation cache")
+CACHE_MISSES_HELP = ("Executables this process compiled and wrote to jax's "
+                     "persistent compilation cache")
+GC_PAUSE_HELP = ("Seconds this process spent in garbage collections, by "
+                 "generation: every Python thread stands still meanwhile")
+GC_COLLECTIONS_HELP = "Garbage collections of this process, by generation"
+GC_PAUSE_MAX_HELP = "The longest single garbage collection of this process"
+STARTUP_HELP = ("What this process's start cost, frozen at its first train "
+                "step or delivered decode boundary, by part: total (process "
+                "start, or where /proc does not say the telemetry module's "
+                "import, to the freeze), trace, lower and cache_load "
+                "(dl4j_compile_stage_seconds_total as it stood), compile "
+                "(dl4j_compile_seconds_total minus cache_load: cache misses "
+                "only). What total holds beyond the parts is the imports, "
+                "the device's opening, the weights and the caller's own "
+                "work")
+STARTUP_EXECUTABLES_HELP = ("Executables acquired before the start-up "
+                            "account froze, by outcome: hit (read from the "
+                            "persistent compilation cache) or compiled")
+GC_SPAN = "dl4j.process.gc"
+COMPILE_STAGES = ("trace", "lower", "cache_load")
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# the spans whose own time is a stage's; a backend compile has no stage (its
+# seconds come from the duration event) and is taken out of what it nests in
+_SPAN_STAGES = {"/jax/core/compile/jaxpr_trace_duration": "trace",
+                "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+                _COMPILE_EVENT: None}
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "cache_hits",
+                 "/jax/compilation_cache/cache_misses": "cache_misses"}
 
 
-def _install_compile_hook():
-    """Register a jax.monitoring listener once per process: every
-    backend compile (a jit cache miss reaching XLA) bumps
-    dl4j_compile_total / dl4j_compile_seconds_total. The listener checks
-    the enabled flag first, so disabling telemetry silences it."""
-    global _compile_hook_installed
-    if _compile_hook_installed:
+class ProcessInstruments:
+    """The process-wide series, bound once a registry so that the
+    collector's callback and jax's listeners look nothing up by name."""
+
+    __slots__ = ("registry", "compiles", "compile_seconds", "stage",
+                 "cache_hits", "cache_misses", "gc_pause", "gc_collections",
+                 "gc_pause_max")
+
+    def __init__(self, registry):
+        self.registry = registry
+        self.compiles = registry.counter("dl4j_compile_total", COMPILE_HELP)
+        self.compile_seconds = registry.counter(
+            "dl4j_compile_seconds_total", COMPILE_SECONDS_HELP)
+        stage = registry.counter("dl4j_compile_stage_seconds_total",
+                                 COMPILE_STAGE_HELP, ("stage",))
+        self.stage = {s: stage.labels(stage=s) for s in COMPILE_STAGES}
+        self.cache_hits = registry.counter(
+            "dl4j_compile_cache_hits_total", CACHE_HITS_HELP)
+        self.cache_misses = registry.counter(
+            "dl4j_compile_cache_misses_total", CACHE_MISSES_HELP)
+        pause = registry.counter("dl4j_process_gc_pause_seconds_total",
+                                 GC_PAUSE_HELP, ("generation",))
+        count = registry.counter("dl4j_process_gc_collections_total",
+                                 GC_COLLECTIONS_HELP, ("generation",))
+        self.gc_pause = [pause.labels(generation=g) for g in range(3)]
+        self.gc_collections = [count.labels(generation=g) for g in range(3)]
+        self.gc_pause_max = registry.gauge(
+            "dl4j_process_gc_pause_max_seconds", GC_PAUSE_MAX_HELP)
+
+
+_process_bound = [None]
+
+
+def _bind_process(registry):
+    """Bind the process-wide series to `registry`. Never from the
+    collector's callback: registering a family takes the registry's lock,
+    which the thread the collection interrupted may hold."""
+    try:
+        _process_bound[0] = ProcessInstruments(registry)
+    except Exception:   # a stub registry must break neither jit nor a swap
+        _process_bound[0] = None
+
+
+def _process():
+    """The process-wide series on the current registry (made if there is
+    none yet: the first compile precedes the first instrumented loop), or
+    None while telemetry is off or the registry is a stub."""
+    if not _state["enabled"]:
+        return None
+    reg = get_registry()
+    bound = _process_bound[0]
+    if bound is None or bound.registry is not reg:
+        _bind_process(reg)
+        bound = _process_bound[0]
+    return bound
+
+
+def watch_process():
+    """Register the jax.monitoring listeners and the collector's callback
+    once per process: every backend compile (a jit cache miss reaching
+    XLA, or since jax 0.9 a persistent-cache hit) bumps dl4j_compile_total
+    / dl4j_compile_seconds_total, tracing, lowering and cache reads add to
+    dl4j_compile_stage_seconds_total, the cache's outcomes are counted.
+    Every listener checks the enabled flag first, so disabling telemetry
+    silences them. Called when the `telemetry` package is imported (and
+    by `get_registry`, as before): a process's first compiles are its
+    weights', made as arguments of a trainer's or a decode model's
+    constructor, before any object of this package exists that could
+    install a listener. What has happened by then is the import of
+    `telemetry`, at the top of `parallel/step_engine.py` (so of both
+    trainers' modules) and of `serving/decode.py` (so of every decode
+    model's); the `fit()` loops import it when they first run, and freeze
+    no start-up account."""
+    global _process_watched
+    if _process_watched:
         return
     with _lock:
-        if _compile_hook_installed:
+        if _process_watched:
             return
-        _compile_hook_installed = True
+        _process_watched = True
+    _install_gc_hook()
     try:
         import jax.monitoring as monitoring
     except Exception:
         return
 
     def _on_duration(key, seconds, **kw):
-        if not _state["enabled"]:
+        if key == _CACHE_LOAD_EVENT:
+            bound = _process()
+            if bound is not None:
+                bound.stage["cache_load"].inc(seconds)
             return
-        reg = _state["registry"]
-        if reg is None or not key.endswith("backend_compile_duration"):
+        if key != _COMPILE_EVENT or not _state["enabled"]:
             return
-        try:
-            reg.counter("dl4j_compile_total", COMPILE_HELP).inc()
-            reg.counter("dl4j_compile_seconds_total",
-                        "Seconds spent in XLA backend compiles").inc(
-                            seconds)
-        except Exception:
-            pass  # stub registries without counter() must not break jit
+        bound = _process()
+        if bound is not None:
+            bound.compiles.inc()
+            bound.compile_seconds.inc(seconds)
         try:
             from deeplearning4j_tpu.telemetry import flight
 
@@ -938,7 +1189,143 @@ def _install_compile_hook():
         except Exception:
             pass  # the ledger must never break jit either
 
+    def _on_event(key, **kw):
+        which = _CACHE_EVENTS.get(key)
+        if which is not None:
+            bound = _process()
+            if bound is not None:
+                getattr(bound, which).inc()
+
+    # by thread, (start, seconds) of the spans it reported that no later
+    # span of its own has claimed as nested in it. jax reports a span as it
+    # ends, on the thread that ran it, the inner before the outer, and its
+    # durations nest: tracing a step traces every jitted function it calls,
+    # lowering traces again. A stage's total is each span's own time, so
+    # that the stages add up to the thread's wall time.
+    stacks = defaultdict(lambda: deque(maxlen=4096))
+
+    def _on_span(key, start, end, **kw):
+        if key not in _SPAN_STAGES or not _state["enabled"]:
+            return
+        unclaimed = stacks[threading.get_ident()]
+        seconds = end - start
+        own = seconds
+        while unclaimed and unclaimed[-1][0] >= start:
+            own -= unclaimed.pop()[1]
+        unclaimed.append((start, seconds))
+        stage = _SPAN_STAGES[key]
+        if stage is not None and own > 0:
+            bound = _process()
+            if bound is not None:
+                bound.stage[stage].inc(own)
+
     monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
+    monitoring.register_event_time_span_listener(_on_span)
+
+
+# the collection in progress: when it started, and its span if it has one
+_gc_open = [None, None]
+
+
+def _on_gc(phase, info):
+    """`gc.callbacks` entry: a collection's seconds into the cell the decode
+    engines read and into the pre-bound series, and a generation-2
+    collection as the span `dl4j.process.gc` on the profiler's clock. It
+    takes no lock and allocates nothing the collector tracks but that one
+    span's annotation."""
+    if phase == "start":
+        if not _state["enabled"]:
+            return
+        if info["generation"] == 2:
+            try:
+                import jax
+
+                _gc_open[1] = jax.profiler.TraceAnnotation(GC_SPAN)
+                _gc_open[1].__enter__()
+            except Exception:   # profiling unavailable: keep timing
+                _gc_open[1] = None
+        _gc_open[0] = time.perf_counter()
+        return
+    t0, span = _gc_open
+    if t0 is None:
+        return
+    seconds = time.perf_counter() - t0
+    _gc_open[0] = _gc_open[1] = None
+    if span is not None:
+        span.__exit__(None, None, None)
+    _gc_seconds[0] += seconds
+    bound = _process_bound[0]
+    if bound is not None and bound.registry is _state["registry"]:
+        generation = info["generation"]
+        bound.gc_pause[generation].inc(seconds)
+        bound.gc_collections[generation].inc()
+        if seconds > bound.gc_pause_max.value:
+            bound.gc_pause_max.set(seconds)
+
+
+def _install_gc_hook():
+    import gc
+
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+
+
+# -- the start-up account -----------------------------------------------------
+
+_startup = {"frozen": False}
+_startup_lock = threading.Lock()
+
+
+def _process_age():
+    """Seconds since this process started, from /proc, or None."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            # field 22, starttime in clock ticks since boot; the fields
+            # after the command's closing bracket start at the third
+            ticks = int(f.read().rsplit(b")", 1)[1].split()[19])
+        with open("/proc/uptime", "rb") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def startup_done():
+    """Freeze the start-up account: called by `StepEngine.run` once its
+    first call of the step has returned and by `DecodeEngine._deliver` at
+    its first delivered token-step boundary. The first call in the process
+    writes `dl4j_startup_seconds{part}` and `dl4j_startup_executables
+    {outcome}` from the running totals as they stand; every later call is
+    one flag read. The totals keep running: what was compiled after the
+    first step is the running total minus the frozen part."""
+    if _startup["frozen"] or not _state["enabled"]:
+        return
+    with _startup_lock:
+        if _startup["frozen"]:
+            return
+        _startup["frozen"] = True
+    bound = _process()
+    if bound is None:
+        return
+    since_import = time.perf_counter() - _IMPORTED_AT
+    total = _process_age()
+    if total is None or total < since_import:
+        total = since_import
+    load = bound.stage["cache_load"].value
+    parts = {"total": total, "trace": bound.stage["trace"].value,
+             "lower": bound.stage["lower"].value, "cache_load": load,
+             "compile": max(0.0, bound.compile_seconds.value - load)}
+    seconds = bound.registry.gauge("dl4j_startup_seconds", STARTUP_HELP,
+                                   ("part",))
+    for part, value in parts.items():
+        seconds.labels(part=part).set(value)
+    hits = bound.cache_hits.value
+    executables = bound.registry.gauge(
+        "dl4j_startup_executables", STARTUP_EXECUTABLES_HELP, ("outcome",))
+    executables.labels(outcome="hit").set(hits)
+    executables.labels(outcome="compiled").set(
+        max(0.0, bound.compiles.value - hits))
 
 
 # -- device memory (read on demand by exporters, never per step) -------------
