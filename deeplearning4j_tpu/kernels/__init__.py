@@ -6,7 +6,10 @@ LSTM path). Each kernel ships with an XLA fallback and parity tests.
 This module also owns the ROUTING decision between a kernel and its
 ``lax.scan`` fallback (:func:`recurrence_route`), so that the choice is
 made in one place and is never silent: every traced ``lstmLayer`` /
-``gruLayer`` call is counted in ``dl4j_recurrence_route_total{op,route}``.
+``gruLayer`` call is counted in ``dl4j_recurrence_route_total{op,route}``,
+and every traced token step of a paged decode model that has a kernel for
+its attention (:func:`decode_attention_route`; `latent_attention.py`) in
+``dl4j_decode_attention_route_total{model,route}``.
 """
 
 import contextlib
@@ -21,6 +24,13 @@ ROUTE_HELP = ("Traced lstmLayer/gruLayer calls by the implementation "
               "kernel, interpret = the same kernel under the Pallas "
               "interpreter, scan = the lax.scan lowering). Counted at "
               "trace time: once per compiled executable, not per step")
+
+DECODE_ROUTE_HELP = ("Traced token steps of a paged decode model by what "
+                     "reads the pool in its attention (kernel = the "
+                     "compiled Pallas paged-attention kernel, every live "
+                     "page read once where it lies; loop = the XLA loop "
+                     "over gathered chunks of live pages). Counted at "
+                     "trace time: once per traced executable, not per step")
 
 _tls = threading.local()
 
@@ -55,6 +65,36 @@ def recurrence_route(op: str, available: bool) -> str:
             "dl4j_recurrence_route_total", ROUTE_HELP, ("op", "route"))
         fam.local = True   # depends on the host's backend: scrape-only
         fam.labels(op=op, route=route).inc()
+    return route
+
+
+def _on_tpu():
+    """A compile for a described chip, on a host whose backend is the CPU,
+    steers this (as `models/causal_lm.py:_on_tpu`): scratch scripts and
+    tests, never an option of the program."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def decode_attention_route(model: str, available: bool) -> str:
+    """What one traced token step of decode model ``model`` (its class's
+    name) reads its pool with: ``"kernel"`` (the compiled paged-attention
+    kernel: TPU backend, and the model's shape gate passed, ``available``)
+    or ``"loop"`` (`serving/decode.py:live_page_attention`: the CPU, a
+    small page, a row that is no whole tile). Nothing but what the code
+    can observe decides, and the decision is counted in
+    ``dl4j_decode_attention_route_total``, so a replica whose step quietly
+    fell back to the loop shows up on /metrics."""
+    from deeplearning4j_tpu.telemetry import registry as _registry
+
+    route = "kernel" if available and _on_tpu() else "loop"
+    if _registry.enabled():
+        fam = _registry.get_registry().counter(
+            "dl4j_decode_attention_route_total", DECODE_ROUTE_HELP,
+            ("model", "route"))
+        fam.local = True   # depends on the host's backend: scrape-only
+        fam.labels(model=model, route=route).inc()
     return route
 
 

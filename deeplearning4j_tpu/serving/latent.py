@@ -14,11 +14,16 @@ the up-projection `wkv_b` into the query and the output,
 
 so a cached position is never expanded into heads: a page's scores are one
 `[H, kv_rank + rope_dim] x [kv_rank + rope_dim, page]` product and its
-values the first `kv_rank` of the same numbers a position. The loop over the
-batch's live pages and the split-K combination are `serving/decode.py`'s
-(`live_pages`, `live_page_attention`), shared with `TransformerDecodeModel`;
-this model brings the page's partial. Greedy argmax over the held rows of
-the vocabulary.
+values the first `kv_rank` of the same numbers a position. On a TPU, where
+the pool's pages fill whole tiles, a Pallas kernel reads each live page once
+from the pool where it lies and keeps a slot's running softmax on the chip
+(`kernels/latent_attention.py`; `wv_b` once a slot after it). Everywhere
+else (the CPU, a small page) the loop over the batch's live pages and the
+split-K combination are `serving/decode.py`'s (`live_pages`,
+`live_page_attention`), shared with `TransformerDecodeModel`; this model
+brings the page's partial. Which of the two a step was traced with is
+counted (`kernels.decode_attention_route`). Greedy argmax over the held
+rows of the vocabulary.
 
 Per-sequence determinism: attention, norms and the dense products are
 row-wise, and so is the expert share while its products run dense
@@ -111,6 +116,7 @@ class LatentDecodeModel:
         import jax
         import jax.numpy as jnp
 
+        from deeplearning4j_tpu.kernels import latent_attention
         from deeplearning4j_tpu.models.causal_lm import rope_tables
         from deeplearning4j_tpu.parallel.moe import moe_share_dense
 
@@ -130,6 +136,10 @@ class LatentDecodeModel:
         self.max_len = self.page * self.max_pages_per_slot
         self.n_pages = (int(n_pages) if n_pages is not None
                         else max_slots * max_pages_per_slot)
+        # whether the pool's pages are shapes the paged-attention kernel
+        # takes; the backend decides the rest, when a step is traced
+        self.kernel_fits = latent_attention.available(
+            self.n_heads, self.row, self.page, cfg.kv_rank, self.dtype)
         self.moe_layers = tuple(cfg.sparse_layers)
         # the step asks its expert share for `max_slots * top_k` rows
         self.moe_dense = bool(self.moe_layers) and moe_share_dense(
@@ -162,7 +172,12 @@ class LatentDecodeModel:
         lies; with the 576 numbers minor (four and a half lanes of 128)
         the device stores the pool position-minor all the same and
         every launch converts the whole pool there and back (PERF.md,
-        PR 32: the compiler's own choice, read from an AOT compile)."""
+        PR 32: the compiler's own choice, read from an AOT compile).
+        Both attention paths read it so: the kernel copies a page
+        ``[row, page]`` to VMEM as one contiguous piece of the pool,
+        which stays in HBM in this layout (a custom call's operand is
+        taken as it lies: no copy of the pool, PERF.md, PR 37); the
+        loop gathers chunks of such pages into a copy."""
         return (self.n_layers, self.n_pages + 1, self.row, self.page)
 
     def init_state(self):
@@ -178,11 +193,36 @@ class LatentDecodeModel:
         return {memledger.device_label():
                 math.prod(self._pool_shape()) * self.dtype.itemsize}
 
-    def _attend(self, q, wv_b, pool, li, live):
+    def _walk(self, pos, table):
+        """Once a step, for all its layers: the route the step's
+        attention takes, counted, and the order in which it reads the
+        pool: the kernel's walk over the live pages, or the loop's list
+        of them."""
+        from deeplearning4j_tpu import kernels
+        from deeplearning4j_tpu.kernels import latent_attention
+
+        route = kernels.decode_attention_route(type(self).__name__,
+                                               self.kernel_fits)
+        if route == "kernel":
+            return route, latent_attention.page_walk(pos, table, self.page)
+        return route, live_pages(pos, table, self.page)
+
+    def _attend(self, q, wv_b, pool, li, walk):
         """q [S, H, kv_rank + rope_dim] (the absorbed query beside the
         rotated one) against each slot's own positions of layer ``li``
-        -> each head's output [S, H, v_dim] float32. The partial of a
-        chunk of live pages: gather the pages out of the whole pool,
+        -> each head's output [S, H, v_dim] float32; ``walk`` is
+        `_walk`'s.
+
+        The kernel (a TPU, pages of whole tiles) is handed the whole
+        pool in HBM and reads each live page of each slot once, where
+        it lies, by a copy of that page alone into VMEM; a slot's
+        running maximum, sum and weighted latent stay on the chip, and
+        each head's normalised latent goes through its ``wv_b`` once a
+        slot. No gather, no partials, no combination, no loop in the
+        step.
+
+        The loop (everywhere else) reduces a chunk of live pages at a
+        time: gather the pages out of the whole pool into a copy,
         score every position against every head in one product, weigh
         the positions' first ``kv_rank`` numbers, and take each head's
         weighted latent through its ``wv_b`` there and then: the
@@ -192,7 +232,17 @@ class LatentDecodeModel:
         combination reads: PERF.md, PR 32)."""
         import jax.numpy as jnp
 
+        from deeplearning4j_tpu.kernels import latent_attention
+
         cfg, dt = self.cfg, self.dtype
+        route, live = walk
+        if route == "kernel":
+            o = latent_attention.latent_page_attention(
+                q, pool, live, layer=li, kv_rank=cfg.kv_rank,
+                scale=cfg.latent_scale)             # [S, H, kv_rank]
+            return jnp.einsum("hsr,hrd->hsd", o.swapaxes(0, 1), wv_b,
+                              preferred_element_type=jnp.float32
+                              ).swapaxes(0, 1)
         cols = jnp.arange(self.page)
 
         def partial(slot, pg, last):
@@ -257,7 +307,7 @@ class LatentDecodeModel:
         cos, sin = self._cos[pos], self._sin[pos]
         off = pos % self.page
         cols = jnp.arange(self.page)[None, :]
-        live = live_pages(pos, table, self.page)    # once a step
+        walk = self._walk(pos, table)               # once a step
         pool = state["latent"]
         h = params["embed"][tokens].astype(dt)
         counts = []
@@ -282,7 +332,7 @@ class LatentDecodeModel:
                               row[:, :, None], pages)
             pool = pool.at[li, pidx].set(pages)
             with jax.named_scope("mla.attend"):
-                o = self._attend(q, lp["wv_b"], pool, li, live)
+                o = self._attend(q, lp["wv_b"], pool, li, walk)
                 att = _mm(o.astype(dt).reshape(S, H * cfg.v_dim), lp["wo"])
             h = (h + att).astype(dt)
             u = rms_norm(h, lp["mlp_norm"], cfg.rms_eps).astype(dt)

@@ -51,6 +51,7 @@ bit-identical params).
 
 from __future__ import annotations
 
+import collections
 import itertools
 import logging
 import os
@@ -270,9 +271,30 @@ class MemLedger:
         self._claims: dict = {}
         self._totals: dict = {}       # (category, device) -> bytes
         self._lock = threading.Lock()
+        self._collected = collections.deque()   # keys, see release_soon
 
     # -- mutation ------------------------------------------------------------
+    def release_soon(self, category, name):
+        """For an owner's finalizer, and nothing else. The collector runs
+        it between any two instructions of any thread, this ledger's own
+        critical sections included, and `_lock` is not re-entrant: a
+        finalizer that took it (or the registry's, through the gauge)
+        under a collection that began inside `claims()` waited for its
+        own thread for ever. So it takes no lock: the key is queued, and
+        the claim goes at the ledger's next call (`_reap`), before
+        anything is read or published."""
+        self._collected.append((str(category), str(name)))
+
+    def _reap(self):
+        while self._collected:
+            try:
+                key = self._collected.popleft()
+            except IndexError:               # another thread took it
+                break
+            self.release(*key)
+
     def claim(self, category, name, nbytes, device, meta=None) -> Claim:
+        self._reap()
         key = (str(category), str(name))
         with self._lock:
             existing = self._claims.get(key)
@@ -322,6 +344,7 @@ class MemLedger:
     def release_prefix(self, category, name_prefix) -> int:
         """Release every claim in ``category`` whose name starts with
         ``name_prefix`` (rolling-update sweeps). Returns the count."""
+        self._reap()
         with self._lock:
             hits = [c for (cat, name), c in self._claims.items()
                     if cat == category and name.startswith(name_prefix)]
@@ -331,6 +354,7 @@ class MemLedger:
 
     # -- reads ---------------------------------------------------------------
     def claims(self, category=None) -> list:
+        self._reap()
         with self._lock:
             out = list(self._claims.values())
         if category is not None:
@@ -338,10 +362,12 @@ class MemLedger:
         return sorted(out, key=lambda c: -c.bytes)
 
     def get(self, category, name):
+        self._reap()
         with self._lock:
             return self._claims.get((str(category), str(name)))
 
     def total(self, category=None, device=None) -> int:
+        self._reap()
         with self._lock:
             return sum(v for (cat, dev), v in self._totals.items()
                        if (category is None or cat == category)
@@ -378,6 +404,7 @@ class MemLedger:
         """Refresh every (category, device) gauge plus the
         ``unattributed`` residual per device (scrape-time; see
         :func:`refresh_metrics`)."""
+        self._reap()
         fam = self._gauge()
         if fam is None:
             return
@@ -461,10 +488,18 @@ def claim_for_owner(owner, category, prefix, nbytes=None, tree=None,
     c = claim(category, tag, nbytes=nbytes, tree=tree, **meta)
     if c is not None and fresh:
         try:
-            weakref.finalize(owner, release, category, tag)
+            weakref.finalize(owner, _release_collected, category, tag)
         except TypeError:
             pass   # unweakrefable owner: the claim simply persists
     return c
+
+
+def _release_collected(category, name):
+    """`claim_for_owner`'s finalizer: queues the key and touches no lock
+    (`MemLedger.release_soon`)."""
+    led = _state["ledger"]
+    if led is not None:
+        led.release_soon(category, name)
 
 
 def release(category, name):

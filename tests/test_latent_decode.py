@@ -67,15 +67,16 @@ def model(dtype="float32", seed=1, experts_held=(0, 16), **kw):
         **geometry), weights, sizes
 
 
-def stepwise_logits(m, tokens):
+def stepwise_logits(m, tokens, start=0):
     """The token step's logits at every position of one sequence, in slot 1
     of the pool, a position a launch: prompt, then decode, through the
-    cache."""
+    cache (from position ``start`` on, over the zeros the pool starts
+    with: a short sequence that crosses a page's edge)."""
     kv = PagedKVCache(m.n_pages, m.page, m.max_pages_per_slot, m.max_slots)
-    kv.reserve(1, len(tokens))
+    kv.reserve(1, start + len(tokens))
     apply = jax.jit(m._apply)
     state, out = m.init_state(), []
-    for p, tok in enumerate(tokens):
+    for p, tok in enumerate(tokens, start):
         feed = np.zeros(m.max_slots, np.int32)
         pos = np.zeros(m.max_slots, np.int32)
         table = np.zeros_like(kv.table)
@@ -411,3 +412,229 @@ def test_chunked_prefill_gives_the_token_steps_answers():
         finally:
             eng.close()
     assert answers[0] == answers[1]
+
+
+# -- ISSUE 37: the paged-attention kernel under the latent step ---------------
+# (`kernels/latent_attention.py`, through the Pallas interpreter on the CPU)
+
+from deeplearning4j_tpu import kernels  # noqa: E402
+from deeplearning4j_tpu.kernels import latent_attention  # noqa: E402
+from deeplearning4j_tpu.serving.decode import live_pages  # noqa: E402
+
+PAGE, PAGES, SLOTS = 128, 3, 4
+
+
+def paged_model(dtype="float32", rope=8, **kw):
+    """A model whose pool the kernel takes: 8 heads on pages ``[24, 128]``
+    (latent 16 over 8 rotary numbers: whole float32 tiles; over 16, whole
+    bfloat16 tiles)."""
+    cfg = config()
+    cfg["published"] = dict(PUBLISHED, num_attention_heads=8,
+                            num_key_value_heads=8, qk_rope_head_dim=rope)
+    sizes = driver.reference_sizes(cfg)
+    geometry = dict(max_slots=SLOTS, page=PAGE, max_pages_per_slot=PAGES)
+    geometry.update(kw)
+    return LatentDecodeModel(
+        driver.to_program(plain.draw_params(1, sizes)),
+        driver.program_config(cfg, compute_dtype=dtype), dtype=dtype,
+        **geometry)
+
+
+@pytest.fixture
+def interpreted(monkeypatch):
+    """The step's kernel branch on the CPU: the route steered as a compile
+    for a described chip steers it, the kernel through the interpreter."""
+    import functools
+
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    monkeypatch.setattr(
+        latent_attention, "latent_page_attention",
+        functools.partial(latent_attention.latent_page_attention,
+                          interpret=True))
+
+
+def own_tables(rng=None):
+    """Every slot its own pages, in order or in an order `rng` draws."""
+    ids = np.arange(1, SLOTS * PAGES + 1, dtype=np.int32)
+    if rng is not None:
+        ids = rng.permutation(ids).astype(np.int32)
+    return ids.reshape(SLOTS, PAGES)
+
+
+def idle(pos, table, *slots):
+    """As the engine hands over a slot that is not fed: position 0 and a
+    zero row of the table (the scratch page)."""
+    pos, table = np.array(pos, np.int32), table.copy()
+    for s in slots:
+        pos[s], table[s] = 0, 0
+    return pos, table
+
+
+CASES = {
+    "a slot at position 0": lambda: ([0, 5, 200, 300], own_tables()),
+    "a context ends on a page's last column":
+        lambda: ([PAGE - 1, 2 * PAGE - 1, 40, 300], own_tables()),
+    "a context ends on the next page's first column":
+        lambda: ([PAGE, 2 * PAGE, 40, 300], own_tables()),
+    "a full table": lambda: ([PAGES * PAGE - 1] * SLOTS, own_tables()),
+    "idle slots between fed ones":
+        lambda: idle([77, 0, 290, 0], own_tables(), 1, 3),
+    "no shared page, tables in scrambled order":
+        lambda: ([383, 130, 255, 256],
+                 own_tables(np.random.default_rng(5))),
+}
+
+
+def both_routes(m, pos, table, seed=0, layer=1, pool=None):
+    """`_attend` of layer `layer` over one random pool by the loop and by
+    the (interpreted) kernel -> two ``[S, H, v_dim]`` float32 arrays."""
+    rng = np.random.default_rng(seed)
+    dt = m.dtype
+    if pool is None:
+        pool = rng.normal(size=m._pool_shape())
+    pool = jnp.asarray(pool, dt)
+    q = jnp.asarray(rng.normal(size=(m.max_slots, m.n_heads, m.row)), dt)
+    wv_b = m.params["layers"][layer]["wv_b"]
+    pos, table = jnp.asarray(pos, jnp.int32), jnp.asarray(table, jnp.int32)
+    loop = m._attend(q, wv_b, pool, layer,
+                     ("loop", live_pages(pos, table, m.page)))
+    kernel = m._attend(q, wv_b, pool, layer,
+                       ("kernel", latent_attention.page_walk(
+                           pos, table, m.page)))
+    return np.asarray(loop), np.asarray(kernel)
+
+
+@pytest.mark.parametrize("case", list(CASES))
+def test_the_kernel_gives_what_the_loop_gives_on_the_same_pool(
+        case, interpreted, assert_rows_close):
+    """Float32 on both sides, so the same precision; the kernel sums a
+    slot's pages into one running softmax where the loop reduces each page
+    on its own and combines: another order of summation, `assert_rows_close`'s
+    tolerance (1e-5 relative; a wrong page, column or mask misses by orders
+    more: the control below)."""
+    m = paged_model()
+    assert m.kernel_fits
+    pos, table = CASES[case]()
+    loop, kernel = both_routes(m, pos, table)
+    assert np.isfinite(kernel).all()
+    assert_rows_close(kernel, loop)
+    # the control: one position fewer is another answer
+    shorter = np.maximum(np.asarray(pos) - 1, 0)
+    fed = np.asarray(pos) > 0
+    _, other = both_routes(m, shorter, table)
+    assert np.abs(other - loop)[fed].max() > 1e-3
+
+
+def test_the_kernel_in_bfloat16_reads_what_the_loop_reads(interpreted):
+    """As served: bfloat16 operands, float32 accumulation on both sides.
+    The loop rounds each page's unnormalised weighted latent to bfloat16
+    before `wv_b`, the kernel a slot's normalised one: the same precision
+    at another place, so the two differ by bfloat16's rounding (2^-8) of an
+    output, not by more."""
+    m = paged_model("bfloat16", rope=16)
+    assert m.kernel_fits and not paged_model("bfloat16").kernel_fits
+    loop, kernel = both_routes(m, *CASES["a full table"]())
+    scale = np.abs(loop).max()
+    assert np.abs(kernel - loop).max() < 2 ** -6 * scale
+
+
+def test_a_slots_output_does_not_change_with_what_its_neighbours_hold(
+        interpreted):
+    """Exact: the same program, and a slot's pages, running softmax and
+    output are its own (what differs is the place of its pages in the walk
+    and in the ring of copies)."""
+    m = paged_model()
+    table = own_tables(np.random.default_rng(7))
+    rng = np.random.default_rng(11)
+    pool = rng.normal(size=m._pool_shape())
+    _, among = both_routes(m, [383, 130, 255, 256], table, pool=pool)
+    others = pool.copy()
+    for s in (0, 1, 3):                 # slot 2's neighbours: other rows
+        others[:, table[s]] = rng.normal(size=others[:, table[s]].shape)
+    pos, lone = idle([5, 130, 255, 0], table, 3)
+    pos[1] = 0                          # fed, but at another position
+    _, alone = both_routes(m, pos, lone, pool=others)
+    np.testing.assert_array_equal(among[2], alone[2])
+
+
+def test_the_step_through_the_kernel_gives_the_loops_logits(
+        interpreted, monkeypatch):
+    """The whole token step, a sequence that crosses a page's edge through
+    the pool, by the kernel branch and by the loop: the same logits at
+    float32's tolerance."""
+    m = paged_model()
+    through_kernel = stepwise_logits(m, TOKENS[:7], start=PAGE - 3)
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: False)
+    through_loop = stepwise_logits(m, TOKENS[:7], start=PAGE - 3)
+    np.testing.assert_allclose(through_kernel, through_loop, rtol=TOL,
+                               atol=TOL)
+
+
+def test_the_step_that_takes_the_kernel_holds_no_loop(monkeypatch):
+    """Lowered for the TPU (no chip needed): a custom call a layer and no
+    `while` in the step; the loop's step holds its `while`. The benchmark's
+    `serve.latent_attention_roofline` reads the step's `while` operations:
+    one left in the kernel's step would be read as the whole attention."""
+    m = paged_model()
+    z = np.zeros(SLOTS, np.int32)
+    args = (m.params, m.init_state(), z, z,
+            np.zeros((SLOTS, PAGES), np.int32))
+
+    def lowered():
+        return jax.jit(m._fn).trace(*args).lower(
+            lowering_platforms=("tpu",)).as_text()
+
+    loop = lowered()
+    assert "stablehlo.while" in loop and "tpu_custom_call" not in loop
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    kernel = lowered()
+    assert "stablehlo.while" not in kernel
+    assert kernel.count("tpu_custom_call") == m.n_layers
+
+
+def route_count(model, route):
+    fam = telemetry.get_registry().counter(
+        "dl4j_decode_attention_route_total", kernels.DECODE_ROUTE_HELP,
+        ("model", "route"))
+    return fam.labels(model=model, route=route).value
+
+
+@pytest.mark.parametrize("why, geometry, fits", [
+    ("on the CPU", dict(page=PAGE), True),
+    ("a page of 16", dict(page=16), False),
+    ("a page that is no whole lane tile", dict(page=192), False)])
+def test_the_gate_says_loop_and_the_route_is_counted(why, geometry, fits):
+    """Nothing but what the code can observe decides: the backend, and the
+    pool's shape. A traced step is counted once under its route."""
+    m = paged_model(max_pages_per_slot=2, **geometry)
+    assert m.kernel_fits is fits
+    before = route_count("LatentDecodeModel", "loop")
+    state = m.init_state()
+    z = np.zeros(m.max_slots, np.int32)
+    m.step(state, z, z, np.zeros((m.max_slots, 2), np.int32))
+    assert route_count("LatentDecodeModel", "loop") == before + 1
+
+
+@pytest.mark.parametrize("heads, row, page, kv_rank, dtype, fits", [
+    (128, 576, 256, 512, "bfloat16", True),     # the served cell's pool
+    (128, 576, 16, 512, "bfloat16", False),     # a page of 16
+    (8, 24, 128, 16, "bfloat16", False),        # a row that is no whole
+    (8, 24, 128, 16, "float32", True),          # bfloat16 tile of 16
+    (4, 24, 128, 16, "float32", False),         # heads that fill no tile
+    (128, 576, 8192, 512, "bfloat16", False)])  # a ring beyond its VMEM
+def test_the_kernels_gate_by_shape(heads, row, page, kv_rank, dtype, fits):
+    assert latent_attention.available(heads, row, page, kv_rank,
+                                      dtype) is fits
+
+
+def test_the_kernel_route_is_counted_where_the_backend_is_a_tpu(monkeypatch):
+    before = route_count("LatentDecodeModel", "kernel")
+    assert kernels.decode_attention_route("LatentDecodeModel",
+                                          True) == "loop"
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: True)
+    assert kernels.decode_attention_route("LatentDecodeModel",
+                                          False) == "loop"
+    assert route_count("LatentDecodeModel", "kernel") == before
+    assert kernels.decode_attention_route("LatentDecodeModel",
+                                          True) == "kernel"
+    assert route_count("LatentDecodeModel", "kernel") == before + 1

@@ -153,6 +153,89 @@ class TestLedgerCore:
                        for k in reg_snap)   # excluded from aggregation
 
 
+class _Owner:
+    """Something a claim can be keyed to: weakly referable."""
+
+
+class TestAnOwnersFinalizer:
+    """A collection can begin between any two instructions, inside the
+    ledger's own critical sections too, and `MemLedger._lock` is not
+    re-entrant: the finalizer of `claim_for_owner` queues its key and
+    takes no lock (a tier-1 worker once waited for itself for ever in
+    `claims()`), and the ledger drops the claim at its next call."""
+
+    def test_it_does_not_wait_for_the_ledgers_lock(self, fresh_ledger):
+        import threading
+
+        led = memledger.get_memledger()
+        owners = [_Owner()]
+        memledger.claim_for_owner(owners[0], "train", "fit", nbytes=64)
+        # whoever holds the lock (the finalizer's own thread, when the
+        # collection began under it): the finalizer must come back
+        dropper = threading.Thread(target=owners.pop, daemon=True)
+        with led._lock:
+            dropper.start()
+            dropper.join(timeout=10)
+            waits = dropper.is_alive()
+        dropper.join(timeout=10)
+        assert not waits
+        assert led.claims("train") == []
+
+    def test_a_collection_under_the_lock_comes_back(self, fresh_ledger):
+        import threading
+
+        led = memledger.get_memledger()
+        owners = [_Owner()]
+        memledger.claim_for_owner(owners[0], "train", "fit", nbytes=64)
+
+        def collect_under_the_lock():
+            with led._lock:         # as `claims()` holds it
+                owners.pop()        # the finalizer runs here, in the
+                gc.collect()        # thread that holds the lock
+
+        # in a thread of its own, so that a finalizer that waits fails
+        # this test and does not stop the run
+        holder = threading.Thread(target=collect_under_the_lock,
+                                  daemon=True)
+        holder.start()
+        holder.join(timeout=10)
+        assert not holder.is_alive()
+        assert led.total("train") == 0
+
+    @pytest.mark.parametrize("read", [
+        lambda led, name: led.claims("train"),
+        lambda led, name: led.get("train", name),
+        lambda led, name: led.total("train"),
+        lambda led, name: led.top(),
+        lambda led, name: led.release_prefix("train", "fit")],
+        ids=["claims", "get", "total", "top", "release_prefix"])
+    def test_every_read_sees_the_claim_gone(self, fresh_ledger, read):
+        led = memledger.get_memledger()
+        owner = _Owner()
+        name = memledger.claim_for_owner(owner, "train", "fit",
+                                         nbytes=64).name
+        assert read(led, name)
+        del owner
+        gc.collect()
+        assert not read(led, name)
+
+    def test_the_scrape_publishes_the_total_without_it(self, fresh_ledger):
+        from deeplearning4j_tpu.telemetry import prometheus
+
+        dev = memledger._device_label()
+        owner = _Owner()
+        memledger.claim_for_owner(owner, "train", "fit", nbytes=64)
+        memledger.claim("train", "kept", nbytes=32, device=dev)
+        del owner
+        gc.collect()
+        memledger.refresh_metrics()
+        text = prometheus.render(fresh_ledger, collect_system=False)
+        line = [ln for ln in text.splitlines()
+                if ln.startswith("dl4j_device_memory_claimed_bytes")
+                and 'category="train"' in ln]
+        assert len(line) == 1 and float(line[0].split()[-1]) == 32
+
+
 # ---------------------------------------------------------------------------
 # registrars: train loops
 # ---------------------------------------------------------------------------
