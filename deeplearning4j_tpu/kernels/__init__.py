@@ -9,7 +9,10 @@ made in one place and is never silent: every traced ``lstmLayer`` /
 ``gruLayer`` call is counted in ``dl4j_recurrence_route_total{op,route}``,
 and every traced token step of a paged decode model that has a kernel for
 its attention (:func:`decode_attention_route`; `latent_attention.py`) in
-``dl4j_decode_attention_route_total{model,route}``.
+``dl4j_decode_attention_route_total{model,route}``. `stream_maps.py` (the
+maps of a residual path of several streams) is taken by
+`models/causal_lm.py:stream_maps` where `_on_tpu()` says so and the
+positions fill whole lanes; the trace names its calls `stream_maps`.
 """
 
 import contextlib

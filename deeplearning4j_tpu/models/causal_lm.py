@@ -1,6 +1,11 @@
 """A causal language model whose block is described by data, TPU-first.
 
 One pre-norm block, `h = x + Attn_l(RMSNorm(x))`, `y = h + MLP_l(RMSNorm(h))`,
+or, where the description gives a position `streams` > 1 residual vectors,
+each sublayer reading a learned combination of them and writing back through
+a doubly stochastic mix (`stream_maps`, `stream_read`, `stream_write`: the
+residual path is part of the description, and the plain sum is its one-stream
+case),
 where each layer says for itself which attention it has (`full` or
 `sliding`: causal, a sliding layer also masks `i - j >= sliding_window`;
 `latent`: causal, queries and keys and values through low-rank latents, one
@@ -31,6 +36,7 @@ block description, token by token, over a paged pool of latents, and
 
 from __future__ import annotations
 
+import functools
 import math
 from dataclasses import dataclass
 
@@ -110,6 +116,14 @@ class CausalLMConfig:
     ssm_state: int = 0
     conv_kernel: int = 0
     time_step: tuple = (0.001, 0.1, 1e-4)
+    # the residual path: `streams` residual vectors a position (1: the plain
+    # `x + f(norm(x))`); with more, the iterations and the `eps` of the
+    # Sinkhorn normalisation that makes a sublayer's mixing map doubly
+    # stochastic, and the clamp (min, max) on that map's logits before `exp`
+    streams: int = 1
+    sinkhorn_iters: int = 0
+    sinkhorn_eps: float = 0.0
+    res_clamp: tuple = (0.0, 0.0)
 
     @classmethod
     def from_hybrid_published(cls, published: dict, layer_ids=None,
@@ -170,7 +184,34 @@ class CausalLMConfig:
         the first `first_k_dense_replace` have a dense MLP. YaRN's two
         magnitude factors are equal there, so the tables carry none and
         the scores are scaled by (1 + 0.1 mscale ln factor)^2 beside
-        1 / sqrt(the query head's width)."""
+        1 / sqrt(the query head's width). `hc_mult` > 1 gives a position that
+        many residual streams (`hc_sinkhorn_iters`, `hc_eps`,
+        `mhc_h_res_clamp_min`, `mhc_h_res_clamp_max` beside it). A key whose
+        value cannot be honoured is refused by name, not ignored."""
+        refuse = lambda key, why: ValueError(  # noqa: E731
+            f"{key} = {published.get(key)!r}: {why}")
+        if published.get("moe_layer_freq", 1) != 1:
+            raise refuse("moe_layer_freq", "every layer after the leading "
+                         "dense ones is sparse here")
+        if published["n_shared_experts"] < 1:
+            raise refuse("n_shared_experts", "a sparse layer has a shared "
+                         "expert here")
+        if published["topk_method"] not in ("noaux_tc", "greedy"):
+            raise refuse("topk_method", "noaux_tc (biased scores, a group's "
+                         "mark its two best) and greedy (plain top-k) are "
+                         "written here")
+        streams = published.get("hc_mult", 1)
+        hc_keys = ("hc_sinkhorn_iters", "hc_eps", "mhc_h_res_clamp_min",
+                   "mhc_h_res_clamp_max")
+        if streams > 1 and any(k not in published for k in hc_keys):
+            raise refuse("hc_mult", "more than one residual stream needs "
+                         + ", ".join(k for k in hc_keys
+                                     if k not in published))
+        hyper = {} if streams == 1 else dict(
+            streams=streams, sinkhorn_iters=published["hc_sinkhorn_iters"],
+            sinkhorn_eps=published["hc_eps"],
+            res_clamp=(published["mhc_h_res_clamp_min"],
+                       published["mhc_h_res_clamp_max"]))
         ids = (range(published["num_hidden_layers"]) if layer_ids is None
                else layer_ids)
         heads = published["num_attention_heads"]
@@ -211,7 +252,8 @@ class CausalLMConfig:
             q_rank=published["q_lora_rank"],
             kv_rank=published["kv_lora_rank"], nope_dim=nope, rope_dim=rot,
             v_dim=published["v_head_dim"],
-            latent_scale=mscale * mscale / math.sqrt(nope + rot), **kw)
+            latent_scale=mscale * mscale / math.sqrt(nope + rot),
+            **{**hyper, **kw})
 
     @classmethod
     def from_published(cls, published: dict, num_layers=None,
@@ -305,6 +347,22 @@ def _mamba_init(cfg, heads, key, std):
             "w_out": _normal(k[2], (inner, cfg.hidden), std)}
 
 
+def _stream_init(cfg, key, std):
+    """One sublayer's residual maps: `phi [streams * hidden, 2 streams +
+    streams^2]` (the columns that make `H_pre`, `H_post` and, row by row,
+    `H_res`), the three `alpha` on its products and the `bias` under them,
+    all float32. The start is the plain residual as nearly as the maps can
+    say it, the choice here since a published config gives none: `alpha`
+    0.01, `H_pre = sigmoid(0)` on every stream, `H_post = 2 sigmoid(0) = 1`,
+    and `H_res` the identity but for `exp(-8)` off the diagonal."""
+    n = cfg.streams
+    return {"phi": _normal(key, (n * cfg.hidden, 2 * n + n * n), std),
+            "alpha": jnp.full((3,), 0.01, jnp.float32),
+            "bias": jnp.concatenate([
+                jnp.zeros((2 * n,)),
+                (-8.0 * (1.0 - jnp.eye(n))).reshape(-1)])}
+
+
 def init_params(cfg: CausalLMConfig, key) -> dict:
     d, hd, std = cfg.hidden, cfg.head_dim, INIT_STD
     keys = jax.random.split(key, 2 + len(cfg.layers))
@@ -319,6 +377,12 @@ def init_params(cfg: CausalLMConfig, key) -> dict:
             layer["attn_norm"] = jnp.ones((d,))
         if spec.mlp != "none":
             layer["mlp_norm"] = jnp.ones((d,))
+        if cfg.streams > 1:
+            ka, km = jax.random.split(k[7])
+            if spec.attention != "none":
+                layer["attn_streams"] = _stream_init(cfg, ka, std)
+            if spec.mlp != "none":
+                layer["mlp_streams"] = _stream_init(cfg, km, std)
         if spec.attention == "mamba":
             layer.update(_mamba_init(cfg, spec.heads, k[0], std))
         elif spec.attention == "latent":
@@ -511,6 +575,119 @@ def _splash(qg, kt, vt, window):
     return attend(qg, kt, vt)
 
 
+# -- the residual path --------------------------------------------------------
+
+def _total(parts):
+    """The sum of a few arrays as additions of them: elementwise work."""
+    return functools.reduce(jnp.add, parts)
+
+
+def streams_enter(x, cfg: CausalLMConfig):
+    """The embedding [..., d] as the residual path carries it: itself, or
+    `streams` copies of it [..., streams, d]."""
+    if cfg.streams == 1:
+        return x
+    return jnp.broadcast_to(x[..., None, :],
+                            (*x.shape[:-1], cfg.streams, x.shape[-1]))
+
+
+def streams_exit(x, cfg: CausalLMConfig):
+    """What the final norm reads: the one stream, or the sum of them
+    (float32 sum, rounded once)."""
+    if cfg.streams == 1:
+        return x
+    return jnp.sum(x.astype(jnp.float32), axis=-2).astype(x.dtype)
+
+
+def stream_maps(hp, x, cfg: CausalLMConfig, fused=False):
+    """The three maps of one sublayer from the streams it meets, x [...,
+    streams, d], and `hp` (`_stream_init`'s leaves); None for one stream.
+
+        xbar = vec(x) / sqrt(mean(vec(x)^2) + rms_eps)        (no gain)
+        [a | b | c] = xbar phi
+        pre = sigmoid(alpha_0 a + bias_a)                     n entries
+        post = 2 sigmoid(alpha_1 b + bias_b)                  n entries
+        m = exp(clip(alpha_2 mat(c) + bias_c, *res_clamp))    n x n entries
+        `sinkhorn_iters` times:  m /= column sums + eps;  m /= row sums + eps
+        res = m
+
+    -> {"pre": [n], "post": [n], "res": [n][n], "defect"}: every ENTRY of a
+    map is one float32 array over the positions, shaped as x's leading axes
+    (`stream_matrix` stacks them where a matrix is wanted), and so is
+    `defect`, the largest `|sum - 1|` over the rows and columns of `res`:
+    how far the iterations left it from doubly stochastic. Float32 at the
+    highest matmul precision whatever x's dtype: the maps decide what every
+    stream holds next, as a router's scores decide a row's experts.
+
+    The positions are the minor axis from the product on. What follows it
+    is `kernels/stream_maps.py`: `packed_maps`, or, where `fused` says that
+    no gradient is wanted (the decode step), the backend is a TPU and the
+    positions fill whole lanes, the same as one kernel call."""
+    if cfg.streams == 1:
+        return None
+    from deeplearning4j_tpu import kernels
+    from deeplearning4j_tpu.kernels import stream_maps as maps_kernel
+
+    n = cfg.streams
+    cols = 2 * n + n * n
+    with jax.named_scope("hc.maps"):
+        lead = x.shape[:-2]
+        xf = x.astype(jnp.float32).reshape(-1, n * x.shape[-1])
+        scale = jax.lax.rsqrt(jnp.mean(xf * xf, -1) + cfg.rms_eps)
+        z = jnp.einsum("rk,kf->fr", xf, hp["phi"].astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+        # each column's alpha: the three, spread over their maps' columns
+        spread = np.repeat(np.eye(3, dtype=np.float32), [n, n, n * n], 1)
+        by_map = jnp.sum(hp["alpha"].astype(jnp.float32)[:, None] * spread, 0)
+        z = z * scale * by_map[:, None] \
+            + hp["bias"].astype(jnp.float32)[:, None]
+        how = dict(n=n, iters=cfg.sinkhorn_iters, eps=cfg.sinkhorn_eps,
+                   clamp=cfg.res_clamp)
+        if fused and kernels._on_tpu() \
+                and maps_kernel.available(z.shape[1], n):
+            packed = maps_kernel.sinkhorn_maps(z, **how)
+        else:
+            packed = maps_kernel.packed_maps(z, **how)
+        entry = lambda k: packed[k].reshape(lead)  # noqa: E731
+        return {"pre": [entry(i) for i in range(n)],
+                "post": [entry(n + i) for i in range(n)],
+                "res": [[entry(2 * n + i * n + j) for j in range(n)]
+                        for i in range(n)],
+                "defect": entry(cols)}
+
+
+def stream_matrix(maps):
+    """`stream_maps`' `res` as one array [..., n, n]: `[..., i, j]` is what
+    stream i takes of stream j."""
+    return jnp.stack([jnp.stack(row, -1) for row in maps["res"]], -2)
+
+
+def stream_read(x, maps):
+    """What a sublayer's norm reads: x itself, or `sum_i pre_i x_i` of the
+    streams x [..., n, d] (float32 sum, rounded once to x's dtype)."""
+    if maps is None:
+        return x
+    with jax.named_scope("hc.mix"):
+        xf = x.astype(jnp.float32)
+        return _total(w[..., None] * xf[..., i, :]
+                      for i, w in enumerate(maps["pre"])).astype(x.dtype)
+
+
+def stream_write(x, y, maps):
+    """The sublayer's result y [..., d] (float32) taken into the residual
+    path: `x + y`, or `x'_i = sum_j res_ij x_j + post_i y` for the streams
+    x [..., n, d]; summed in float32, rounded once to x's dtype."""
+    if maps is None:
+        return (x + y).astype(x.dtype)
+    with jax.named_scope("hc.mix"):
+        xf, yf = x.astype(jnp.float32), y.astype(jnp.float32)
+        return jnp.stack([
+            _total(w[..., None] * xf[..., j, :] for j, w in enumerate(row))
+            + post[..., None] * yf
+            for row, post in zip(maps["res"], maps["post"])],
+            axis=-2).astype(x.dtype)
+
+
 # -- the block ----------------------------------------------------------------
 
 def rms_norm(x, g, eps):
@@ -670,22 +847,28 @@ def attention_block(lp, u, cfg: CausalLMConfig, spec: LayerSpec, tables):
 
 
 def layer_forward(lp, x, cfg: CausalLMConfig, spec: LayerSpec, tables):
-    """x [B, T, d] in the compute dtype -> (y, choices int32 [held experts],
+    """x [B, T, d] in the compute dtype ([B, T, streams, d] where the
+    residual path has more than one) -> (y, choices int32 [held experts],
     dropped int32); the two counts are nought on a dense layer."""
     dtype = x.dtype
-    b, t, d = x.shape
+    b, t, d = x.shape[0], x.shape[1], x.shape[-1]
     h = x
     if spec.attention != "none":
+        maps = stream_maps(lp.get("attn_streams"), x, cfg)
         with jax.named_scope("attention." + spec.attention):
-            u = rms_norm(x, lp["attn_norm"], cfg.rms_eps).astype(dtype)
-            h = (x + attention_block(lp, u, cfg, spec, tables)).astype(dtype)
+            u = rms_norm(stream_read(x, maps), lp["attn_norm"],
+                         cfg.rms_eps).astype(dtype)
+            h = stream_write(x, attention_block(lp, u, cfg, spec, tables),
+                             maps)
     count = cfg.experts_held[1]
     if spec.mlp != "sparse":
         choices = jnp.zeros((count,), jnp.int32)
         dropped = jnp.zeros((), jnp.int32)
         if spec.mlp == "none":
             return h, choices, dropped
-    u = rms_norm(h, lp["mlp_norm"], cfg.rms_eps).astype(dtype)
+    maps = stream_maps(lp.get("mlp_streams"), h, cfg)
+    u = rms_norm(stream_read(h, maps), lp["mlp_norm"],
+                 cfg.rms_eps).astype(dtype)
     if spec.mlp == "dense":
         with jax.named_scope("mlp.dense"):
             out = mlp_apply(lp["mlp"], u)
@@ -698,7 +881,7 @@ def layer_forward(lp, x, cfg: CausalLMConfig, spec: LayerSpec, tables):
         with jax.named_scope("moe.shared"):
             out = routed.reshape(b, t, d) + mlp_apply(lp["shared"], u,
                                                       cfg.expert_act)
-    return (h + out).astype(dtype), choices, dropped
+    return stream_write(h, out, maps), choices, dropped
 
 
 def checkpointed_layer(cfg: CausalLMConfig, spec: LayerSpec, tables):
@@ -726,14 +909,15 @@ def forward(params, cfg: CausalLMConfig, tokens):
     tables = {kind: rope_tables(cfg.rope[kind], cfg.rotary_width(kind), t)
               for kind in {s.attention for s in cfg.layers}
               if kind in cfg.rope}
-    x = params["embed"][tokens].astype(dtype)
+    x = streams_enter(params["embed"][tokens].astype(dtype), cfg)
     choices, dropped = [], []
     for lp, spec in zip(params["layers"], cfg.layers):
         x, c, dr = checkpointed_layer(cfg, spec, tables)(lp, x)
         if spec.mlp == "sparse":
             choices.append(c)
             dropped.append(dr)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps).astype(dtype)
+    x = rms_norm(streams_exit(x, cfg), params["final_norm"],
+                 cfg.rms_eps).astype(dtype)
     held = cfg.experts_held[1]
     return (x, jnp.stack(choices) if choices
             else jnp.zeros((0, held), jnp.int32),

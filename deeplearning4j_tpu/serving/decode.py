@@ -1429,18 +1429,24 @@ class DecodeEngine:
                 self._finish(req, error=err)
 
     def _model_step(self, state, tokens, pos, table):
-        """(tokens, state, counts) of one launch of the token step. A
-        model whose step routes tokens to experts returns its router's
-        counts beside the tokens: float32 ``[len(model.moe_layers), 5]``,
-        a row (every choice, held choices, dropped, the fullest held
-        expert over the mean, held experts touched: what
-        ``MoeInstruments.step`` takes) a sparse layer over the rows the
-        launch fed, which ``_deliver`` reads with the tokens and
-        publishes; the others return two values and ``counts`` is None."""
+        """(tokens, state, beside) of one launch of the token step.
+        ``beside`` is what the step returns after its state, as a tuple,
+        which ``_deliver`` reads with the tokens and publishes; None where
+        the step returns tokens and state alone. First the router's
+        counts of a model whose step routes tokens to experts: float32
+        ``[len(model.moe_layers), 5]``, a row (every choice, held choices,
+        dropped, the fullest held expert over the mean, held experts
+        touched: what ``MoeInstruments.step`` takes) a sparse layer over
+        the rows the launch fed. After them, from a model whose residual
+        path has more than one stream, that path's health over the same
+        rows: float32 ``[2]``, the largest distance of a mixing map's row
+        or column sum from 1 and the largest gain of a row's streams from
+        entry to exit (``ServingInstruments.residual_health``)."""
         kw = ({"site": f"decode:{self.name}:step"}
               if self._step_takes_site else {})
-        out = self.model.step(state, tokens, pos, table, **kw)
-        return out if len(out) == 3 else (*out, None)
+        nxt, state, *beside = self.model.step(state, tokens, pos, table,
+                                              **kw)
+        return nxt, state, tuple(beside) or None
 
     def clear_prefix_cache(self):
         """Drop every cached prefix chain (both lanes), releasing the
@@ -1623,7 +1629,7 @@ class DecodeEngine:
                 tokens = feed
                 if (feed < 0).any():
                     tokens = self._pick(feed, prev[0])
-                nxt, self._state, counts = self._model_step(
+                nxt, self._state, beside = self._model_step(
                     self._state, tokens, pos, table)
                 if self._spec is not None:
                     # fallback boundaries keep the draft pool in sync so
@@ -1636,7 +1642,7 @@ class DecodeEngine:
             return
         for _, req, _ in fed:
             req.ptr += 1
-        launch = (nxt, fed, t_b0, counts)
+        launch = (nxt, fed, t_b0, beside)
         if prev is not None and not self._deliver(inst, prev, True):
             return
         if self._pick is not None and any(
@@ -1653,21 +1659,22 @@ class DecodeEngine:
         successor was dispatched before this read. A row whose request
         has ended since the dispatch (its ``eos`` came with the
         boundary before, or it was failed or closed) is discarded:
-        nothing of it is emitted or counted. The router's counts, where
-        the step returned any, come to the host in the same read as the
-        tokens and go to the ``dl4j_moe_*`` series at the end of `emit`:
-        nothing is read a second time, and nothing on the dispatch side
-        waits for them. Returns False when the read raised (every request
-        was failed)."""
-        nxt, fed, t_b0, counts = launch
+        nothing of it is emitted or counted. What the step returned
+        beside its tokens (``_model_step``: the router's counts, the
+        residual path's health) comes to the host in the same read as the
+        tokens and goes to the ``dl4j_moe_*`` series and the ``dl4j_hc_*``
+        gauges at the end of `emit`: nothing is read a second time, and
+        nothing on the dispatch side waits for them. Returns False when
+        the read raised (every request was failed)."""
+        nxt, fed, t_b0, beside = launch
         try:
             with _phase(inst, "readback", self._account):
-                if counts is None:
+                if beside is None:
                     nxt = np.asarray(nxt)
                 else:
                     import jax
 
-                    nxt, counts = jax.device_get((nxt, counts))
+                    nxt, beside = jax.device_get((nxt, beside))
         except Exception as e:
             self._fail_boundary(_boundary_error(
                 e, f"decode:{self.name}:step", "decode step failed"))
@@ -1719,9 +1726,13 @@ class DecodeEngine:
                                     np.asarray(positions, np.int32))
                 if overlapped:
                     inst.overlapped.inc()
-                if counts is not None:
-                    inst.moe_step(self.model.moe_layers, counts,
-                                  getattr(self.model, "moe_dense", False))
+                if beside is not None:
+                    counts, *health = beside
+                    if len(counts):
+                        inst.moe_step(self.model.moe_layers, counts,
+                                      getattr(self.model, "moe_dense", False))
+                    if health:
+                        inst.residual_health(*health[0])
                 startup_done()      # one flag read after the process's first
         return True
 

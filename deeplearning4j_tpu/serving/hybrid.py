@@ -91,6 +91,10 @@ class HybridDecodeModel:
             raise DecodeError(
                 "HybridDecodeModel serves layers of one mixer each: mamba, "
                 "full attention without rotary embedding, or sparse experts")
+        if cfg.streams > 1:
+            raise DecodeError(
+                f"HybridDecodeModel carries one residual stream a position; "
+                f"the description asks for streams = {cfg.streams}")
         self.cfg = cfg
         self.dtype = jnp.dtype(dtype)
         self.params = decode_layout(params, self.dtype)

@@ -45,9 +45,10 @@ from deeplearning4j_tpu.serving.decode import (
 from deeplearning4j_tpu.telemetry import compile_ledger
 
 # leaves of the block that stay float32 whatever the weights' dtype: norm
-# gains and what the router chooses by
+# gains, what the router chooses by, and what the residual path's maps are
+# made from (`causal_lm.stream_maps`: `phi`, `alpha` and their `bias`)
 _FLOAT32 = ("attn_norm", "mlp_norm", "q_norm", "kv_norm", "final_norm",
-            "router", "bias")
+            "router", "bias", "phi", "alpha")
 
 
 def cast_leaves(params, dtype, float32):
@@ -104,7 +105,11 @@ class LatentDecodeModel:
     did with the rows the launch fed (`DecodeEngine._model_step`),
     `moe_layers` names those layers, and `moe_dense` says whether their
     expert products run dense at this many slots (`moe_share_dense`: the
-    engine counts such steps in `dl4j_moe_dense_steps_total`)."""
+    engine counts such steps in `dl4j_moe_dense_steps_total`). Where the
+    description gives a position more than one residual stream
+    (`cfg.streams`), the step carries `[slots, streams, hidden]` through
+    its layers and returns a fourth value, the residual path's two health
+    numbers over the rows it fed (`_apply`)."""
 
     uses_pages = True
     # the pool is donated to every executable over it and written in
@@ -268,9 +273,11 @@ class LatentDecodeModel:
         import jax.numpy as jnp
 
         pidx = table[jnp.arange(self.max_slots), pos // self.page]
-        logits, state, counts = self._apply(params, state, tokens, pos,
-                                            table, pidx)
+        logits, state, counts, health = self._apply(
+            params, state, tokens, pos, table, pidx)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        if health is not None:
+            return nxt, state, counts, health
         return (nxt, state, counts) if self.moe_layers else (nxt, state)
 
     def masked_fn(self, params, state, tokens, pos, table, active):
@@ -283,22 +290,27 @@ class LatentDecodeModel:
         pos = jnp.where(active, pos, 0)
         pidx = jnp.where(
             active, table[jnp.arange(self.max_slots), pos // self.page], 0)
-        logits, state, _ = self._apply(params, state, tokens, pos, table,
-                                       pidx)
+        logits, state, _, _ = self._apply(params, state, tokens, pos, table,
+                                          pidx)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return jnp.where(active, nxt, -1), state
 
     def _apply(self, params, state, tokens, pos, table, pidx):
         """-> (float32 logits [S, vocab], the state, the routers' counts
-        float32 [sparse layers, 5]). ``pidx [S]`` is the page each slot
+        float32 [sparse layers, 5], the residual path's health float32
+        [2] or None for one stream). ``pidx [S]`` is the page each slot
         writes: the scratch page for a slot that is not fed (the engine
         gives it a zero row of the table), which is also how the step
-        knows the rows that carry a token."""
+        knows the rows that carry a token. The health numbers, over the
+        fed rows: the largest `|row or column sum - 1|` of any sublayer's
+        mixing map (`stream_maps`' `defect`), and the largest RMS of a
+        row's streams at the exit over their RMS at the entry."""
         import jax
         import jax.numpy as jnp
 
         from deeplearning4j_tpu.models.causal_lm import (
-            _mm, latent_project, mlp_apply, rms_norm)
+            _mm, latent_project, mlp_apply, rms_norm, stream_maps,
+            stream_read, stream_write, streams_enter, streams_exit)
         from deeplearning4j_tpu.parallel.moe import moe_share_apply
 
         cfg, dt, S, H = self.cfg, self.dtype, self.max_slots, self.n_heads
@@ -309,10 +321,12 @@ class LatentDecodeModel:
         cols = jnp.arange(self.page)[None, :]
         walk = self._walk(pos, table)               # once a step
         pool = state["latent"]
-        h = params["embed"][tokens].astype(dt)
-        counts = []
+        h = entry = streams_enter(params["embed"][tokens].astype(dt), cfg)
+        counts, defects = [], []
         for li, (lp, spec) in enumerate(zip(params["layers"], cfg.layers)):
-            u = rms_norm(h, lp["attn_norm"], cfg.rms_eps).astype(dt)
+            maps = stream_maps(lp.get("attn_streams"), h, cfg, fused=True)
+            u = rms_norm(stream_read(h, maps), lp["attn_norm"],
+                         cfg.rms_eps).astype(dt)
             with jax.named_scope("mla.project"):
                 q_n, q_r, c, k_r = latent_project(lp, u, cfg, H, cos, sin)
                 # heads lead the product's result (the CPU backend has
@@ -334,8 +348,13 @@ class LatentDecodeModel:
             with jax.named_scope("mla.attend"):
                 o = self._attend(q, lp["wv_b"], pool, li, walk)
                 att = _mm(o.astype(dt).reshape(S, H * cfg.v_dim), lp["wo"])
-            h = (h + att).astype(dt)
-            u = rms_norm(h, lp["mlp_norm"], cfg.rms_eps).astype(dt)
+            h = stream_write(h, att, maps)
+            mlp_maps = stream_maps(lp.get("mlp_streams"), h, cfg,
+                                   fused=True)
+            u = rms_norm(stream_read(h, mlp_maps), lp["mlp_norm"],
+                         cfg.rms_eps).astype(dt)
+            if maps is not None:
+                defects += [maps["defect"], mlp_maps["defect"]]
             if spec.mlp == "dense":
                 with jax.named_scope("mlp.dense"):
                     out = mlp_apply(lp["mlp"], u)
@@ -357,13 +376,21 @@ class LatentDecodeModel:
                     dropped.astype(jnp.float32),
                     jnp.max(held) / jnp.maximum(jnp.mean(held), 1e-9),
                     jnp.sum(held > 0).astype(jnp.float32)]))
-            h = (h + out).astype(dt)
+            h = stream_write(h, out, mlp_maps)
         with jax.named_scope("lm.head"):
-            x = rms_norm(h, params["final_norm"], cfg.rms_eps).astype(dt)
+            x = rms_norm(streams_exit(h, cfg), params["final_norm"],
+                         cfg.rms_eps).astype(dt)
             logits = _mm(x, params["head"])
         counts = (jnp.stack(counts) if counts
                   else jnp.zeros((0, 5), jnp.float32))
-        return logits, {"latent": pool}, counts
+        health = None
+        if defects:
+            rms = lambda a: jnp.sqrt(jnp.mean(  # noqa: E731
+                jnp.square(a.astype(jnp.float32)), axis=(-2, -1)))
+            health = jnp.stack([
+                jnp.max(jnp.where(fed, jnp.max(jnp.stack(defects), 0), 0.0)),
+                jnp.max(jnp.where(fed, rms(h) / rms(entry), 0.0))])
+        return logits, {"latent": pool}, counts, health
 
     def params_for_step(self):
         return self.params

@@ -394,6 +394,8 @@ def fmt_float(v):
         return "+Inf"
     if v == float("-inf"):
         return "-Inf"
+    if v != v:      # a gauge of a step that overflowed: shown, not raised
+        return "NaN"
     if float(v) == int(v) and abs(v) < 1e15:
         return str(int(v))
     return repr(float(v))
@@ -643,6 +645,17 @@ MOE_DENSE_HELP = ("Of dl4j_moe_steps_total, the steps whose expert products "
                   "dropped), by model")
 
 
+HC_RESIDUAL_HELP = ("Of the decode engine's last delivered token step, over "
+                    "the rows it fed and every sublayer: the largest "
+                    "|row or column sum - 1| of a residual mixing map "
+                    "after its Sinkhorn iterations (0 is doubly "
+                    "stochastic), by model")
+HC_GAIN_HELP = ("Of the decode engine's last delivered token step, over the "
+                "rows it fed: the largest RMS of a row's residual streams "
+                "after the last layer over their RMS at the embedding, by "
+                "model")
+
+
 class MoeInstruments:
     """The `dl4j_moe_*` series of one publisher, labelled `model`: a
     trainer's name or a decode engine's. `step(layers, counts)` adds one
@@ -746,13 +759,14 @@ class ServingInstruments:
                  "kv_fill_sum", "live_pages_sum", "overlapped",
                  "decode_queue_wait", "_registry", "_moe", "_state_starts",
                  "interval", "interval_max", "between", "interval_gc",
-                 "_longest")
+                 "_longest", "_residual")
 
     def __init__(self, registry, model):
         self.model = model
         self._registry = registry
         self._moe = None    # bound by the first token step that routes
         self._state_starts = None   # and by a model's first state start
+        self._residual = None       # and by the first health numbers
         self._requests = registry.counter(
             "dl4j_serving_requests_total", SERVING_REQUESTS_HELP,
             ("model", "outcome"))
@@ -903,6 +917,23 @@ class ServingInstruments:
                 "dl4j_decode_slot_state_bytes", DECODE_SLOT_STATE_HELP,
                 ("model",)).labels(model=self.model).set(nbytes)
         self._state_starts.inc()
+
+    def residual_health(self, defect, gain):
+        """One token step's two numbers on a residual path of several
+        streams (`serving/latent.py:LatentDecodeModel._apply`): how far
+        its worst mixing map was from doubly stochastic, and its largest
+        gain from entry to exit. Gauges of the last delivered step,
+        scrape-only."""
+        if self._residual is None:
+            self._residual = []
+            for name, text in (
+                    ("dl4j_hc_sinkhorn_residual_max", HC_RESIDUAL_HELP),
+                    ("dl4j_hc_stream_gain_max", HC_GAIN_HELP)):
+                fam = self._registry.gauge(name, text, ("model",))
+                fam.local = True
+                self._residual.append(fam.labels(model=self.model))
+        for gauge, value in zip(self._residual, (defect, gain)):
+            gauge.set(float(value))
 
     def moe_step(self, layers, counts, dense=False):
         """One token step's router counts (`MoeInstruments.step`), under

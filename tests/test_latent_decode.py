@@ -82,7 +82,7 @@ def stepwise_logits(m, tokens, start=0):
         table = np.zeros_like(kv.table)
         feed[1], pos[1], table[1] = tok, p, kv.table[1]
         pidx = table[np.arange(m.max_slots), pos // m.page]
-        logits, state, _ = apply(m.params, state, feed, pos, table, pidx)
+        logits, state, *_ = apply(m.params, state, feed, pos, table, pidx)
         out.append(np.asarray(logits[1]))
     return np.stack(out)
 
