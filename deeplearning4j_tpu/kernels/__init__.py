@@ -9,7 +9,9 @@ made in one place and is never silent: every traced ``lstmLayer`` /
 ``gruLayer`` call is counted in ``dl4j_recurrence_route_total{op,route}``,
 and every traced token step of a paged decode model that has a kernel for
 its attention (:func:`decode_attention_route`; `latent_attention.py`) in
-``dl4j_decode_attention_route_total{model,route}``. `stream_maps.py` (the
+``dl4j_decode_attention_route_total{model,route}``, and what writes that
+step's new rows into its pool (:func:`decode_pool_write`) in
+``dl4j_decode_pool_write_total{model,route}``. `stream_maps.py` (the
 maps of a residual path of several streams) is taken by
 `models/causal_lm.py:stream_maps` where `_on_tpu()` says so and the
 positions fill whole lanes; the trace names its calls `stream_maps`.
@@ -35,7 +37,25 @@ DECODE_ROUTE_HELP = ("Traced token steps of a paged decode model by what "
                      "over gathered chunks of live pages). Counted at "
                      "trace time: once per traced executable, not per step")
 
+POOL_WRITE_HELP = ("Traced token steps of a paged decode model by what "
+                   "writes the step's new rows into its pool (kernel = the "
+                   "paged-attention kernel, each fed slot's column set in "
+                   "the page it copies to VMEM and that page sent back "
+                   "where it lies; scatter = XLA's gather, select and "
+                   "scatter of each fed slot's whole page). Counted at "
+                   "trace time: once per traced executable, not per step")
+
 _tls = threading.local()
+
+
+def _count_route(name, help_, labels, **values):
+    """One traced decision into the scrape-only counter ``name``."""
+    from deeplearning4j_tpu.telemetry import registry as _registry
+
+    if _registry.enabled():
+        fam = _registry.get_registry().counter(name, help_, labels)
+        fam.local = True   # depends on the host's backend: scrape-only
+        fam.labels(**values).inc()
 
 
 def recurrence_route(op: str, available: bool) -> str:
@@ -52,8 +72,6 @@ def recurrence_route(op: str, available: bool) -> str:
     chip_smoke.py."""
     import jax
 
-    from deeplearning4j_tpu.telemetry import registry as _registry
-
     if not available or \
             os.environ.get(f"DL4J_DISABLE_PALLAS_{op}") == "1":
         route = "scan"
@@ -63,11 +81,8 @@ def recurrence_route(op: str, available: bool) -> str:
         route = "interpret"
     else:
         route = "scan"
-    if _registry.enabled():
-        fam = _registry.get_registry().counter(
-            "dl4j_recurrence_route_total", ROUTE_HELP, ("op", "route"))
-        fam.local = True   # depends on the host's backend: scrape-only
-        fam.labels(op=op, route=route).inc()
+    _count_route("dl4j_recurrence_route_total", ROUTE_HELP, ("op", "route"),
+                 op=op, route=route)
     return route
 
 
@@ -89,16 +104,21 @@ def decode_attention_route(model: str, available: bool) -> str:
     can observe decides, and the decision is counted in
     ``dl4j_decode_attention_route_total``, so a replica whose step quietly
     fell back to the loop shows up on /metrics."""
-    from deeplearning4j_tpu.telemetry import registry as _registry
-
     route = "kernel" if available and _on_tpu() else "loop"
-    if _registry.enabled():
-        fam = _registry.get_registry().counter(
-            "dl4j_decode_attention_route_total", DECODE_ROUTE_HELP,
-            ("model", "route"))
-        fam.local = True   # depends on the host's backend: scrape-only
-        fam.labels(model=model, route=route).inc()
+    _count_route("dl4j_decode_attention_route_total", DECODE_ROUTE_HELP,
+                 ("model", "route"), model=model, route=route)
     return route
+
+
+def decode_pool_write(model: str, route: str) -> None:
+    """Count what writes one traced token step's new rows into the pool of
+    decode model ``model``: ``"kernel"`` (the paged-attention kernel sets
+    each fed slot's column where its page lies) or ``"scatter"`` (XLA takes
+    each fed slot's page out, selects the column in and puts the page back
+    whole), in ``dl4j_decode_pool_write_total``: did this replica's step
+    stop taking its pages out and back."""
+    _count_route("dl4j_decode_pool_write_total", POOL_WRITE_HELP,
+                 ("model", "route"), model=model, route=route)
 
 
 @contextlib.contextmanager

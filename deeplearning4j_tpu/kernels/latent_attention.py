@@ -16,12 +16,32 @@ maximum, sum and weighted latent `[H, kv_rank]` in float32 scratch (online
 softmax). This is one key for all heads: a page is scored against the 128
 heads in one product and its first ``kv_rank`` rows are the values.
 
+The kernel also WRITES the step's new rows (PERF.md, PR 40). A slot's new
+position is a column of its last live page, and that page is the last the
+slot's walk copies to VMEM: the kernel sets the column there, after the
+copy's ``wait()`` and before the page is scored (the causal mask admits
+it), and sends the page back to where it lies in the pool by an async copy
+of its own. The pool is an output aliased to the input, so it is written in
+place; the step threads it to the next layer. Which page: ``pidx[s]``, the
+caller's, never the walk's. ``pidx[s] == 0`` (the scratch page) is a slot
+that is not fed and writes nothing: a masked step's inactive slot walks its
+table's first page at position 0, and inferring the target from the walk
+would write column 0 of a live page. A page's write-back is waited for
+before its entry of the ring is filled again, ``DEPTH`` pages on, and at the
+grid's end. No other slot reads the written page in the same call (a
+partial page is never shared: `serving/prefix_cache.py`; idle slots walk the
+scratch page, which nobody writes). What it replaces: taking each fed slot's
+page out of the pool, selecting the column in and scattering the whole page
+back, in XLA, every layer (4.8 ms of a 26.5 ms launch at 40 layers: PERF.md,
+PR 39); a scatter of the column alone turns the whole pool round.
+
 Layouts: ``q [S, H, row]``, ``pool [L, pages, row, page]`` (a position is a
-COLUMN of its page, latent over rotated key), out ``[S, H, kv_rank]`` in
-the pool's dtype: each head's normalised weighted latent, which the caller
-takes through its ``wv_b`` once a slot. Operands in the pool's dtype,
-products accumulated in float32, the weights ``p`` rounded to the pool's
-dtype before the value product: the precision the loop has.
+COLUMN of its page, latent over rotated key), ``rows [S, row]``, out
+``[S, H, kv_rank]`` in the pool's dtype: each head's normalised weighted
+latent, which the caller takes through its ``wv_b`` once a slot. Operands
+in the pool's dtype, products accumulated in float32, the weights ``p``
+rounded to the pool's dtype before the value product: the precision the
+loop has.
 
 Constraints (`available`): ``page % 128 == 0`` (a page's columns are the
 lanes), ``row`` and ``kv_rank`` multiples of the dtype's sublane tile, the
@@ -54,11 +74,12 @@ _VMEM_BUDGET = 32 * 1024 * 1024
 
 
 def _vmem_bytes(heads, row, page, kv_rank, itemsize):
-    """What the kernel holds in VMEM: the ring of pages, q's and the
-    output's blocks twice (the grid's pipeline), the float32 accumulator
-    and a page's scores and weights."""
+    """What the kernel holds in VMEM: the ring of pages, q's, the new
+    rows' (128 float32 columns) and the output's blocks twice (the grid's
+    pipeline), the float32 accumulator and a page's scores and weights."""
     return (DEPTH * row * page * itemsize
-            + 2 * heads * row * itemsize + 2 * heads * kv_rank * itemsize
+            + 2 * heads * row * itemsize + 2 * row * 128 * 4
+            + 2 * heads * kv_rank * itemsize
             + heads * kv_rank * 4 + 2 * heads * 128 * 4
             + 3 * heads * page * 4)
 
@@ -96,34 +117,52 @@ def page_walk(pos, table, page):
             "pos": pos.astype(jnp.int32)}
 
 
-def _kernel(pages_ref, first_ref, count_ref, total_ref, pos_ref,
-            q_ref, pool_ref, o_ref, ring, sems, sent_ref, m_ref, l_ref,
-            acc_ref, *, layer, kv_rank, page, scale):
+def _kernel(pages_ref, first_ref, count_ref, total_ref, pos_ref, pidx_ref,
+            q_ref, row_ref, pool_ref, o_ref, pool_out, ring, sems, wsems,
+            sent_ref, wpend_ref, m_ref, l_ref, acc_ref, *, layer, kv_rank,
+            page, scale):
     s = pl.program_id(0)
     first, count, total = first_ref[s], count_ref[s], total_ref[0]
-    last_pos = pos_ref[s]
+    last_pos, target = pos_ref[s], pidx_ref[s]
 
     def copy(j):
         k = lax.rem(j, DEPTH)
         return pltpu.make_async_copy(pool_ref.at[layer, pages_ref[j]],
                                      ring.at[k], sems.at[k])
 
+    def write(k):
+        """Ring entry ``k`` back to the page the slot writes (a wait only
+        needs the semaphore and the size)."""
+        return pltpu.make_async_copy(ring.at[k], pool_out.at[layer, target],
+                                     wsems.at[k])
+
+    def drain(k):
+        """The write-back from ring entry ``k``, if one is on its way."""
+        @pl.when(wpend_ref[k] != 0)
+        def _():
+            write(k).wait()
+            wpend_ref[k] = 0
+
     def send_ahead(j, most=BLOCK):
         """Before page ``j`` is scored: the walk's pages up to ``j + DEPTH
         - 1`` are on their way (they and ``j`` fill the ring; what lay
         before ``j`` has been scored). ``sent_ref`` counts the copies
-        started; ``most`` are missing at most, a block's pages."""
+        started; ``most`` are missing at most, a block's pages. An entry
+        whose page is still being written back is waited for first."""
         for _ in range(most):
             k = sent_ref[0]
 
             @pl.when((k < j + DEPTH) & (k < total))
             def _():
+                drain(lax.rem(k, DEPTH))
                 copy(k).start()
                 sent_ref[0] = k + 1
 
     @pl.when(s == 0)
     def _():
         sent_ref[0] = 0
+        for k in range(DEPTH):
+            wpend_ref[k] = 0
         send_ahead(0, DEPTH)
 
     m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -131,6 +170,31 @@ def _kernel(pages_ref, first_ref, count_ref, total_ref, pos_ref,
     acc_ref[...] = jnp.zeros_like(acc_ref)
     q = q_ref[...]                                      # [H, row]
     cols = lax.broadcasted_iota(jnp.int32, (q.shape[0], page), 1)
+
+    def put_row(i, k):
+        """Page ``i`` of the slot, in ring entry ``k``: where it is the
+        slot's last and the slot is fed, its column of the slot's position
+        takes the new row, and the page goes back to the pool. The row is
+        lane ``s % 128`` of its block (`latent_page_attention`), turned to
+        the column's lane; only the column's lane tile of the page is
+        read and written."""
+        @pl.when((i == count - 1) & (target != 0))
+        def _():
+            col = last_pos - i * page
+            lane = lax.rem(col, 128)
+            new = pltpu.roll(row_ref[...], lax.rem(lane - lax.rem(s, 128)
+                                                   + 128, 128), 1)
+            new = new.astype(ring.dtype)                # [row, 128]
+            at = lax.broadcasted_iota(jnp.int32, new.shape, 1) == lane
+            for t in range(page // 128):
+
+                @pl.when(col // 128 == t)
+                def _():
+                    tile = pl.ds(t * 128, 128)
+                    ring[k, :, tile] = jnp.where(at, new, ring[k, :, tile])
+
+            write(k).start()
+            wpend_ref[k] = 1
 
     def reduce(i, n):
         """The slot's pages ``i .. i + n - 1`` into its running softmax,
@@ -142,7 +206,9 @@ def _kernel(pages_ref, first_ref, count_ref, total_ref, pos_ref,
         scs, cbs = [], []
         for t in range(n):
             copy(j + t).wait()
-            cb = ring[lax.rem(j + t, DEPTH)]            # [row, page]
+            k = lax.rem(j + t, DEPTH)
+            put_row(i + t, k)
+            cb = ring[k]                                # [row, page]
             sc = jnp.dot(q, cb, preferred_element_type=jnp.float32) * scale
             # causal + length: the columns up to the slot's position. A
             # live page holds one at least, so the maximum is finite
@@ -174,42 +240,67 @@ def _kernel(pages_ref, first_ref, count_ref, total_ref, pos_ref,
         def _():
             reduce(count - n, n)
 
+    @pl.when(s == pl.num_programs(0) - 1)
+    def _():
+        for k in range(DEPTH):
+            drain(k)
+
     o_ref[...] = (acc_ref[...] * (1.0 / l_ref[...])).astype(o_ref.dtype)
 
 
-def latent_page_attention(q, pool, walk, *, layer, kv_rank, scale,
-                          interpret=False):
+def latent_page_attention(q, pool, walk, rows, pidx, *, layer, kv_rank,
+                          scale, interpret=False):
     """``q [S, H, row]`` against each slot's own live pages of layer
     ``layer`` of ``pool [L, pages, row, page]``, in the order ``walk``
-    (`page_walk`) gives -> each head's weighted latent ``[S, H, kv_rank]``
-    in the pool's dtype. The pool is read in HBM as it lies, page by page;
-    nothing of it is copied or gathered beside the pages in flight."""
+    (`page_walk`) gives, after each fed slot's new row ``rows[s]`` is set in
+    the column of its position in its page ``pidx[s]`` (0: not fed, no
+    write) -> (each head's weighted latent ``[S, H, kv_rank]`` in the pool's
+    dtype, the pool so written). The pool is read in HBM as it lies, page
+    by page, and written in place (the output is the input, aliased):
+    nothing of it is copied or gathered beside the pages in flight and the
+    pages written."""
     S, H, row = q.shape
     page = pool.shape[-1]
     kernel = functools.partial(_kernel, layer=int(layer), kv_rank=kv_rank,
                                page=page, scale=float(scale))
     by_slot = lambda s, *_: (s, 0, 0)  # noqa: E731
+    # the rows as columns, 128 slots a block: slot s is lane s % 128 of
+    # block s // 128 (a block of one column a slot would be padded to a
+    # lane tile a slot, 128 times the row)
+    groups = -(-S // 128)
+    cols = jnp.pad(rows.astype(jnp.float32), ((0, groups * 128 - S), (0, 0))
+                   ).reshape(groups, 128, row).swapaxes(1, 2)
+    scalars = (walk["pages"], walk["first"], walk["count"], walk["total"],
+               walk["pos"], pidx.astype(jnp.int32))
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((S, H, kv_rank), pool.dtype),
+        out_shape=(jax.ShapeDtypeStruct((S, H, kv_rank), pool.dtype),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=len(scalars),
             grid=(S,),
             in_specs=[pl.BlockSpec((None, H, row), by_slot),
+                      pl.BlockSpec((None, row, 128),
+                                   lambda s, *_: (s // 128, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((None, H, kv_rank), by_slot),
+            out_specs=(pl.BlockSpec((None, H, kv_rank), by_slot),
+                       pl.BlockSpec(memory_space=pl.ANY)),
             scratch_shapes=[
                 pltpu.VMEM((DEPTH, row, page), pool.dtype),
                 pltpu.SemaphoreType.DMA((DEPTH,)),
+                pltpu.SemaphoreType.DMA((DEPTH,)),
                 pltpu.SMEM((1,), jnp.int32),
+                pltpu.SMEM((DEPTH,), jnp.int32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, 1), jnp.float32),
                 pltpu.VMEM((H, kv_rank), jnp.float32)]),
+        # the pool, the operand after q and the rows' columns, is the
+        # second output
+        input_output_aliases={len(scalars) + 2: 1},
         # slot after slot: the ring of pages runs across their borders
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=_VMEM_BUDGET),
         name="latent_page_attention",
         interpret=interpret,
-    )(walk["pages"], walk["first"], walk["count"], walk["total"],
-      walk["pos"], q.astype(pool.dtype), pool)
+    )(*scalars, q.astype(pool.dtype), cols, pool)

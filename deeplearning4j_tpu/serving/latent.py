@@ -200,22 +200,29 @@ class LatentDecodeModel:
 
     def _walk(self, pos, table):
         """Once a step, for all its layers: the route the step's
-        attention takes, counted, and the order in which it reads the
-        pool: the kernel's walk over the live pages, or the loop's list
-        of them."""
+        attention takes and what writes its rows (the kernel, or XLA's
+        scatter of whole pages), both counted, and the order in which it
+        reads the pool: the kernel's walk over the live pages, or the
+        loop's list of them."""
         from deeplearning4j_tpu import kernels
         from deeplearning4j_tpu.kernels import latent_attention
 
         route = kernels.decode_attention_route(type(self).__name__,
                                                self.kernel_fits)
+        kernels.decode_pool_write(type(self).__name__,
+                                  "kernel" if route == "kernel"
+                                  else "scatter")
         if route == "kernel":
             return route, latent_attention.page_walk(pos, table, self.page)
         return route, live_pages(pos, table, self.page)
 
-    def _attend(self, q, wv_b, pool, li, walk):
-        """q [S, H, kv_rank + rope_dim] (the absorbed query beside the
-        rotated one) against each slot's own positions of layer ``li``
-        -> each head's output [S, H, v_dim] float32; ``walk`` is
+    def _attend(self, q, row, wv_b, pool, li, walk, pidx, off):
+        """Each fed slot's new ``row [S, kv_rank + rope_dim]`` into column
+        ``off[s]`` of its page ``pidx[s]`` of layer ``li`` (the scratch
+        page for a slot that is not fed), then q [S, H, kv_rank +
+        rope_dim] (the absorbed query beside the rotated one) against
+        each slot's own positions of that layer -> (each head's output
+        [S, H, v_dim] float32, the pool so written); ``walk`` is
         `_walk`'s.
 
         The kernel (a TPU, pages of whole tiles) is handed the whole
@@ -223,12 +230,22 @@ class LatentDecodeModel:
         it lies, by a copy of that page alone into VMEM; a slot's
         running maximum, sum and weighted latent stay on the chip, and
         each head's normalised latent goes through its ``wv_b`` once a
-        slot. No gather, no partials, no combination, no loop in the
-        step.
+        slot. It writes the rows too: the page a fed slot writes is the
+        last it copies, the column is set there before it is scored,
+        and that page alone goes back to the pool, which is the
+        kernel's output aliased to its input (`latent_attention`'s
+        docstring: which page, the ring entry's wait, ``pidx == 0``).
+        No gather, no scatter, no partials, no combination, no loop in
+        the step.
 
-        The loop (everywhere else) reduces a chunk of live pages at a
-        time: gather the pages out of the whole pool into a copy,
-        score every position against every head in one product, weigh
+        The loop (everywhere else) first writes the rows in XLA: each
+        fed slot's page comes out of the donated pool, takes the
+        column by a select and goes back whole (a scatter of single
+        columns makes the device turn the whole pool round for it, and
+        back, in every layer: PERF.md, PR 32). It then reduces a chunk
+        of live pages at a time: gather the pages out of the whole
+        pool into a copy, score every position against every head in
+        one product, weigh
         the positions' first ``kv_rank`` numbers, and take each head's
         weighted latent through its ``wv_b`` there and then: the
         combination is linear in a page's output, and a page's partial
@@ -242,13 +259,17 @@ class LatentDecodeModel:
         cfg, dt = self.cfg, self.dtype
         route, live = walk
         if route == "kernel":
-            o = latent_attention.latent_page_attention(
-                q, pool, live, layer=li, kv_rank=cfg.kv_rank,
+            o, pool = latent_attention.latent_page_attention(
+                q, pool, live, row, pidx, layer=li, kv_rank=cfg.kv_rank,
                 scale=cfg.latent_scale)             # [S, H, kv_rank]
             return jnp.einsum("hsr,hrd->hsd", o.swapaxes(0, 1), wv_b,
                               preferred_element_type=jnp.float32
-                              ).swapaxes(0, 1)
+                              ).swapaxes(0, 1), pool
         cols = jnp.arange(self.page)
+        pages = pool[li, pidx]                      # [S, row, page]
+        pages = jnp.where((cols[None, :] == off[:, None])[:, None, :],
+                          row[:, :, None], pages)
+        pool = pool.at[li, pidx].set(pages)
 
         def partial(slot, pg, last):
             cb = pool[li, pg]                       # [C, row, page]
@@ -267,7 +288,7 @@ class LatentDecodeModel:
 
         return live_page_attention(live, partial, lambda a: a[..., None],
                                    self.n_heads,
-                                   (self.n_heads, cfg.v_dim))
+                                   (self.n_heads, cfg.v_dim)), pool
 
     def _fn(self, params, state, tokens, pos, table):
         import jax.numpy as jnp
@@ -318,7 +339,6 @@ class LatentDecodeModel:
         n_fed = jnp.sum(fed).astype(jnp.float32)
         cos, sin = self._cos[pos], self._sin[pos]
         off = pos % self.page
-        cols = jnp.arange(self.page)[None, :]
         walk = self._walk(pos, table)               # once a step
         pool = state["latent"]
         h = entry = streams_enter(params["embed"][tokens].astype(dt), cfg)
@@ -337,16 +357,18 @@ class LatentDecodeModel:
                     [q_lat.astype(dt).swapaxes(0, 1), q_r], axis=-1)
                 row = jnp.concatenate([c, k_r], axis=-1)
             # S columns a layer into the donated pool, in place, before
-            # the layer's attention reads them: each slot's page comes
-            # out, takes the column and goes back whole (a scatter of
-            # single columns makes the device turn the whole pool round
-            # for it, and back, in every layer: PERF.md, PR 32)
-            pages = pool[li, pidx]                  # [S, row, page]
-            pages = jnp.where((cols == off[:, None])[:, None, :],
-                              row[:, :, None], pages)
-            pool = pool.at[li, pidx].set(pages)
+            # the layer's attention reads them (`_attend`). On the
+            # kernel's route the kernel sets each fed slot's column in
+            # the slot's last live page, which it copies to VMEM anyway,
+            # before scoring it, and sends that page back where it lies:
+            # the pool is aliased from the kernel's input to its output,
+            # a page's write-back is waited for before its ring entry is
+            # filled again, and ``pidx == 0`` (not fed) writes nothing.
+            # On the loop's route XLA takes each fed slot's page out,
+            # selects the column in and puts the page back whole
             with jax.named_scope("mla.attend"):
-                o = self._attend(q, lp["wv_b"], pool, li, walk)
+                o, pool = self._attend(q, row, lp["wv_b"], pool, li, walk,
+                                       pidx, off)
                 att = _mm(o.astype(dt).reshape(S, H * cfg.v_dim), lp["wo"])
             h = stream_write(h, att, maps)
             mlp_maps = stream_maps(lp.get("mlp_streams"), h, cfg,

@@ -590,40 +590,54 @@ def test_a_layers_backward_pass_runs_the_forward_kernel_once(
     assert launches() == {"fwd": 2, "dkv": 1, "dq": 1}
 
 
+@pytest.mark.parametrize("S, H, row, page, P, L, kv_rank", [
+    (128, 128, 576, 256, 8, 5, 512),    # deepseek-v3.closed-128
+    (128, 32, 576, 128, 4, 40, 512)],   # xing4-29b.closed-128
+    ids=["deepseek-v3", "xing4"])
 def test_the_latent_paged_attention_kernel_compiles_at_the_cells_sizes(
-        topo, no_cache):
-    """`kernels/latent_attention.py` at `deepseek-v3.closed-128`'s sizes
-    (128 slots, 128 heads, pages ``[576, 256]`` of bfloat16, 8 a slot),
-    compiled for a v5e: the Mosaic compiler takes it (tiles, VMEM), the pool
-    is its operand as it lies (no copy of it, no second layout) and nothing
-    beside the output is made."""
+        topo, no_cache, S, H, row, page, P, L, kv_rank):
+    """`kernels/latent_attention.py` at a served cell's sizes (128 slots;
+    128 heads on pages ``[576, 256]`` of bfloat16, 8 a slot, 5 layers; 32
+    heads on pages ``[576, 128]``, 4 a slot, 40 layers), the slots' new
+    rows written, compiled for a v5e: the Mosaic compiler takes it (tiles,
+    VMEM), the pool is its operand as it lies and its output in place
+    (aliased, no copy of it, no second layout) and nothing beside the
+    output is made."""
     import re
 
     from jax.sharding import SingleDeviceSharding
 
     from deeplearning4j_tpu.kernels import latent_attention as la
 
-    S, H, row, page, P, L, kv_rank = 128, 128, 576, 256, 8, 5, 512
     assert la.available(H, row, page, kv_rank, jnp.bfloat16)
     one = SingleDeviceSharding(topo.devices[0])
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         shape, dtype, sharding=one)
 
-    def attend(q, pool, pos, table):
+    def attend(q, pool, pos, table, rows):
+        pidx = table[jnp.arange(S), pos // page]
         return la.latent_page_attention(
-            q, pool, la.page_walk(pos, table, page), layer=L - 1,
-            kv_rank=kv_rank, scale=0.1)
+            q, pool, la.page_walk(pos, table, page), rows, pidx,
+            layer=L - 1, kv_rank=kv_rank, scale=0.1)
 
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(attend).lower(
+        compiled = jax.jit(attend, donate_argnums=1).lower(
             sd((S, H, row), jnp.bfloat16),
             sd((L, S * P + 1, row, page), jnp.bfloat16),
-            sd((S,), jnp.int32), sd((S, P), jnp.int32)).compile()
+            sd((S,), jnp.int32), sd((S, P), jnp.int32),
+            sd((S, row), jnp.bfloat16)).compile()
     text = compiled.as_text()
-    assert len(re.findall(r"%latent_page_attention\S* = .*custom-call\(",
-                          text)) == 1
+    calls = re.findall(r"%latent_page_attention\S* = .*custom-call\(.*",
+                       text)
+    assert len(calls) == 1
+    # the kernel's second output is its pool operand, and the step's
+    assert re.search(r"output_to_operand_aliasing=\{\{1\}: \(\d+, \{\}\)\}",
+                     calls[0])
+    assert re.search(r"input_output_alias=\{ \{1\}: \(1, \{\}", text)
     pool = rf"bf16\[{L},{S * P + 1},{row},{page}\]"
     assert set(re.findall(pool + r"\{([0-9,]*)", text)) == {"3,2,1,0"}
     assert not re.search(r"= " + pool + r"\S* copy\(", text)
     assert "while(" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= L * (S * P + 1) * row * page * 2
+    assert ma.temp_size_in_bytes < 1 << 20

@@ -399,7 +399,7 @@ def test_the_served_cells_kernels_compile_for_the_chip(topo, no_cache):
     """At the served cell's sizes, compiled for a v5e (nothing runs): the
     maps' kernel over 128 slots, and the paged-attention kernel over 32
     heads and pages `[576, 128]`, 4 a slot, of a 40-layer pool, which stays
-    its operand as it lies."""
+    its operand as it lies and is written in place (the slots' new rows)."""
     import re
 
     from jax.sharding import SingleDeviceSharding
@@ -417,16 +417,18 @@ def test_the_served_cells_kernels_compile_for_the_chip(topo, no_cache):
     S, H, row, page, P, L, kv_rank = 128, 32, 576, 128, 4, 40, 512
     assert la.available(H, row, page, kv_rank, jnp.bfloat16)
 
-    def attend(q, pool, pos, table):
+    def attend(q, pool, pos, table, rows):
         return la.latent_page_attention(
-            q, pool, la.page_walk(pos, table, page), layer=L - 1,
+            q, pool, la.page_walk(pos, table, page), rows,
+            table[jnp.arange(S), pos // page], layer=L - 1,
             kv_rank=kv_rank, scale=0.1)
 
     with jax.default_matmul_precision("default"):
-        compiled = jax.jit(attend).lower(
+        compiled = jax.jit(attend, donate_argnums=1).lower(
             sd((S, H, row), jnp.bfloat16),
             sd((L, S * P + 1, row, page), jnp.bfloat16),
-            sd((S,), jnp.int32), sd((S, P), jnp.int32)).compile()
+            sd((S,), jnp.int32), sd((S, P), jnp.int32),
+            sd((S, row), jnp.bfloat16)).compile()
     text = compiled.as_text()
     pool = rf"bf16\[{L},{S * P + 1},{row},{page}\]"
     assert set(re.findall(pool + r"\{([0-9,]*)", text)) == {"3,2,1,0"}

@@ -486,22 +486,28 @@ CASES = {
 
 
 def both_routes(m, pos, table, seed=0, layer=1, pool=None):
-    """`_attend` of layer `layer` over one random pool by the loop and by
-    the (interpreted) kernel -> two ``[S, H, v_dim]`` float32 arrays."""
+    """`_attend` of layer `layer` over one random pool, each fed slot's new
+    row written as the step writes it, by the loop and by the (interpreted)
+    kernel -> two ``[S, H, v_dim]`` float32 arrays, zero where a slot is
+    not fed (its output is discarded, and on the loop's route it reads the
+    scratch page the loop wrote)."""
     rng = np.random.default_rng(seed)
     dt = m.dtype
     if pool is None:
         pool = rng.normal(size=m._pool_shape())
     pool = jnp.asarray(pool, dt)
     q = jnp.asarray(rng.normal(size=(m.max_slots, m.n_heads, m.row)), dt)
+    row = jnp.asarray(rng.normal(size=(m.max_slots, m.row)), dt)
     wv_b = m.params["layers"][layer]["wv_b"]
     pos, table = jnp.asarray(pos, jnp.int32), jnp.asarray(table, jnp.int32)
-    loop = m._attend(q, wv_b, pool, layer,
-                     ("loop", live_pages(pos, table, m.page)))
-    kernel = m._attend(q, wv_b, pool, layer,
-                       ("kernel", latent_attention.page_walk(
-                           pos, table, m.page)))
-    return np.asarray(loop), np.asarray(kernel)
+    pidx = table[jnp.arange(m.max_slots), pos // m.page]
+    fed = (np.asarray(pidx) != 0)[:, None, None]
+    (loop, _), (kernel, _) = (
+        m._attend(q, row, wv_b, pool, layer, walk, pidx, pos % m.page)
+        for walk in (("loop", live_pages(pos, table, m.page)),
+                     ("kernel", latent_attention.page_walk(
+                         pos, table, m.page))))
+    return np.where(fed, loop, 0), np.where(fed, kernel, 0)
 
 
 @pytest.mark.parametrize("case", list(CASES))
@@ -638,3 +644,110 @@ def test_the_kernel_route_is_counted_where_the_backend_is_a_tpu(monkeypatch):
     assert kernels.decode_attention_route("LatentDecodeModel",
                                           True) == "kernel"
     assert route_count("LatentDecodeModel", "kernel") == before + 1
+
+
+# -- ISSUE 40: the kernel writes the step's rows where the pages lie ----------
+
+def pool_write_count(route):
+    fam = telemetry.get_registry().counter(
+        "dl4j_decode_pool_write_total", kernels.POOL_WRITE_HELP,
+        ("model", "route"))
+    return fam.labels(model="LatentDecodeModel", route=route).value
+
+
+KERNEL = latent_attention.latent_page_attention
+
+
+def scatter_then_read(q, pool, walk, rows, pidx, *, layer, **kw):
+    """The kernel's route as it stood before ISSUE 40: XLA takes each fed
+    slot's page out, selects the new column in and scatters the whole page
+    back, and the kernel only reads (no ``pidx`` names a page to write)."""
+    page = pool.shape[-1]
+    cols = jnp.arange(page)[None, :]
+    pages = pool[layer, pidx]
+    pages = jnp.where((cols == (walk["pos"] % page)[:, None])[:, None, :],
+                      rows[:, :, None], pages)
+    pool = pool.at[layer, pidx].set(pages)
+    return KERNEL(q, pool, walk, rows, jnp.zeros_like(pidx), layer=layer,
+                  interpret=True, **kw)
+
+
+WRITES = {
+    # slot 0 fills its first page's last column, then opens its second;
+    # slot 1 fills its second page, then opens its third; slot 3 fills the
+    # last column of its table
+    "a page filled, then a page opened":
+        lambda: ([PAGE - 2, 2 * PAGE - 1, 40, 3 * PAGE - 3], own_tables(),
+                 None),
+    "tables in a drawn order, idle slots between fed ones":
+        lambda: (*idle([PAGE - 1, 0, 2 * PAGE + 7, 0],
+                       own_tables(np.random.default_rng(3)), 1, 3), None),
+    # the masked step zeroes an inactive slot's position and page, not its
+    # row of the table: its walk reads a live page, which nobody may write
+    "a masked step's inactive slot holds live pages":
+        lambda: ([PAGE - 1, PAGE + 5, 2 * PAGE, 70], own_tables(),
+                 np.array([True, False, True, True])),
+}
+
+
+@pytest.mark.parametrize("case", list(WRITES))
+def test_the_kernel_writes_the_rows_the_scatter_wrote(
+        case, interpreted, monkeypatch):
+    """Three token steps from one pool by three routes: the kernel writing
+    the fed slots' rows where their pages lie, the kernel after XLA's
+    gather, select and scatter (the step before ISSUE 40), and the loop.
+    The first two are the same numbers in another place: the same tokens
+    and a pool bit-identical on every page but the scratch page, which the
+    scatter writes for the slots that are not fed and the kernel leaves
+    alone. The loop sums the attention in another order: the same tokens,
+    the first layer's rows bit-identical (they come before any attention),
+    the second's at float32's tolerance. Each route's step is counted once
+    under what wrote its rows."""
+    pos0, table, active = WRITES[case]()
+    rng = np.random.default_rng(17)
+    pool0 = rng.normal(size=paged_model()._pool_shape()).astype(np.float32)
+    tokens0 = rng.integers(3, 96, SLOTS).astype(np.int32)
+    fed = np.asarray(active if active is not None
+                     else table[np.arange(SLOTS), np.asarray(pos0) // PAGE]
+                     != 0)
+
+    def three_steps(route):
+        m = paged_model()           # a model of its own: a trace of its own
+        before = pool_write_count(route)
+        state = {"latent": jnp.asarray(pool0)}
+        pos, tokens, out = np.array(pos0, np.int32), tokens0, []
+        for _ in range(3):
+            if active is None:
+                nxt, state, *_ = m.step(state, tokens, pos, table)
+            else:
+                nxt, state = m.step_masked(state, tokens, pos, table, active)
+            tokens = np.where(fed, np.asarray(nxt), tokens0).astype(np.int32)
+            out.append(np.where(fed, np.asarray(nxt), -1))
+            pos = np.where(fed, pos + 1, pos).astype(np.int32)
+        assert pool_write_count(route) == before + 1
+        return np.stack(out), np.asarray(state["latent"])
+
+    written, pool = three_steps("kernel")
+    monkeypatch.setattr(latent_attention, "latent_page_attention",
+                        scatter_then_read)
+    scattered, before = three_steps("kernel")
+    monkeypatch.setattr(kernels, "_on_tpu", lambda: False)
+    looped, loop_pool = three_steps("scatter")
+
+    np.testing.assert_array_equal(written, scattered)
+    np.testing.assert_array_equal(written, looped)
+    np.testing.assert_array_equal(pool[:, 1:], before[:, 1:])
+    np.testing.assert_array_equal(pool[0, 1:], loop_pool[0, 1:])
+    np.testing.assert_allclose(pool[1:, 1:], loop_pool[1:, 1:], rtol=TOL,
+                               atol=TOL)
+    # the rows went in: every fed slot's three columns changed, and the
+    # pages of a slot that was not fed came out as they went in
+    changed = np.any(pool != pool0, axis=(0, 2))     # [pages, page]
+    for s in range(SLOTS):
+        cols = [(table[s, p // PAGE], p % PAGE)
+                for p in range(pos0[s], pos0[s] + 3)]
+        if fed[s]:
+            assert all(changed[c] for c in cols)
+        else:
+            assert not changed[table[s]].any()
+    assert changed[1:].sum() == 3 * fed.sum()
